@@ -54,6 +54,7 @@ from repro.cache_service import (  # noqa: E402
     StalenessConfig, TieringConfig,
 )
 from repro.cache_service.feedback import FeedbackConfig  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 # hard-assert ledger: every claim this bench certifies lands in
 # "checked"; anything environment-skipped lands in "skipped" with the
@@ -316,6 +317,7 @@ def _json_path():
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="short traces (CI-sized); same asserts")

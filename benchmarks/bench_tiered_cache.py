@@ -146,6 +146,7 @@ from repro.core import EmbedderTrainer, FinetuneConfig
 from repro.core import store as store_lib
 from repro.data import HashTokenizer, make_pair_dataset
 from repro.data.corpora import DOMAINS, render_query
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.obs import Telemetry
 from repro.obs.health import check_overhead_budget
@@ -1480,6 +1481,8 @@ def main() -> None:
     flush+rebuild, rebuild stall) on a 4k corpus in well under a
     minute."""
     import argparse
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",

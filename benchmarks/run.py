@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _registry():
     from benchmarks.paper_benches import (
@@ -30,6 +32,7 @@ def _registry():
 
 
 def main() -> None:
+    enable_compile_cache()
     registry = _registry()
     selected = sys.argv[1:] or list(registry)
     unknown = [s for s in selected if s not in registry]

@@ -59,6 +59,7 @@ from repro.cache_service.protocol import (
     MaintenanceReport, coalesce_misses, ungrouped_misses,
 )
 from repro.core.calibration import Calibration
+from repro.kernels.cascade_lookup.ops import _on_tpu
 from repro.obs import Telemetry
 from repro.obs.registry import SCHEMA, tenant_label
 
@@ -131,7 +132,11 @@ class CacheService:
         lookup kernel (`kernels/cascade_lookup`) on TPU — subject to
         the kernel's VMEM budget: the warm slice must fit on-chip
         (DESIGN.md §3.1).  On CPU the flag falls back to the same
-        four-op math, so it never changes results or CPU latency.
+        four-op math, so it never changes results or CPU latency.  On
+        a TPU the kernel is compiled when the flag is set, and a kernel
+        the chip's compiler refuses raises ``NotImplementedError`` with
+        the compiler's reason (today it refuses the in-kernel IVF
+        gathers) — it never falls back to the four-op path silently.
 
         ``background_rebuild=True`` double-buffers the IVF rebuild
         (DESIGN.md §7): flushes that would have re-clustered inline
@@ -195,6 +200,8 @@ class CacheService:
         in blocks of that many rows (DESIGN.md §12), lifting the
         single-block VMEM ceiling on warm capacity; None keeps the
         whole-panel residency.  Results are bit-identical either way.
+        It only means something to the fused kernel, so on a TPU it is
+        checked like ``fused=True``.
 
         ``embedders`` turns on the fused multi-embedder ensemble
         (DESIGN.md §13): an int E (or a sequence of E embedder handles,
@@ -555,7 +562,12 @@ class CacheService:
 
     def set_fused(self, fused: bool) -> None:
         """Select the cascade execution path (four-op vs fused kernel);
-        re-jits the lookup, so flipping it mid-serve costs one trace."""
+        re-jits the lookup, so flipping it mid-serve costs one trace.
+        On a TPU the kernel path is compiled here, and a refusal of the
+        chip's compiler raises ``NotImplementedError`` (with the
+        compiler's error chained)."""
+        if (fused or self.warm_block) and _on_tpu():
+            self._require_kernel_compiles()
         self.fused = bool(fused)
         self._lookup = jax.jit(partial(
             tiers.cascade_query, k=self.topk, n_probe=self._n_probe,
@@ -570,6 +582,36 @@ class CacheService:
                 quantized=self.warm_dtype == "int8",
                 mesh=self._mesh, axis=self._shard_axis,
                 warm_block_n=self.warm_block))
+
+    def _require_kernel_compiles(self) -> None:
+        """Compile the fused kernel lookup for one query at this
+        service's tier shapes; raise with the compiler's reason when the
+        chip refuses it, instead of serving through another path."""
+        lookup = partial(
+            tiers.ensemble_cascade_query if self.ens is not None
+            else tiers.cascade_query, k=self.topk, n_probe=self._n_probe,
+            tail=self._tail, fused=True,
+            quantized=self.warm_dtype == "int8", mesh=self._mesh,
+            axis=self._shard_axis, warm_block_n=self.warm_block)
+        q_shape = (1, self.n_embedders, self.dim) if self.ens is not None \
+            else (1, self.dim)
+        args = [self.hot, self.warm]
+        if self.ens is not None:
+            args += [self.ens, jax.ShapeDtypeStruct(q_shape, jnp.float32),
+                     jax.ShapeDtypeStruct((1, self.n_embedders),
+                                          jnp.float32)]
+        else:
+            args.append(jax.ShapeDtypeStruct(q_shape, jnp.float32))
+        args += [jax.ShapeDtypeStruct((1,), jnp.int32),
+                 jax.ShapeDtypeStruct((1,), jnp.float32)]
+        try:
+            jax.jit(lookup).lower(*args).compile()
+        except Exception as e:   # Mosaic and XLA raise many types here
+            raise NotImplementedError(
+                "the fused cascade kernel (fused=True / warm_block) does "
+                "not compile for this TPU; serve with the four-op cascade "
+                f"(fused=False, warm_block=None). Compiler: "
+                f"{type(e).__name__}: {e}") from e
 
     # ------------------------------------------------------------------
     # tenant policy surface

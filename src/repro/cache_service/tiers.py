@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ivf as ivf_lib
+from repro.kernels.cascade_lookup.ref import cosine, fuse
 
 NEG = -1e30
 
@@ -201,7 +202,7 @@ def hot_query(state: HotState, q: jax.Array, q_tenants: jax.Array,
               k: int = 1) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Exact tenant-masked top-k.  q: (Q, D), q_tenants: (Q,) int32."""
     qn = _unit(q.astype(jnp.float32))
-    scores = qn @ state.keys.T                                    # (Q, N)
+    scores = cosine(qn, state.keys)                               # (Q, N)
     ok = state.valid[None, :] & (state.tenants[None, :]
                                  == q_tenants[:, None])
     scores = jnp.where(ok, scores, NEG)
@@ -480,7 +481,7 @@ def warm_query(state: WarmState, q: jax.Array, q_tenants: jax.Array,
     n_clusters, bucket = state.members.shape
     n_probe = min(n_probe, n_clusters)
 
-    csims = qn @ state.centroids.T                                 # (Q, K)
+    csims = cosine(qn, state.centroids)                            # (Q, K)
     _, probes = jax.lax.top_k(csims, n_probe)
     cand = state.members[probes].reshape(Q, n_probe * bucket)
     # partition candidates by write epoch so a slot overwritten after
@@ -502,7 +503,7 @@ def warm_query(state: WarmState, q: jax.Array, q_tenants: jax.Array,
     ok = (cand >= 0) & state.valid[safe] \
         & (state.tenants[safe] == q_tenants[:, None]) \
         & (is_tail | (state.write_seq[safe] <= state.indexed_total))
-    scores = jnp.einsum("qd,qnd->qn", qn, state.keys[safe])
+    scores = cosine(qn, state.keys[safe])
     scores = jnp.where(ok, scores, NEG)
     top_s, top_i = jax.lax.top_k(scores, k)
     rows = jnp.arange(Q)[:, None]
@@ -563,7 +564,7 @@ def _rescore_exact(qn, keys, s, wslots):
     the exact pass costs O(Q·k·D) — the bulk scan stays int8.
     """
     safe = jnp.clip(wslots, 0, keys.shape[0] - 1)
-    exact = jnp.einsum("qd,qkd->qk", qn, keys[safe])
+    exact = cosine(qn, keys[safe])
     return jnp.where(wslots >= 0, exact, s)
 
 
@@ -617,7 +618,6 @@ def _cascade_sharded(hot: HotState, swarm: WarmState, qn, qt, thr, k,
     """shard_map execution of the sharded cascade: warm leaves split on
     their leading shard axis over ``axis``, hot/queries replicated, one
     (Q, k·shards) all-gather merge (`core.distrib.merge_local_topk`)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.distrib import merge_local_topk
@@ -636,13 +636,13 @@ def _cascade_sharded(hot: HotState, swarm: WarmState, qn, qt, thr, k,
         return sm, vm, hslot0, hot_hit, hit
 
     rep = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: rep, hot),
                   jax.tree_util.tree_map(lambda _: P(axis), swarm),
                   rep, rep, rep),
         out_specs=(rep, rep, rep, rep, rep),
-        check_rep=False)
+        check_vma=False)
     s, vids, hslots, hot_hit, hit = fn(hot, swarm, qn, qt, thr)
     return CascadeResult(scores=s, value_ids=vids, hot_slots=hslots,
                          hot_hit=hot_hit, hit=hit)
@@ -986,9 +986,8 @@ def _rescore_exact_fused(qe, w, warm_panels, s, wslots):
     O(Q·k·E·D) on the few selected rows (DESIGN.md §13)."""
     E = qe.shape[0]
     safe = jnp.clip(wslots, 0, warm_panels.shape[1] - 1)
-    pans = [jnp.einsum("qd,qkd->qk", qe[e], warm_panels[e][safe])
-            for e in range(E)]
-    exact = jnp.einsum("qke,qe->qk", jnp.stack(pans, -1), w)
+    exact = fuse([cosine(qe[e], warm_panels[e][safe]) for e in range(E)],
+                 w)
     return jnp.where(wslots >= 0, exact, s)
 
 
@@ -1072,7 +1071,6 @@ def _ensemble_sharded(hot, swarm, ens, qe, w, qt, thr, k, n_probe, tail,
     queries replicated, one (Q, k·shards) merge carrying (vid, is_hot,
     warm-slot, shard) payloads so the winner's panel keys can be
     gathered after the merge."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.distrib import merge_local_topk
@@ -1096,14 +1094,14 @@ def _ensemble_sharded(hot, swarm, ens, qe, w, qt, thr, k, n_probe, tail,
 
     rep = P()
     shard = lambda x: P(*((axis,) + (None,) * (x.ndim - 1)))
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: rep, hot),
                   jax.tree_util.tree_map(lambda _: P(axis), swarm),
                   shard(ens.warm_keys), shard(ens.warm_keys_q),
                   shard(ens.warm_scales), rep, rep, rep, rep, rep),
         out_specs=(rep,) * 7,
-        check_rep=False)
+        check_vma=False)
     s, vids, hslots, hot_hit, hit, wslot, wshard = fn(
         hot, swarm, ens.warm_keys, ens.warm_keys_q, ens.warm_scales,
         ens.hot_keys, qe, w, qt, thr)

@@ -120,7 +120,6 @@ def query_sharded(state: StoreState, q: jax.Array, threshold: float,
     tiny-merge step itself is `core.distrib.merge_local_topk`, shared
     with the tiered cache's sharded warm lookup (DESIGN.md §8).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.distrib import merge_local_topk
@@ -144,13 +143,13 @@ def query_sharded(state: StoreState, q: jax.Array, threshold: float,
         # tiny merge: gather only (Q, k) candidates from every shard
         return merge_local_topk(axis, k, s, i_glob, vals)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(axis),
                   P(batch_axes, None)),
         out_specs=(P(batch_axes, None), P(batch_axes, None),
                    P(batch_axes, None)),
-        check_rep=False)
+        check_vma=False)
     scores, slots, value_ids = fn(state.keys, state.valid, state.value_ids,
                                   qn)
     hit = scores[:, 0] >= threshold

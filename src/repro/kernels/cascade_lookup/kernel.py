@@ -46,10 +46,15 @@ the VMEM hog — stream.  ``warm_block_n`` therefore bounds residency at
 ``warm_block_n·D`` key bytes regardless of warm capacity: a shard's
 warm slice may exceed the old single-block design size (DESIGN.md §12)
 at the cost of one extra probe-panel pass per additional block.  Valid
-masks travel as int32 and the hit flags return as int32 (bool VMEM refs
-are a Mosaic lowering hazard); `interpret=True` runs the same dataflow
-as pure XLA ops for CPU tests — the only mode exercised in this repo's
-CPU CI, as with the other kernel packages.
+masks travel as int32 (1, N) rows and the hit flags return as int32
+(bool VMEM refs are a Mosaic lowering hazard); every top-k is the
+gather-free `cosine_topk.select_topk`, and scores are `ref.cosine` /
+`ref.fuse`, so interpret mode is bit-equal with the oracle.
+
+Mosaic refuses this kernel for a TPU: the IVF candidate panels are
+data-dependent VMEM gathers (``mem[probes]``, ``wkb[local]`` and the
+metadata columns at ``gsafe``), which it cannot lower.  `interpret=True`
+runs the same dataflow as XLA ops on the CPU, where the tests check it.
 """
 from __future__ import annotations
 
@@ -60,73 +65,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-DEFAULT_BLOCK_N = 512
-# tie-key sentinel for consumed / masked candidates: larger than any
-# real flat panel position (n_probe·bucket + tail << 2^30)
-POS_PAD = 2 ** 30
+from repro.kernels.cascade_lookup.ref import cosine, fuse
+from repro.kernels.cosine_topk.kernel import (
+    DEFAULT_BLOCK_N, NEG_INF, POS_PAD, select_topk,
+)
 
 
-def _select_topk(scores, idx, k):
-    """scores: (Q, M) candidates with payload idx (Q, M) -> (Q, k) best
-    by k rounds of masked argmax (unrolled, k small).  argmax picks the
-    first occurrence, matching lax.top_k's lowest-index tie-break."""
-    out_s, out_i = [], []
-    for _ in range(k):
-        best = jnp.argmax(scores, axis=-1)                       # (Q,)
-        rows = jnp.arange(scores.shape[0])
-        out_s.append(scores[rows, best])
-        out_i.append(idx[rows, best])
-        scores = scores.at[rows, best].set(NEG_INF)
-    return jnp.stack(out_s, -1), jnp.stack(out_i, -1)
-
-
-def _merge(acc_s, acc_i, blk_s, blk_i, k):
-    """Running top-k merge; accumulator first so earlier candidates win
-    ties (panel order)."""
-    cand_s = jnp.concatenate([acc_s, blk_s], axis=-1)
-    cand_i = jnp.concatenate([acc_i, blk_i], axis=-1)
-    return _select_topk(cand_s, cand_i, k)
-
-
-def _select_topk_pos(scores, pos, slot, k):
-    """Top-k by score with ties broken by the lowest ``pos`` — the flat
-    candidate-panel position each entry occupies in the oracle's single
-    gathered panel.  Masked / already-consumed entries carry POS_PAD,
-    so among equal (e.g. all-NEG) scores the selection order is
-    ascending panel position: exactly `lax.top_k`'s stable
-    lowest-index-first order, independent of the order blocks streamed
-    their candidates in."""
-    rows = jnp.arange(scores.shape[0])
-    out_s, out_p, out_i = [], [], []
-    for _ in range(k):
-        m = jnp.max(scores, axis=-1, keepdims=True)
-        tie_pos = jnp.where(scores >= m, pos, POS_PAD)
-        col = jnp.argmin(tie_pos, axis=-1)
-        out_s.append(scores[rows, col])
-        out_p.append(pos[rows, col])
-        out_i.append(slot[rows, col])
-        scores = scores.at[rows, col].set(NEG_INF)
-        pos = pos.at[rows, col].set(POS_PAD)
-    return (jnp.stack(out_s, -1), jnp.stack(out_p, -1),
-            jnp.stack(out_i, -1))
-
-
-def _merge_pos(acc_s, acc_p, acc_i, blk_s, blk_p, blk_i, k):
-    """Running top-k merge keyed on (score, panel position)."""
-    cand_s = jnp.concatenate([acc_s, blk_s], axis=-1)
-    cand_p = jnp.concatenate([acc_p, blk_p], axis=-1)
-    cand_i = jnp.concatenate([acc_i, blk_i], axis=-1)
-    return _select_topk_pos(cand_s, cand_p, cand_i, k)
-
-
-def _kernel(q_ref, qt_ref, thr_ref, hk_ref, hv_ref, ht_ref, hvid_ref,
-            wk_ref, wscale_ref, wv_ref, wt_ref, wvid_ref, wseq_ref,
-            cent_ref, mem_ref, meta_ref, out_s_ref, out_v_ref,
-            out_wslot_ref, out_hslot_ref, out_flag_ref,
-            acc_s, acc_i, wacc_s, wacc_p, wacc_i, *, k: int, block_n: int,
-            n_hot: int, n_hot_blocks: int, warm_block_n: int, n_warm: int,
+def _kernel(*refs, ensemble: bool, k: int, block_n: int, n_hot: int,
+            n_hot_blocks: int, warm_block_n: int, n_warm: int,
             n_probe: int, tail: int, quantized: bool):
+    """One grid sweep: hot blocks, then warm blocks, then the merge.
+
+    With ``ensemble`` (DESIGN.md §13) every key-panel stream carries E
+    stacked panels and every score is the weighted fused similarity
+    ``sum_e w[q, e] · cos(q_e, key_e)`` (`ref.fuse`, the oracle's own
+    primitive); routing (probe selection and the IVF index arithmetic)
+    runs once, on the unweighted pilot panel, so the candidate index
+    stream and all masks are shared across panels."""
+    if ensemble:
+        q_ref, w_ref, *refs = refs
+    else:
+        q_ref, *refs = refs
+    (qt_ref, thr_ref, hk_ref, hv_ref, ht_ref, hvid_ref, wk_ref, wscale_ref,
+     wv_ref, wt_ref, wvid_ref, wseq_ref, cent_ref, mem_ref, meta_ref,
+     out_s_ref, out_v_ref, out_wslot_ref, out_hslot_ref, out_flag_ref,
+     acc_s, acc_i, acc_v, wacc_s, wacc_p, wacc_i, wacc_v) = refs
     j = pl.program_id(0)
     nb = pl.num_programs(0)
 
@@ -134,28 +97,41 @@ def _kernel(q_ref, qt_ref, thr_ref, hk_ref, hv_ref, ht_ref, hvid_ref,
     def _init():
         acc_s[...] = jnp.full_like(acc_s, NEG_INF)
         acc_i[...] = jnp.zeros_like(acc_i)
+        acc_v[...] = jnp.zeros_like(acc_v)
         wacc_s[...] = jnp.full_like(wacc_s, NEG_INF)
         wacc_p[...] = jnp.full_like(wacc_p, POS_PAD)
         wacc_i[...] = jnp.zeros_like(wacc_i)
+        wacc_v[...] = jnp.zeros_like(wacc_v)
 
-    q = q_ref[...].astype(jnp.float32)                 # (Q, D)
-    qt = qt_ref[...]                                   # (Q,)
-    Q = q.shape[0]
+    q = q_ref[...].astype(jnp.float32)                 # (Q, D) | (E, Q, D)
+    qt = qt_ref[...]                                   # (Q, 1)
+    if ensemble:
+        w = w_ref[...].astype(jnp.float32)             # (Q, E)
+        E = q.shape[0]
+        route_q = q[0]
+
+        def scores(panel):                             # (E, N, D)|(E, Q, N, D)
+            return fuse([cosine(q[e], panel[e]) for e in range(E)], w)
+    else:
+        route_q = q
+
+        def scores(panel):
+            return cosine(q, panel)
+    Q = route_q.shape[0]
 
     # ---- hot tier: streamed block, tenant-masked running top-k ------
     @pl.when(j < n_hot_blocks)
     def _hot():
-        kblk = hk_ref[...].astype(jnp.float32)         # (BN, D)
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = scores(hk_ref[...].astype(jnp.float32))    # (Q, BN)
         col = j * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = (hv_ref[...] != 0)[None, :] \
-            & (ht_ref[...][None, :] == qt[:, None]) & (col < n_hot)
+        ok = (hv_ref[...] != 0) & (ht_ref[...] == qt) & (col < n_hot)
         s = jnp.where(ok, s, NEG_INF)
-        blk_s, blk_i = _select_topk(s, col, k)
-        new_s, new_i = _merge(acc_s[...], acc_i[...], blk_s, blk_i, k)
+        new_s, (new_i, new_v) = select_topk(
+            k, [(acc_s[...], acc_i[...], acc_v[...]),
+                (s, col, jnp.broadcast_to(hvid_ref[...], s.shape))])
         acc_s[...] = new_s
         acc_i[...] = new_i
+        acc_v[...] = new_v
 
     # ---- warm tier: streamed block, position-keyed running top-k ----
     @pl.when(j >= n_hot_blocks)
@@ -165,32 +141,36 @@ def _kernel(q_ref, qt_ref, thr_ref, hk_ref, hv_ref, ht_ref, hvid_ref,
         bucket = mem_ref.shape[1]
         cursor = meta_ref[0]
         indexed_total = meta_ref[1]
-        wv = wv_ref[...] != 0                          # (cap,) whole
-        wt = wt_ref[...]
-        wseq = wseq_ref[...]
+        wv = wv_ref[0] != 0                            # (cap,) whole
+        wt = wt_ref[0]
+        wvid = wvid_ref[0]
+        wseq = wseq_ref[0]
+        wkb = wk_ref[...]          # (WB, D) | (E, WB, D); int8 if quantized
         if quantized:
             # int8 warm block stays int8-resident: dequantize one
             # (Q, B, D) gather at a time, fp32 accumulation
-            wkb = wk_ref[...]                          # (WB, D) int8 VMEM
-            wscaleb = wscale_ref[...]                  # (WB,) fp32
+            wsc = wscale_ref[...]                      # (1, WB) | (E, WB)
 
-            def _panel_scores(local):
-                pan = wkb[local].astype(jnp.float32)
-                return jnp.einsum("qd,qbd->qb", q, pan) * wscaleb[local]
+            def panel_scores(local):
+                if ensemble:
+                    return fuse([cosine(q[e], wkb[e][local].astype(
+                        jnp.float32)) * wsc[e][local] for e in range(E)], w)
+                return cosine(q, wkb[local].astype(jnp.float32)) \
+                    * wsc[0][local]
         else:
-            wkb = wk_ref[...].astype(jnp.float32)      # (WB, D) VMEM
+            wkb = wkb.astype(jnp.float32)
 
-            def _panel_scores(local):
-                return jnp.einsum("qd,qbd->qb", q, wkb[local])
+            def panel_scores(local):
+                if ensemble:
+                    return scores(wkb[:, local])
+                return scores(wkb[local])
 
-        # probe selection: centroid matmul + n_probe argmax rounds —
-        # recomputed per block from the VMEM-resident centroids (tiny,
+        # probe selection: centroid scores + n_probe rounds — recomputed
+        # per block from the VMEM-resident centroids (tiny,
         # deterministic: every block sees identical probes)
-        csims = jax.lax.dot_general(
-            q, cent_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (Q, K)
+        csims = cosine(route_q, cent_ref[...].astype(jnp.float32))  # (Q, K)
         pcol = jax.lax.broadcasted_iota(jnp.int32, csims.shape, 1)
-        _, probes = _select_topk(csims, pcol, n_probe)  # (Q, n_probe)
+        _, (probes,) = select_topk(n_probe, [(csims, pcol)])  # (Q, n_probe)
 
         # IVF gather: one (Q, bucket) candidate panel per probe, index
         # arithmetic over the inverted lists, restricted to candidates
@@ -198,21 +178,24 @@ def _kernel(q_ref, qt_ref, thr_ref, hk_ref, hv_ref, ht_ref, hvid_ref,
         # exactly once across the sweep, in its own block, tagged with
         # its flat panel position so merge order is block-invariant
         mem = mem_ref[...]                             # (K, bucket)
-        ws, wp, wi = wacc_s[...], wacc_p[...], wacc_i[...]
-        for p in range(n_probe):
-            cand = mem[probes[:, p]]                   # (Q, bucket)
+        acc = (wacc_s[...], wacc_p[...], wacc_i[...], wacc_v[...])
+
+        def merge(acc, cand, fpos0, extra_ok):
             local = cand - base
             inblk = (cand >= 0) & (local >= 0) & (local < warm_block_n)
             gsafe = jnp.clip(cand, 0, n_warm - 1)
-            sc = _panel_scores(jnp.clip(local, 0, warm_block_n - 1))
-            okp = inblk & wv[gsafe] & (wt[gsafe] == qt[:, None]) \
-                & (wseq[gsafe] <= indexed_total)
-            sc = jnp.where(okp, sc, NEG_INF)
-            fpos = p * bucket \
-                + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-            fpos = jnp.where(okp, fpos, POS_PAD)
-            pb_s, pb_p, pb_i = _select_topk_pos(sc, fpos, gsafe, k)
-            ws, wp, wi = _merge_pos(ws, wp, wi, pb_s, pb_p, pb_i, k)
+            sc = panel_scores(jnp.clip(local, 0, warm_block_n - 1))
+            ok = inblk & wv[gsafe] & (wt[gsafe] == qt) & extra_ok(gsafe)
+            sc = jnp.where(ok, sc, NEG_INF)
+            fpos = fpos0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            fpos = jnp.where(ok, fpos, POS_PAD)
+            s, pay = select_topk(k, [acc, (sc, fpos, gsafe, wvid[gsafe])],
+                                 tie=True)
+            return (s, *pay)
+
+        for p in range(n_probe):
+            acc = merge(acc, mem[probes[:, p]], p * bucket,
+                        lambda g: wseq[g] <= indexed_total)
 
         # unindexed-tail scan: last `tail` ring writes, newest first
         if tail:
@@ -221,206 +204,156 @@ def _kernel(q_ref, qt_ref, thr_ref, hk_ref, hv_ref, ht_ref, hvid_ref,
             unindexed = wseq[pos] > indexed_total
             tcand = jnp.broadcast_to(jnp.where(unindexed, pos, -1),
                                      (Q, tail))
-            tlocal = tcand - base
-            inblk = (tcand >= 0) & (tlocal >= 0) & (tlocal < warm_block_n)
-            tsafe = jnp.clip(tcand, 0, n_warm - 1)
-            sc = _panel_scores(jnp.clip(tlocal, 0, warm_block_n - 1))
-            okt = inblk & wv[tsafe] & (wt[tsafe] == qt[:, None])
-            sc = jnp.where(okt, sc, NEG_INF)
-            fpos = n_probe * bucket \
-                + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-            fpos = jnp.where(okt, fpos, POS_PAD)
-            tb_s, tb_p, tb_i = _select_topk_pos(sc, fpos, tsafe, k)
-            ws, wp, wi = _merge_pos(ws, wp, wi, tb_s, tb_p, tb_i, k)
-        wacc_s[...] = ws
-        wacc_p[...] = wp
-        wacc_i[...] = wi
+            acc = merge(acc, tcand, n_probe * bucket, lambda g: True)
+        wacc_s[...], wacc_p[...], wacc_i[...], wacc_v[...] = acc
 
     # ---- best-of-tiers merge: once, after the last warm block -------
     @pl.when(j == nb - 1)
     def _finish():
-        rows = jnp.arange(Q)[:, None]
-        hs, hi = acc_s[...], acc_i[...]
-        ws_acc, wi_acc = wacc_s[...], wacc_i[...]
-        hvids = jnp.where(hs > NEG_INF / 2, hvid_ref[...][hi], -1)
-        wvids = jnp.where(ws_acc > NEG_INF / 2, wvid_ref[...][wi_acc], -1)
-        wslot_c = jnp.where(ws_acc > NEG_INF / 2, wi_acc, -1)
-        cand_s = jnp.concatenate([hs, ws_acc], axis=-1)     # (Q, 2k)
-        cand_v = jnp.concatenate([hvids, wvids], axis=-1)
-        cand_w = jnp.concatenate(
-            [jnp.full((Q, k), -1, jnp.int32), wslot_c], axis=-1)
-        ppos = jax.lax.broadcasted_iota(jnp.int32, cand_s.shape, 1)
-        out_s, out_p = _select_topk(cand_s, ppos, k)
+        hs, ws = acc_s[...], wacc_s[...]
+        live_h, live_w = hs > NEG_INF / 2, ws > NEG_INF / 2
+        kcol = jax.lax.broadcasted_iota(jnp.int32, hs.shape, 1)
+        out_s, (out_p, out_v, out_w) = select_topk(k, [
+            (hs, kcol, jnp.where(live_h, acc_v[...], -1),
+             jnp.full(hs.shape, -1, jnp.int32)),
+            (ws, kcol + k, jnp.where(live_w, wacc_v[...], -1),
+             jnp.where(live_w, wacc_i[...], -1))])
         out_s_ref[...] = out_s
-        out_v_ref[...] = cand_v[rows, out_p]
-        out_wslot_ref[...] = cand_w[rows, out_p]
-        out_hslot_ref[...] = hi[:, :1]
-        hit = out_s[:, 0] >= thr_ref[...]
-        out_flag_ref[...] = jnp.stack(
-            [hit, hit & (out_p[:, 0] < k)], -1).astype(jnp.int32)
+        out_v_ref[...] = out_v
+        out_wslot_ref[...] = out_w
+        out_hslot_ref[...] = acc_i[...][:, :1]
+        hit = out_s[:, :1] >= thr_ref[...]
+        hot_hit = hit & (out_p[:, :1] < k)
+        fcol = jax.lax.broadcasted_iota(jnp.int32, (Q, 2), 1)
+        out_flag_ref[...] = jnp.where(fcol == 0, hit, hot_hit).astype(
+            jnp.int32)
 
 
-def _ens_kernel(q_ref, w_ref, qt_ref, thr_ref, hk_ref, hv_ref, ht_ref,
-                hvid_ref, wk_ref, wscale_ref, wv_ref, wt_ref, wvid_ref,
-                wseq_ref, cent_ref, mem_ref, meta_ref, out_s_ref, out_v_ref,
-                out_wslot_ref, out_hslot_ref, out_flag_ref,
-                acc_s, acc_i, wacc_s, wacc_p, wacc_i, *, k: int, block_n: int,
-                n_hot: int, n_hot_blocks: int, warm_block_n: int, n_warm: int,
-                n_probe: int, tail: int, quantized: bool):
-    """E-panel variant of `_kernel` (DESIGN.md §13): the same grid,
-    phases, accumulators and merge, but every key-panel stream carries
-    E stacked panels and every score is the weighted fused similarity
-    ``sum_e w[q, e] · cos(q_e, key_e)``.  The cross-panel weighted sum
-    is one einsum contraction over the stacked per-panel scores —
-    `ref.ensemble_lookup` uses the identical primitive, which is what
-    keeps parity bit-exact (an unrolled multiply-add chain is not
-    fusion-stable across eager/jit graph boundaries).
-    Routing (probe selection and the IVF gather index arithmetic) runs
-    once, on the unweighted pilot panel — the candidate *index* stream
-    and all masks are shared across panels, which is where the
-    sequential path's E× overhead goes away."""
-    j = pl.program_id(0)
-    nb = pl.num_programs(0)
+def _fused_call(q, weights, q_tenants, thresholds, hot_keys, hot_valid,
+                hot_tenants, hot_value_ids, warm_keys, warm_valid,
+                warm_tenants, warm_value_ids, warm_write_seq, centroids,
+                members, cursor, indexed_total, warm_keys_q, warm_scales,
+                k, n_probe, tail, quantized, block_n, warm_block_n,
+                interpret):
+    """Shared pallas_call of both entry points; ``weights is None``
+    selects the single-embedder kernel (q (Q, D), panels (N, D)), else
+    the E-panel ensemble (q (E, Q, D), panels (E, N, D))."""
+    ensemble = weights is not None
+    lead = q.shape[:1] if ensemble else ()            # (E,) | ()
+    Q, D = q.shape[-2:]
+    n_hot = hot_keys.shape[-2]
+    n_clusters = centroids.shape[0]
+    n_probe = min(n_probe, n_clusters)
+    cap = warm_keys.shape[-2]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_s[...] = jnp.full_like(acc_s, NEG_INF)
-        acc_i[...] = jnp.zeros_like(acc_i)
-        wacc_s[...] = jnp.full_like(wacc_s, NEG_INF)
-        wacc_p[...] = jnp.full_like(wacc_p, POS_PAD)
-        wacc_i[...] = jnp.zeros_like(wacc_i)
+    if quantized:
+        wk_in = warm_keys_q.astype(jnp.int8)
+        wscale_in = warm_scales.astype(jnp.float32)
+    else:
+        wk_in = warm_keys.astype(jnp.float32)
+        wscale_in = jnp.zeros(lead + (cap,), jnp.float32)  # unread
+    if not ensemble:
+        wscale_in = wscale_in[None, :]                 # (1, cap) row
 
-    q = q_ref[...].astype(jnp.float32)                 # (E, Q, D)
-    w = w_ref[...].astype(jnp.float32)                 # (Q, E)
-    qt = qt_ref[...]                                   # (Q,)
-    E = q.shape[0]
-    Q = q.shape[1]
+    bn = min(block_n, n_hot)
+    n_blocks = -(-n_hot // bn)
+    pad = n_blocks * bn - n_hot
+    # bool VMEM refs are a Mosaic lowering hazard, and 1-D blocks miss
+    # its tiling: per-slot columns travel as int32 (1, N) rows
+    row = lambda x: jnp.asarray(x).astype(jnp.int32)[None, :]
+    hot_valid, hot_tenants, hot_value_ids = (
+        row(hot_valid), row(hot_tenants), row(hot_value_ids))
+    if pad:
+        hot_keys = jnp.pad(hot_keys, ((0, 0),) * len(lead)
+                           + ((0, pad), (0, 0)))
+        hot_valid = jnp.pad(hot_valid, ((0, 0), (0, pad)))
+        hot_tenants = jnp.pad(hot_tenants, ((0, 0), (0, pad)),
+                              constant_values=-1)
+        hot_value_ids = jnp.pad(hot_value_ids, ((0, 0), (0, pad)),
+                                constant_values=-1)
 
-    # ---- hot tier: streamed stacked block, fused running top-k ------
-    @pl.when(j < n_hot_blocks)
-    def _hot():
-        kblk = hk_ref[...].astype(jnp.float32)         # (E, BN, D)
-        pans = [jax.lax.dot_general(q[e], kblk[e], (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-                for e in range(E)]
-        s = jnp.einsum("qne,qe->qn", jnp.stack(pans, -1), w)
-        col = j * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = (hv_ref[...] != 0)[None, :] \
-            & (ht_ref[...][None, :] == qt[:, None]) & (col < n_hot)
-        s = jnp.where(ok, s, NEG_INF)
-        blk_s, blk_i = _select_topk(s, col, k)
-        new_s, new_i = _merge(acc_s[...], acc_i[...], blk_s, blk_i, k)
-        acc_s[...] = new_s
-        acc_i[...] = new_i
+    wb = min(warm_block_n or cap, cap)
+    n_wblocks = -(-cap // wb)
+    wpad = n_wblocks * wb - cap
+    if wpad:
+        # only the streamed panels pad (their BlockSpec tiles the padded
+        # extent); per-slot metadata stays (1, cap) — no candidate id
+        # ever reaches the pad rows, so they are dead weight, never read
+        wk_in = jnp.pad(wk_in, ((0, 0),) * len(lead)
+                        + ((0, wpad), (0, 0)))
+        wscale_in = jnp.pad(wscale_in, ((0, 0), (0, wpad)))
+    meta = jnp.stack([jnp.asarray(cursor, jnp.int32),
+                      jnp.asarray(indexed_total, jnp.int32)])
 
-    # ---- warm tier: pilot-routed, fused position-keyed top-k --------
-    @pl.when(j >= n_hot_blocks)
-    def _warm():
-        b = j - n_hot_blocks
-        base = b * warm_block_n
-        bucket = mem_ref.shape[1]
-        cursor = meta_ref[0]
-        indexed_total = meta_ref[1]
-        wv = wv_ref[...] != 0                          # (cap,) whole
-        wt = wt_ref[...]
-        wseq = wseq_ref[...]
-        if quantized:
-            # int8 stacked warm block: per-panel dequant + scale, then
-            # one stacked contraction with the weights — same primitive
-            # sequence as the oracle
-            wkb = wk_ref[...]                          # (E, WB, D) int8
-            wscaleb = wscale_ref[...]                  # (E, WB) fp32
-
-            def _panel_scores(local):
-                pans = [jnp.einsum("qd,qbd->qb", q[e],
-                                   wkb[e][local].astype(jnp.float32))
-                        * wscaleb[e][local] for e in range(E)]
-                return jnp.einsum("qbe,qe->qb", jnp.stack(pans, -1), w)
-        else:
-            wkb = wk_ref[...].astype(jnp.float32)      # (E, WB, D)
-
-            def _panel_scores(local):
-                pans = [jnp.einsum("qd,qbd->qb", q[e], wkb[e][local])
-                        for e in range(E)]
-                return jnp.einsum("qbe,qe->qb", jnp.stack(pans, -1), w)
-
-        # probe selection on the pilot panel only: one centroid matmul
-        # and one set of probes shared by all E panels
-        csims = jax.lax.dot_general(
-            q[0], cent_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (Q, K)
-        pcol = jax.lax.broadcasted_iota(jnp.int32, csims.shape, 1)
-        _, probes = _select_topk(csims, pcol, n_probe)  # (Q, n_probe)
-
-        # shared IVF gather indices: the (Q, bucket) candidate id panel
-        # and its masks are computed once per probe and reused by every
-        # panel's score term inside _panel_scores
-        mem = mem_ref[...]                             # (K, bucket)
-        ws, wp, wi = wacc_s[...], wacc_p[...], wacc_i[...]
-        for p in range(n_probe):
-            cand = mem[probes[:, p]]                   # (Q, bucket)
-            local = cand - base
-            inblk = (cand >= 0) & (local >= 0) & (local < warm_block_n)
-            gsafe = jnp.clip(cand, 0, n_warm - 1)
-            sc = _panel_scores(jnp.clip(local, 0, warm_block_n - 1))
-            okp = inblk & wv[gsafe] & (wt[gsafe] == qt[:, None]) \
-                & (wseq[gsafe] <= indexed_total)
-            sc = jnp.where(okp, sc, NEG_INF)
-            fpos = p * bucket \
-                + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-            fpos = jnp.where(okp, fpos, POS_PAD)
-            pb_s, pb_p, pb_i = _select_topk_pos(sc, fpos, gsafe, k)
-            ws, wp, wi = _merge_pos(ws, wp, wi, pb_s, pb_p, pb_i, k)
-
-        # unindexed-tail scan: last `tail` ring writes, newest first
-        if tail:
-            offs = jax.lax.broadcasted_iota(jnp.int32, (1, tail), 1)
-            pos = (cursor - 1 - offs) % n_warm         # (1, tail)
-            unindexed = wseq[pos] > indexed_total
-            tcand = jnp.broadcast_to(jnp.where(unindexed, pos, -1),
-                                     (Q, tail))
-            tlocal = tcand - base
-            inblk = (tcand >= 0) & (tlocal >= 0) & (tlocal < warm_block_n)
-            tsafe = jnp.clip(tcand, 0, n_warm - 1)
-            sc = _panel_scores(jnp.clip(tlocal, 0, warm_block_n - 1))
-            okt = inblk & wv[tsafe] & (wt[tsafe] == qt[:, None])
-            sc = jnp.where(okt, sc, NEG_INF)
-            fpos = n_probe * bucket \
-                + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-            fpos = jnp.where(okt, fpos, POS_PAD)
-            tb_s, tb_p, tb_i = _select_topk_pos(sc, fpos, tsafe, k)
-            ws, wp, wi = _merge_pos(ws, wp, wi, tb_s, tb_p, tb_i, k)
-        wacc_s[...] = ws
-        wacc_p[...] = wp
-        wacc_i[...] = wi
-
-    # ---- best-of-tiers merge: once, after the last warm block -------
-    @pl.when(j == nb - 1)
-    def _finish():
-        rows = jnp.arange(Q)[:, None]
-        hs, hi = acc_s[...], acc_i[...]
-        ws_acc, wi_acc = wacc_s[...], wacc_i[...]
-        hvids = jnp.where(hs > NEG_INF / 2, hvid_ref[...][hi], -1)
-        wvids = jnp.where(ws_acc > NEG_INF / 2, wvid_ref[...][wi_acc], -1)
-        wslot_c = jnp.where(ws_acc > NEG_INF / 2, wi_acc, -1)
-        cand_s = jnp.concatenate([hs, ws_acc], axis=-1)     # (Q, 2k)
-        cand_v = jnp.concatenate([hvids, wvids], axis=-1)
-        cand_w = jnp.concatenate(
-            [jnp.full((Q, k), -1, jnp.int32), wslot_c], axis=-1)
-        ppos = jax.lax.broadcasted_iota(jnp.int32, cand_s.shape, 1)
-        out_s, out_p = _select_topk(cand_s, ppos, k)
-        out_s_ref[...] = out_s
-        out_v_ref[...] = cand_v[rows, out_p]
-        out_wslot_ref[...] = cand_w[rows, out_p]
-        out_hslot_ref[...] = hi[:, :1]
-        hit = out_s[:, 0] >= thr_ref[...]
-        out_flag_ref[...] = jnp.stack(
-            [hit, hit & (out_p[:, 0] < k)], -1).astype(jnp.int32)
+    bucket = members.shape[1]
+    grid = (n_blocks + n_wblocks,)
+    whole = lambda shape: pl.BlockSpec(shape, lambda j: (0,) * len(shape))
+    # clamped index maps: hot tiles only advance through the hot steps,
+    # warm tiles only through the warm steps — a revisited index fetches
+    # nothing new, so neither stream pays for the other's phase
+    hj = lambda j: jnp.minimum(j, n_blocks - 1)
+    wj = lambda j: jnp.maximum(j - n_blocks, 0)
+    zl = (0,) * len(lead)
+    in_specs = [whole(q.shape)]
+    args = [q.astype(jnp.float32)]
+    if ensemble:
+        in_specs.append(whole(weights.shape))
+        args.append(weights.astype(jnp.float32))
+    in_specs += [
+        whole((Q, 1)),                                    # q_tenants
+        whole((Q, 1)),                                    # thresholds
+        pl.BlockSpec(lead + (bn, D), lambda j: zl + (hj(j), 0)),  # hot keys
+        pl.BlockSpec((1, bn), lambda j: (0, hj(j))),      # hot valid
+        pl.BlockSpec((1, bn), lambda j: (0, hj(j))),      # hot tenants
+        pl.BlockSpec((1, bn), lambda j: (0, hj(j))),      # hot value ids
+        pl.BlockSpec(lead + (wb, D), lambda j: zl + (wj(j), 0)),  # warm keys
+        pl.BlockSpec(wscale_in.shape[:1] + (wb,),
+                     lambda j: (0, wj(j))),               # warm row scales
+        whole((1, cap)),                                  # warm valid
+        whole((1, cap)),                                  # warm tenants
+        whole((1, cap)),                                  # warm value ids
+        whole((1, cap)),                                  # warm write seq
+        whole((n_clusters, D)),                           # centroids
+        whole((n_clusters, bucket)),                      # inverted lists
+        pl.BlockSpec(memory_space=pltpu.SMEM),            # cursor/indexed
+    ]
+    args += [
+        jnp.asarray(q_tenants, jnp.int32)[:, None],
+        jnp.asarray(thresholds, jnp.float32)[:, None],
+        hot_keys.astype(jnp.float32), hot_valid, hot_tenants,
+        hot_value_ids, wk_in, wscale_in, row(warm_valid),
+        row(warm_tenants), row(warm_value_ids), row(warm_write_seq),
+        centroids, members, meta]
+    out_shape = (jax.ShapeDtypeStruct((Q, k), jnp.float32),
+                 jax.ShapeDtypeStruct((Q, k), jnp.int32),
+                 jax.ShapeDtypeStruct((Q, k), jnp.int32),
+                 jax.ShapeDtypeStruct((Q, 1), jnp.int32),
+                 jax.ShapeDtypeStruct((Q, 2), jnp.int32))
+    fn = pl.pallas_call(
+        functools.partial(_kernel, ensemble=ensemble, k=k, block_n=bn,
+                          n_hot=n_hot, n_hot_blocks=n_blocks,
+                          warm_block_n=wb, n_warm=cap, n_probe=n_probe,
+                          tail=tail, quantized=quantized),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=(whole((Q, k)), whole((Q, k)), whole((Q, k)),
+                   whole((Q, 1)), whole((Q, 2))),
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((Q, k), dt) for dt in (
+            jnp.float32, jnp.int32, jnp.int32,           # hot s / slot / vid
+            jnp.float32, jnp.int32, jnp.int32, jnp.int32)],  # warm s/pos/slot/vid
+        interpret=interpret,
+    )
+    out_s, out_v, out_w, hslot, flags = fn(*args)
+    return (out_s, out_v, out_w, hslot[:, 0], flags[:, 1] != 0,
+            flags[:, 0] != 0)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "n_probe", "tail",
-                                             "block_n", "warm_block_n",
-                                             "interpret", "quantized"))
+_STATIC = ("k", "n_probe", "tail", "block_n", "warm_block_n", "interpret",
+           "quantized")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def cascade_lookup_ensemble(q, weights, q_tenants, thresholds,
                             hot_keys, hot_valid, hot_tenants, hot_value_ids,
                             warm_keys, warm_valid, warm_tenants,
@@ -431,7 +364,7 @@ def cascade_lookup_ensemble(q, weights, q_tenants, thresholds,
                             quantized: bool = False,
                             block_n: int = DEFAULT_BLOCK_N,
                             warm_block_n: int | None = None,
-                            interpret: bool = True):
+                            interpret: bool):
     """Fused E-panel ensemble cascade; signature/semantics of
     `ref.ensemble_lookup`.
 
@@ -445,108 +378,15 @@ def cascade_lookup_ensemble(q, weights, q_tenants, thresholds,
     and the running top-k stay single-copy.  Returns the 6-tuple of
     `cascade_lookup` with fused scores.
     """
-    q = q.astype(jnp.float32)
-    weights = weights.astype(jnp.float32)
-    q_tenants = q_tenants.astype(jnp.int32)
-    E, Q, D = q.shape
-    n_hot = hot_keys.shape[1]
-    n_clusters = centroids.shape[0]
-    n_probe = min(n_probe, n_clusters)
-    cap = warm_keys.shape[1]
-
-    if quantized:
-        wk_in = warm_keys_q
-        wscale_in = warm_scales.astype(jnp.float32)
-        wk_dtype = jnp.int8
-    else:
-        wk_in = warm_keys
-        wscale_in = jnp.zeros((E, cap), jnp.float32)    # unread placeholder
-        wk_dtype = jnp.float32
-
-    bn = min(block_n, n_hot)
-    n_blocks = -(-n_hot // bn)
-    pad = n_blocks * bn - n_hot
-    # bool VMEM refs are a Mosaic lowering hazard: masks travel as int32
-    hot_valid = hot_valid.astype(jnp.int32)
-    warm_valid = warm_valid.astype(jnp.int32)
-    if pad:
-        hot_keys = jnp.pad(hot_keys, ((0, 0), (0, pad), (0, 0)))
-        hot_valid = jnp.pad(hot_valid, (0, pad))
-        hot_tenants = jnp.pad(hot_tenants, (0, pad), constant_values=-1)
-        hot_value_ids = jnp.pad(hot_value_ids, (0, pad), constant_values=-1)
-
-    wb = min(warm_block_n or cap, cap)
-    n_wblocks = -(-cap // wb)
-    wpad = n_wblocks * wb - cap
-    wk_in = wk_in.astype(wk_dtype)
-    if wpad:
-        wk_in = jnp.pad(wk_in, ((0, 0), (0, wpad), (0, 0)))
-        wscale_in = jnp.pad(wscale_in, ((0, 0), (0, wpad)))
-    meta = jnp.stack([jnp.asarray(cursor, jnp.int32),
-                      jnp.asarray(indexed_total, jnp.int32)])
-
-    bucket = members.shape[1]
-    grid = (n_blocks + n_wblocks,)
-    whole = lambda shape: pl.BlockSpec(shape, lambda j: (0,) * len(shape))
-    # clamped index maps as in `cascade_lookup`, panel axis never tiled
-    hblk = lambda j: (jnp.minimum(j, n_blocks - 1),)
-    hblk3 = lambda j: (0, jnp.minimum(j, n_blocks - 1), 0)
-    wblk3 = lambda j: (0, jnp.maximum(j - n_blocks, 0), 0)
-    wblk2e = lambda j: (0, jnp.maximum(j - n_blocks, 0))
-    out_shape = (jax.ShapeDtypeStruct((Q, k), jnp.float32),
-                 jax.ShapeDtypeStruct((Q, k), jnp.int32),
-                 jax.ShapeDtypeStruct((Q, k), jnp.int32),
-                 jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-                 jax.ShapeDtypeStruct((Q, 2), jnp.int32))
-    fn = pl.pallas_call(
-        functools.partial(_ens_kernel, k=k, block_n=bn, n_hot=n_hot,
-                          n_hot_blocks=n_blocks, warm_block_n=wb,
-                          n_warm=cap, n_probe=n_probe, tail=tail,
-                          quantized=quantized),
-        grid=grid,
-        in_specs=[
-            whole((E, Q, D)),                             # stacked queries
-            whole((Q, E)),                                # mixture weights
-            whole((Q,)),                                  # q_tenants
-            whole((Q,)),                                  # thresholds
-            pl.BlockSpec((E, bn, D), hblk3),              # hot panel stream
-            pl.BlockSpec((bn,), hblk),                    # hot valid
-            pl.BlockSpec((bn,), hblk),                    # hot tenants
-            whole((n_blocks * bn,)),                      # hot value ids
-            pl.BlockSpec((E, wb, D), wblk3),              # warm panel stream
-            pl.BlockSpec((E, wb), wblk2e),                # warm row scales
-            whole((cap,)),                                # warm valid
-            whole((cap,)),                                # warm tenants
-            whole((cap,)),                                # warm value ids
-            whole((cap,)),                                # warm write seq
-            whole((n_clusters, D)),                       # centroids
-            whole((n_clusters, bucket)),                  # inverted lists
-            whole((2,)),                                  # cursor/indexed
-        ],
-        out_specs=(whole((Q, k)), whole((Q, k)), whole((Q, k)),
-                   whole((Q, 1)), whole((Q, 2))),
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((Q, k), jnp.float32),
-            pltpu.VMEM((Q, k), jnp.int32),
-            pltpu.VMEM((Q, k), jnp.float32),
-            pltpu.VMEM((Q, k), jnp.int32),
-            pltpu.VMEM((Q, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )
-    out_s, out_v, out_w, hslot, flags = fn(
-        q, weights, q_tenants, thresholds.astype(jnp.float32), hot_keys,
-        hot_valid, hot_tenants, hot_value_ids, wk_in, wscale_in,
-        warm_valid, warm_tenants, warm_value_ids, warm_write_seq, centroids,
-        members, meta)
-    return (out_s, out_v, out_w, hslot[:, 0], flags[:, 1] != 0,
-            flags[:, 0] != 0)
+    return _fused_call(
+        q, weights, q_tenants, thresholds, hot_keys, hot_valid, hot_tenants,
+        hot_value_ids, warm_keys, warm_valid, warm_tenants, warm_value_ids,
+        warm_write_seq, centroids, members, cursor, indexed_total,
+        warm_keys_q, warm_scales, k, n_probe, tail, quantized, block_n,
+        warm_block_n, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "n_probe", "tail",
-                                             "block_n", "warm_block_n",
-                                             "interpret", "quantized"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def cascade_lookup(q, q_tenants, thresholds,
                    hot_keys, hot_valid, hot_tenants, hot_value_ids,
                    warm_keys, warm_valid, warm_tenants, warm_value_ids,
@@ -555,7 +395,7 @@ def cascade_lookup(q, q_tenants, thresholds,
                    k: int = 1, n_probe: int = 8, tail: int = 0, *,
                    quantized: bool = False,
                    block_n: int = DEFAULT_BLOCK_N,
-                   warm_block_n: int | None = None, interpret: bool = True):
+                   warm_block_n: int | None = None, interpret: bool):
     """Array-level fused cascade; signature/semantics of `ref.py`.
 
     q: (Q, D) unit-norm.  Returns (scores (Q, k), value_ids (Q, k),
@@ -566,103 +406,9 @@ def cascade_lookup(q, q_tenants, thresholds,
     whole-panel residency); results are bit-identical for every block
     count.
     """
-    q = q.astype(jnp.float32)
-    q_tenants = q_tenants.astype(jnp.int32)
-    Q, D = q.shape
-    n_hot = hot_keys.shape[0]
-    n_clusters = centroids.shape[0]
-    n_probe = min(n_probe, n_clusters)
-    cap = warm_keys.shape[0]
-
-    if quantized:
-        wk_in = warm_keys_q
-        wscale_in = warm_scales.astype(jnp.float32)
-        wk_dtype = jnp.int8
-    else:
-        wk_in = warm_keys
-        wscale_in = jnp.zeros((cap,), jnp.float32)      # unread placeholder
-        wk_dtype = jnp.float32
-
-    bn = min(block_n, n_hot)
-    n_blocks = -(-n_hot // bn)
-    pad = n_blocks * bn - n_hot
-    # bool VMEM refs are a Mosaic lowering hazard: masks travel as int32
-    hot_valid = hot_valid.astype(jnp.int32)
-    warm_valid = warm_valid.astype(jnp.int32)
-    if pad:
-        hot_keys = jnp.pad(hot_keys, ((0, pad), (0, 0)))
-        hot_valid = jnp.pad(hot_valid, (0, pad))
-        hot_tenants = jnp.pad(hot_tenants, (0, pad), constant_values=-1)
-        hot_value_ids = jnp.pad(hot_value_ids, (0, pad), constant_values=-1)
-
-    wb = min(warm_block_n or cap, cap)
-    n_wblocks = -(-cap // wb)
-    wpad = n_wblocks * wb - cap
-    wk_in = wk_in.astype(wk_dtype)
-    if wpad:
-        # only the streamed panels pad (their BlockSpec tiles the padded
-        # extent); per-slot metadata stays (cap,) — no candidate id ever
-        # reaches the pad rows, so they are dead weight, never read
-        wk_in = jnp.pad(wk_in, ((0, wpad), (0, 0)))
-        wscale_in = jnp.pad(wscale_in, (0, wpad))
-    meta = jnp.stack([jnp.asarray(cursor, jnp.int32),
-                      jnp.asarray(indexed_total, jnp.int32)])
-
-    bucket = members.shape[1]
-    grid = (n_blocks + n_wblocks,)
-    whole = lambda shape: pl.BlockSpec(shape, lambda j: (0,) * len(shape))
-    # clamped index maps: hot tiles only advance through the hot steps,
-    # warm tiles only through the warm steps — a revisited index fetches
-    # nothing new, so neither stream pays for the other's phase
-    hblk = lambda j: (jnp.minimum(j, n_blocks - 1),)
-    hblk2 = lambda j: (jnp.minimum(j, n_blocks - 1), 0)
-    wblk = lambda j: (jnp.maximum(j - n_blocks, 0),)
-    wblk2 = lambda j: (jnp.maximum(j - n_blocks, 0), 0)
-    out_shape = (jax.ShapeDtypeStruct((Q, k), jnp.float32),
-                 jax.ShapeDtypeStruct((Q, k), jnp.int32),
-                 jax.ShapeDtypeStruct((Q, k), jnp.int32),
-                 jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-                 jax.ShapeDtypeStruct((Q, 2), jnp.int32))
-    fn = pl.pallas_call(
-        functools.partial(_kernel, k=k, block_n=bn, n_hot=n_hot,
-                          n_hot_blocks=n_blocks, warm_block_n=wb,
-                          n_warm=cap, n_probe=n_probe, tail=tail,
-                          quantized=quantized),
-        grid=grid,
-        in_specs=[
-            whole((Q, D)),                                # q
-            whole((Q,)),                                  # q_tenants
-            whole((Q,)),                                  # thresholds
-            pl.BlockSpec((bn, D), hblk2),                 # hot keys stream
-            pl.BlockSpec((bn,), hblk),                    # hot valid
-            pl.BlockSpec((bn,), hblk),                    # hot tenants
-            whole((n_blocks * bn,)),                      # hot value ids
-            pl.BlockSpec((wb, D), wblk2),                 # warm keys stream
-            pl.BlockSpec((wb,), wblk),                    # warm row scales
-            whole((cap,)),                                # warm valid
-            whole((cap,)),                                # warm tenants
-            whole((cap,)),                                # warm value ids
-            whole((cap,)),                                # warm write seq
-            whole((n_clusters, D)),                       # centroids
-            whole((n_clusters, bucket)),                  # inverted lists
-            whole((2,)),                                  # cursor/indexed
-        ],
-        out_specs=(whole((Q, k)), whole((Q, k)), whole((Q, k)),
-                   whole((Q, 1)), whole((Q, 2))),
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((Q, k), jnp.float32),
-            pltpu.VMEM((Q, k), jnp.int32),
-            pltpu.VMEM((Q, k), jnp.float32),
-            pltpu.VMEM((Q, k), jnp.int32),
-            pltpu.VMEM((Q, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )
-    out_s, out_v, out_w, hslot, flags = fn(
-        q, q_tenants, thresholds.astype(jnp.float32), hot_keys, hot_valid,
-        hot_tenants, hot_value_ids, wk_in, wscale_in,
-        warm_valid, warm_tenants, warm_value_ids, warm_write_seq, centroids,
-        members, meta)
-    return (out_s, out_v, out_w, hslot[:, 0], flags[:, 1] != 0,
-            flags[:, 0] != 0)
+    return _fused_call(
+        q, None, q_tenants, thresholds, hot_keys, hot_valid, hot_tenants,
+        hot_value_ids, warm_keys, warm_valid, warm_tenants, warm_value_ids,
+        warm_write_seq, centroids, members, cursor, indexed_total,
+        warm_keys_q, warm_scales, k, n_probe, tail, quantized, block_n,
+        warm_block_n, interpret)

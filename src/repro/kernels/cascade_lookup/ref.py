@@ -30,6 +30,33 @@ import jax.numpy as jnp
 NEG = -1e30
 
 
+@jax.jit
+def cosine(q, keys):
+    """Scores of unit-norm queries q (Q, D) against keys shared by every
+    query (N, D), or gathered per query (Q, N, D) -> (Q, N).
+
+    Written as an elementwise product summed over D rather than a dot:
+    each score is then the same f32 sum whatever N is and however the
+    panel is cut into blocks, so the kernel's streamed blocks, this
+    oracle's whole panel and the tiers' four-op path agree bit for bit.
+    XLA's CPU dot rounds differently with the panel's shape, and on a
+    TPU an f32 dot defaults to bf16 passes; this form is f32 on both.
+    Jitted, so that called eagerly the product and the sum still fuse
+    into one loop — run as two separate ops they round differently.
+    """
+    if keys.ndim == 2:
+        keys = keys[None]
+    return jnp.sum(q[:, None, :] * keys, axis=-1)
+
+
+@jax.jit
+def fuse(panels, weights):
+    """Weighted cross-panel sum of E per-embedder (Q, N) score panels
+    under (Q, E) mixture weights -> (Q, N), in the same elementwise
+    form as `cosine` (bitwise independent of N)."""
+    return jnp.sum(jnp.stack(panels, -1) * weights[:, None, :], axis=-1)
+
+
 def cascade_lookup(q, q_tenants, thresholds,
                    hot_keys, hot_valid, hot_tenants, hot_value_ids,
                    warm_keys, warm_valid, warm_tenants, warm_value_ids,
@@ -51,7 +78,7 @@ def cascade_lookup(q, q_tenants, thresholds,
     rows = jnp.arange(Q)[:, None]
 
     # hot tier: exact tenant-masked top-k
-    hs_all = q @ hot_keys.T                                        # (Q, Nh)
+    hs_all = cosine(q, hot_keys)                                   # (Q, Nh)
     ok = hot_valid[None, :] & (hot_tenants[None, :] == q_tenants[:, None])
     hs_all = jnp.where(ok, hs_all, NEG)
     hs, hslots = jax.lax.top_k(hs_all, k)
@@ -61,7 +88,7 @@ def cascade_lookup(q, q_tenants, thresholds,
     cap = warm_keys.shape[0]
     n_clusters, bucket = members.shape
     n_probe = min(n_probe, n_clusters)
-    csims = q @ centroids.T                                        # (Q, K)
+    csims = cosine(q, centroids)                                   # (Q, K)
     _, probes = jax.lax.top_k(csims, n_probe)
     cand = members[probes].reshape(Q, n_probe * bucket)
     is_tail = jnp.zeros(cand.shape, bool)
@@ -80,9 +107,9 @@ def cascade_lookup(q, q_tenants, thresholds,
     if quantized:
         # int8 panel, fp32 accumulation: dequantize per candidate row
         panel = warm_keys_q[safe].astype(jnp.float32)
-        wscores = jnp.einsum("qd,qnd->qn", q, panel) * warm_scales[safe]
+        wscores = cosine(q, panel) * warm_scales[safe]
     else:
-        wscores = jnp.einsum("qd,qnd->qn", q, warm_keys[safe])
+        wscores = cosine(q, warm_keys[safe])
     wscores = jnp.where(ok, wscores, NEG)
     ws, wi = jax.lax.top_k(wscores, k)
     wslots = safe[rows, wi]
@@ -127,8 +154,8 @@ def ensemble_lookup(q, weights, q_tenants, thresholds,
 
     The fused score of a candidate row is
     ``sum_e weights[q, e] * cos(q_e, key_e[row])``.  The cross-panel
-    weighted sum is one einsum contraction over the stacked per-panel
-    scores — a single primitive, so eager and jitted evaluation agree
+    weighted sum is one reduction over the stacked per-panel scores
+    (`fuse`) — a single primitive, so eager and jitted evaluation agree
     bitwise and the kernel reproduces it exactly (an unrolled
     multiply-add chain is NOT fusion-stable: XLA reassociates it
     differently across surrounding graphs).  Masking applies after the
@@ -145,8 +172,8 @@ def ensemble_lookup(q, weights, q_tenants, thresholds,
     rows = jnp.arange(Q)[:, None]
 
     # hot tier: fused tenant-masked top-k over the stacked panels
-    hot_pans = [q[e] @ hot_keys[e].T for e in range(E)]            # E×(Q, Nh)
-    hs_all = jnp.einsum("qne,qe->qn", jnp.stack(hot_pans, -1), weights)
+    hot_pans = [cosine(q[e], hot_keys[e]) for e in range(E)]       # E×(Q, Nh)
+    hs_all = fuse(hot_pans, weights)
     ok = hot_valid[None, :] & (hot_tenants[None, :] == q_tenants[:, None])
     hs_all = jnp.where(ok, hs_all, NEG)
     hs, hslots = jax.lax.top_k(hs_all, k)
@@ -156,7 +183,7 @@ def ensemble_lookup(q, weights, q_tenants, thresholds,
     cap = warm_keys.shape[1] if not quantized else warm_keys_q.shape[1]
     n_clusters, bucket = members.shape
     n_probe = min(n_probe, n_clusters)
-    csims = q[0] @ centroids.T                  # pilot routing (Q, K)
+    csims = cosine(q[0], centroids)             # pilot routing (Q, K)
     _, probes = jax.lax.top_k(csims, n_probe)
     cand = members[probes].reshape(Q, n_probe * bucket)
     is_tail = jnp.zeros(cand.shape, bool)
@@ -176,12 +203,10 @@ def ensemble_lookup(q, weights, q_tenants, thresholds,
     def _panel(e):
         if quantized:
             pan = warm_keys_q[e][safe].astype(jnp.float32)
-            return jnp.einsum("qd,qnd->qn", q[e], pan) \
-                * warm_scales[e][safe]
-        return jnp.einsum("qd,qnd->qn", q[e], warm_keys[e][safe])
+            return cosine(q[e], pan) * warm_scales[e][safe]
+        return cosine(q[e], warm_keys[e][safe])
 
-    warm_pans = [_panel(e) for e in range(E)]
-    wscores = jnp.einsum("qne,qe->qn", jnp.stack(warm_pans, -1), weights)
+    wscores = fuse([_panel(e) for e in range(E)], weights)
     wscores = jnp.where(ok, wscores, NEG)
     ws, wi = jax.lax.top_k(wscores, k)
     wslots = safe[rows, wi]

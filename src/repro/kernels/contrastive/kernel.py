@@ -23,14 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BIG = 1e9
-
-
-def _pair_dist(e1, e2):
-    num = jnp.sum(e1 * e2, axis=-1)
-    den = (jnp.sqrt(jnp.sum(e1 * e1, axis=-1)) *
-           jnp.sqrt(jnp.sum(e2 * e2, axis=-1)))
-    return 1.0 - num / jnp.maximum(den, 1e-9)
+from repro.kernels.contrastive.ref import BIG, pair_distance
 
 
 def _kernel(e1_ref, e2_ref, lab_ref, out_ref, stats, *, margin: float,
@@ -49,7 +42,7 @@ def _kernel(e1_ref, e2_ref, lab_ref, out_ref, stats, *, margin: float,
     e1 = e1_ref[...].astype(jnp.float32)
     e2 = e2_ref[...].astype(jnp.float32)
     lab = lab_ref[...]
-    d = _pair_dist(e1, e2)                                 # (BB,)
+    d = pair_distance(e1, e2)                              # (BB,)
     row = jb * block_b + jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
     in_range = row < n_total
     is_pos = (lab == 1) & in_range
@@ -80,7 +73,7 @@ def _kernel(e1_ref, e2_ref, lab_ref, out_ref, stats, *, margin: float,
 
 @functools.partial(jax.jit, static_argnames=("margin", "block_b", "interpret"))
 def contrastive_components(e1, e2, labels, margin: float = 0.5, *,
-                           block_b: int = 1024, interpret: bool = True):
+                           block_b: int = 1024, interpret: bool):
     """e1, e2: (B, D); labels: (B,) int -> (pos_loss, neg_loss, min_neg,
     max_pos) as a (4,) float32 vector, matching ref.contrastive_components."""
     B, D = e1.shape

@@ -6,8 +6,12 @@ resident; the MXU computes the (Q × BLOCK_N) score panel; and a running
 top-k (scores+indices) is carried in VMEM scratch across grid steps —
 the (Q × N) score matrix never exists in HBM.
 
-Top-k selection uses k rounds of masked argmax (k is small for cache
-lookup, typically 1-4), which vectorises on the VPU — no sort network.
+Top-k selection (`select_topk`, shared with `kernels/cascade_lookup`)
+runs k rounds of max + first-column-at-max over the candidate segments
+(accumulator first, then the block), which vectorises on the VPU — no
+sort network and no gather: the winner is found by an iota compare and
+its payload read back by a masked sum, the only forms Mosaic lowers.
+Masks travel as int32 (1, N) rows so their blocks tile like the keys.
 """
 from __future__ import annotations
 
@@ -20,19 +24,55 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_N = 512
+# tie-key sentinel for masked / consumed candidates (larger than any
+# real tie key the callers use)
+POS_PAD = 2 ** 30
 
 
-def _select_topk(scores, idx, k):
-    """scores: (Q, M) candidates with global indices idx (Q, M) ->
-    (Q, k) best by k rounds of masked argmax (unrolled, k small)."""
-    out_s, out_i = [], []
-    for _ in range(k):
-        best = jnp.argmax(scores, axis=-1)                       # (Q,)
-        rows = jnp.arange(scores.shape[0])
-        out_s.append(scores[rows, best])
-        out_i.append(idx[rows, best])
-        scores = scores.at[rows, best].set(NEG_INF)
-    return jnp.stack(out_s, -1), jnp.stack(out_i, -1)
+def select_topk(k, segments, tie: bool = False):
+    """Top-k over the concatenation of ``segments`` without building it.
+
+    Each segment is ``(scores (Q, M), *payloads)`` with int32 payloads of
+    the same shape; candidates are ordered segment by segment, column by
+    column.  Each of the k rounds takes the best score, then (``tie``)
+    the lowest first payload among the entries holding it, then the
+    earliest candidate — `jax.lax.top_k`'s lowest-index-first order,
+    with the tie key taking precedence over position when given.
+    Returns ``(scores (Q, k), [payload (Q, k), ...])``.
+    """
+    segs = [list(s) for s in segments]
+    Q = segs[0][0].shape[0]
+    n_pay = len(segs[0]) - 1
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (Q, k), 1)
+    out_s = jnp.full((Q, k), NEG_INF, jnp.float32)
+    out_p = [jnp.zeros((Q, k), jnp.int32) for _ in range(n_pay)]
+    cols = [jax.lax.broadcasted_iota(jnp.int32, s[0].shape, 1) for s in segs]
+    for r in range(k):
+        m = functools.reduce(jnp.maximum, [
+            jnp.max(s[0], axis=-1, keepdims=True) for s in segs])
+        at = [s[0] >= m for s in segs]
+        if tie:
+            t = functools.reduce(jnp.minimum, [
+                jnp.min(jnp.where(a, s[1], POS_PAD), axis=-1, keepdims=True)
+                for a, s in zip(at, segs)])
+            at = [a & (s[1] == t) for a, s in zip(at, segs)]
+        taken = jnp.zeros((Q, 1), bool)
+        vals = [jnp.zeros((Q, 1), jnp.int32) for _ in range(n_pay)]
+        for a, s, col in zip(at, segs, cols):
+            width = s[0].shape[1]
+            first = jnp.min(jnp.where(a, col, width), axis=-1, keepdims=True)
+            sel = (col == first) & ~taken
+            taken = taken | (first < width)
+            for i in range(n_pay):
+                vals[i] = vals[i] + jnp.sum(jnp.where(sel, s[1 + i], 0),
+                                            axis=-1, keepdims=True)
+            s[0] = jnp.where(sel, NEG_INF, s[0])
+            if tie:
+                s[1] = jnp.where(sel, POS_PAD, s[1])
+        here = kcol == r
+        out_s = jnp.where(here, m, out_s)
+        out_p = [jnp.where(here, v, o) for v, o in zip(vals, out_p)]
+    return out_s, out_p
 
 
 def _kernel(q_ref, keys_ref, valid_ref, out_s_ref, out_i_ref,
@@ -47,17 +87,13 @@ def _kernel(q_ref, keys_ref, valid_ref, out_s_ref, out_i_ref,
 
     q = q_ref[...].astype(jnp.float32)                # (Q, D)
     kblk = keys_ref[...].astype(jnp.float32)          # (BN, D)
-    valid = valid_ref[...]                            # (BN,)
     s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Q, BN)
     col = j * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    ok = valid[None, :] & (col < n_total)
+    ok = (valid_ref[...] != 0) & (col < n_total)      # (1, BN) row
     s = jnp.where(ok, s, NEG_INF)
 
-    blk_s, blk_rel = _select_topk(s, col, k)          # (Q, k) each
-    cand_s = jnp.concatenate([acc_s[...], blk_s], axis=-1)   # (Q, 2k)
-    cand_i = jnp.concatenate([acc_i[...], blk_rel], axis=-1)
-    new_s, new_i = _select_topk(cand_s, cand_i, k)
+    new_s, (new_i,) = select_topk(k, [(acc_s[...], acc_i[...]), (s, col)])
     acc_s[...] = new_s
     acc_i[...] = new_i
 
@@ -69,16 +105,17 @@ def _kernel(q_ref, keys_ref, valid_ref, out_s_ref, out_i_ref,
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
 def cosine_topk(q, keys, valid, k: int = 1, *,
-                block_n: int = DEFAULT_BLOCK_N, interpret: bool = True):
+                block_n: int = DEFAULT_BLOCK_N, interpret: bool):
     """q: (Q, D); keys: (N, D); valid: (N,).  -> ((Q,k) scores, (Q,k) idx)."""
     Q, D = q.shape
     N = keys.shape[0]
     bn = min(block_n, N)
     n_blocks = -(-N // bn)
     pad = n_blocks * bn - N
+    valid = valid.astype(jnp.int32)[None, :]          # (1, N) int32 row
     if pad:
         keys = jnp.pad(keys, ((0, pad), (0, 0)))
-        valid = jnp.pad(valid, (0, pad))
+        valid = jnp.pad(valid, ((0, 0), (0, pad)))
 
     grid = (n_blocks,)
     out_shape = (jax.ShapeDtypeStruct((Q, k), jnp.float32),
@@ -89,7 +126,7 @@ def cosine_topk(q, keys, valid, k: int = 1, *,
         in_specs=[
             pl.BlockSpec((Q, D), lambda j: (0, 0)),
             pl.BlockSpec((bn, D), lambda j: (j, 0)),
-            pl.BlockSpec((bn,), lambda j: (j,)),
+            pl.BlockSpec((1, bn), lambda j: (0, j)),
         ],
         out_specs=(pl.BlockSpec((Q, k), lambda j: (0, 0)),
                    pl.BlockSpec((Q, k), lambda j: (0, 0))),

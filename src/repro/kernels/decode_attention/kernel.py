@@ -57,7 +57,7 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr, acc_scr):
 
 @functools.partial(jax.jit, static_argnames=("block_l", "interpret"))
 def decode_attention(q, k, v, kv_valid, *, block_l: int = DEFAULT_BLOCK_L,
-                     interpret: bool = True):
+                     interpret: bool):
     """q: (B, H, hd); k, v: (B, L, KV, hd); kv_valid: (B, L) bool."""
     B, H, hd = q.shape
     L, KV = k.shape[1], k.shape[2]

@@ -94,7 +94,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_kv: int = DEFAULT_BLOCK_KV,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (B, H, Sq, hd); k, v: (B, KV, Skv, hd) -> (B, H, Sq, hd)."""
     B, H, Sq, hd = q.shape
     KV, Skv = k.shape[1], k.shape[2]

@@ -16,18 +16,14 @@ HBM_BANDWIDTH = 819e9             # B/s
 ICI_LINK_BANDWIDTH = 50e9         # B/s per link
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    # AxisType landed after jax 0.4; older versions default to Auto anyway
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -36,7 +32,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     data = min(data, n)
     model = min(model, max(n // data, 1))
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_mesh_kwargs(2))
+                         axis_types=_auto(2))
 
 
 def make_cache_mesh(model: int | None = None):

@@ -2,7 +2,14 @@
 optional semantic cache in front (the paper's deployment).
 
     PYTHONPATH=src python -m repro.launch.serve \
-        --arch phi3-mini-3.8b --smoke --requests 32 --batch 8 --cache
+        --arch phi3-mini-3.8b --requests 32 --batch 8 --cache
+
+``--smoke`` (the default) builds the reduced embedder (2 layers,
+d_model 128); ``--no-smoke`` serves the published modernbert-149m
+widths (22 layers, d_model 768, vocab 50,368) with cache keys at
+D=768.  The decoder behind the cache is the ``--arch`` config reduced
+to its smoke size in both settings (`build_engine` prints which).
+`chip_smoke.py` drives the same construction (`build_stack`).
 
 ``--tiered`` swaps the flat SemanticCache for the tiered CacheService;
 ``--cache-shards N`` then lays its warm tier over an N-device `model`
@@ -31,14 +38,19 @@ every N batches, so the file holds a time series.  Validate with
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from dataclasses import dataclass
+from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
-from repro.configs import ASSIGNED_ARCHS, get_config
+from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core import EmbedderTrainer, FinetuneConfig, SemanticCache
 from repro.data import HashTokenizer, make_pair_dataset, make_query_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_lm, split
 from repro.obs import Telemetry, write_jsonl
 from repro.serving import CachedLLMService, ServeEngine
@@ -82,10 +94,17 @@ def run_scenario(args):
               f"{row['audited_false_hits']} false; floors {floors}")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="phi3-mini-3.8b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--arch", default="phi3-mini-3.8b",
+                    help="decoder behind the cache; always served at its "
+                         "reduced (2-layer, d_model 128) smoke size")
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced embedder (2 layers, d_model 128, cache "
+                         "keys D=128); --no-smoke serves the published "
+                         "modernbert-149m widths (22 layers, 768 wide, "
+                         "D=768) and, with --scenario, the full trace")
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--max-new-tokens", type=int, default=8)
@@ -150,9 +169,16 @@ def main():
                     metavar="N",
                     help="with --metrics-json: also append a snapshot "
                          "every N batches (0 = final snapshot only)")
-    args = ap.parse_args()
+    return ap
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
+    """Parse and cross-check the launcher's flags (options that need
+    the tiered service switch it on)."""
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.scenario:
-        return run_scenario(args)
+        return args
     if args.metrics_json and not args.cache:
         ap.error("--metrics-json instruments the cached serving path; "
                  "add --cache")
@@ -171,83 +197,116 @@ def main():
         ap.error("--ensemble and --learned-embedder are exclusive: the "
                  "§11 refresh re-embeds one key panel, the §13 ensemble "
                  "serves several (swap panels via publish_panel instead)")
+    return args
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = cfg.reduced()
+
+@dataclass
+class Stack:
+    """Everything `build_stack` constructs for the cached serving path."""
+    decoder: ModelConfig
+    embedder: ModelConfig
+    tokenizer: HashTokenizer
+    trainer: EmbedderTrainer
+    telemetry: Telemetry
+    cache: object                      # CacheService | SemanticCache
+    service: CachedLLMService
+
+
+def build_engine(args) -> Tuple[ModelConfig, ServeEngine]:
+    """The decoder behind the cache, always at its reduced size."""
+    cfg = get_config(args.arch).reduced()
     pv, _ = split(init_lm(cfg, jax.random.PRNGKey(0)))
-    engine = ServeEngine(cfg, pv, max_len=64)
-    print(f"serving {cfg.name} ({cfg.param_count():,} params)")
+    print(f"decoder {cfg.name}: reduced to {cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}, vocab {cfg.vocab_size} "
+          f"({cfg.param_count():,} params)")
+    return cfg, ServeEngine(cfg, pv, max_len=64)
 
-    if not args.cache:
-        rng = np.random.default_rng(0)
-        t0 = time.perf_counter()
-        for i in range(0, args.requests, args.batch):
-            prompts = rng.integers(0, cfg.vocab_size,
-                                   (args.batch, 16)).astype(np.int32)
-            res = engine.generate(prompts, args.max_new_tokens)
-            print(f"batch {i//args.batch}: generated "
-                  f"{res.tokens.shape[1]} tokens x {res.tokens.shape[0]}")
-        print(f"total {time.perf_counter() - t0:.1f}s")
-        return
 
-    enc_cfg = get_config("modernbert-149m").reduced(vocab_size=4096)
+def build_embedder(args) -> Tuple[ModelConfig, HashTokenizer,
+                                  EmbedderTrainer]:
+    """The cache's embedder, fine-tuned for one epoch on seeded
+    synthetic medical pairs; published widths unless ``args.smoke``.
+    The published model trains at the paper's learning rate; 5e-4 suits
+    only the reduced one, and at 768 wide it collapses every embedding
+    into a narrow cone."""
+    enc_cfg = get_config("modernbert-149m")
+    ft = FinetuneConfig(epochs=1, batch_size=32, max_len=24)
+    if args.smoke:
+        enc_cfg = enc_cfg.reduced(vocab_size=4096)
+        ft = dataclasses.replace(ft, lr=5e-4)
+    print(f"embedder {enc_cfg.name}: {enc_cfg.n_layers} layers, d_model "
+          f"{enc_cfg.d_model}, d_ff {enc_cfg.d_ff}, vocab "
+          f"{enc_cfg.vocab_size} ({enc_cfg.param_count():,} params); "
+          f"cache keys D={enc_cfg.d_model}")
     tok = HashTokenizer(vocab_size=enc_cfg.vocab_size)
-    trainer = EmbedderTrainer(enc_cfg, FinetuneConfig(
-        epochs=1, batch_size=32, lr=5e-4, max_len=24))
-    trainer.fit(make_pair_dataset("medical", 512, seed=0), tok)
+    trainer = EmbedderTrainer(enc_cfg, ft)
+    fit = trainer.fit(make_pair_dataset("medical", 512, seed=0), tok)
+    print(f"fine-tuned the embedder: {fit['steps']} steps in "
+          f"{fit['train_seconds']:.1f}s")
+    return enc_cfg, tok, trainer
+
+
+def build_cache(args, enc_cfg: ModelConfig, trainer: EmbedderTrainer,
+                tok: HashTokenizer, telemetry: Telemetry):
+    """The flat SemanticCache, or the tiered CacheService the flags
+    describe."""
+    if not args.tiered:
+        return SemanticCache(capacity=4096, dim=enc_cfg.d_model,
+                             threshold=args.threshold, telemetry=telemetry)
+    from repro.cache_service import (
+        CacheConfig, CacheService, EmbedderRefreshPolicy, EnsembleConfig,
+        LearningConfig, ShardingConfig, StalenessConfig, TieringConfig,
+    )
+    from repro.launch.mesh import make_cache_mesh
+    mesh = make_cache_mesh(args.cache_shards) if args.cache_shards \
+        else None
+    # smoke-scale refresh policy: trip the trigger inside a short
+    # stream, backfill thin splits from the medical grammar (§11)
+    refresh = EmbedderRefreshPolicy(
+        min_pairs=24, min_class=4, refresh_interval=32,
+        synth_domain="medical", synth_min_pairs=128,
+        recalibrate=True,
+    ) if args.learned_embedder else None
+    cache = CacheService(CacheConfig(
+        dim=enc_cfg.d_model, threshold=args.threshold,
+        telemetry=telemetry,
+        tiering=TieringConfig(hot_capacity=512, warm_capacity=4096,
+                              n_clusters=32, bucket=256,
+                              warm_dtype=args.warm_dtype,
+                              warm_block=args.warm_block or None,
+                              cold_capacity=args.cold_capacity),
+        sharding=ShardingConfig(mesh=mesh),
+        learning=LearningConfig(
+            learned_admission=args.learned_admission,
+            conformal=args.conformal,
+            learned_embedder=args.learned_embedder,
+            embedder_trainer=trainer if args.learned_embedder else None,
+            embedder_tokenizer=tok if args.learned_embedder else None,
+            refresh_policy=refresh),
+        ensemble=EnsembleConfig(embedders=args.ensemble or None),
+        staleness=StalenessConfig(default_ttl=args.ttl or None)))
+    caps = cache.capabilities()
+    print(f"tiered cache: warm shards "
+          f"{cache.warm_shards if caps.warm_sharded else 0}, "
+          f"warm dtype {caps.warm_dtype}, learned admission "
+          f"{'on' if caps.learned_admission else 'off'}, "
+          f"learned embedder "
+          f"{'on' if caps.learned_embedder else 'off'}, "
+          f"cold tier {args.cold_capacity if caps.cold_tier else 0} "
+          f"rows, ensemble "
+          f"{f'E={caps.ensemble}' if caps.ensemble else 'off'}, "
+          f"ttl {args.ttl or 'off'}, conformal "
+          f"{'on' if caps.conformal else 'off'}")
+    return cache
+
+
+def build_stack(args) -> Stack:
+    """Construct the cached serving path: decoder, fine-tuned embedder,
+    cache backend and the `CachedLLMService` in front of them."""
+    cfg, engine = build_engine(args)
+    enc_cfg, tok, trainer = build_embedder(args)
     telemetry = Telemetry()
-    if args.tiered:
-        from repro.cache_service import (
-            CacheConfig, CacheService, EmbedderRefreshPolicy,
-            EnsembleConfig, LearningConfig, ShardingConfig,
-            StalenessConfig, TieringConfig,
-        )
-        from repro.launch.mesh import make_cache_mesh
-        mesh = make_cache_mesh(args.cache_shards) if args.cache_shards \
-            else None
-        # smoke-scale refresh policy: trip the trigger inside a short
-        # stream, backfill thin splits from the medical grammar (§11)
-        refresh = EmbedderRefreshPolicy(
-            min_pairs=24, min_class=4, refresh_interval=32,
-            synth_domain="medical", synth_min_pairs=128,
-            recalibrate=True,
-        ) if args.learned_embedder else None
-        cache = CacheService(CacheConfig(
-            dim=enc_cfg.d_model, threshold=args.threshold,
-            telemetry=telemetry,
-            tiering=TieringConfig(hot_capacity=512, warm_capacity=4096,
-                                  n_clusters=32, bucket=256,
-                                  warm_dtype=args.warm_dtype,
-                                  warm_block=args.warm_block or None,
-                                  cold_capacity=args.cold_capacity),
-            sharding=ShardingConfig(mesh=mesh),
-            learning=LearningConfig(
-                learned_admission=args.learned_admission,
-                conformal=args.conformal,
-                learned_embedder=args.learned_embedder,
-                embedder_trainer=trainer
-                if args.learned_embedder else None,
-                embedder_tokenizer=tok
-                if args.learned_embedder else None,
-                refresh_policy=refresh),
-            ensemble=EnsembleConfig(embedders=args.ensemble or None),
-            staleness=StalenessConfig(default_ttl=args.ttl or None)))
-        caps = cache.capabilities()
-        print(f"tiered cache: warm shards "
-              f"{cache.warm_shards if caps.warm_sharded else 0}, "
-              f"warm dtype {caps.warm_dtype}, learned admission "
-              f"{'on' if caps.learned_admission else 'off'}, "
-              f"learned embedder "
-              f"{'on' if caps.learned_embedder else 'off'}, "
-              f"cold tier {args.cold_capacity if caps.cold_tier else 0} "
-              f"rows, ensemble "
-              f"{f'E={caps.ensemble}' if caps.ensemble else 'off'}, "
-              f"ttl {args.ttl or 'off'}, conformal "
-              f"{'on' if caps.conformal else 'off'}")
-    else:
-        cache = SemanticCache(capacity=4096, dim=enc_cfg.d_model,
-                              threshold=args.threshold, telemetry=telemetry)
+    cache = build_cache(args, enc_cfg, trainer, tok, telemetry)
     embed_fn = trainer.make_embed_fn(tok)
     if args.ensemble:
         # pilot = the fine-tuned embedder; the extra panels are cheap
@@ -265,10 +324,17 @@ def main():
             return np.stack(panels, axis=1)        # (B, E, D)
     svc = CachedLLMService(embed_fn, cache, engine, tok,
                            max_new_tokens=args.max_new_tokens)
+    return Stack(decoder=cfg, embedder=enc_cfg,
+                 tokenizer=tok, trainer=trainer, telemetry=telemetry,
+                 cache=cache, service=svc)
 
+
+def serve_stream(args, stack: Stack) -> float:
+    """Serve the seeded medical query stream in batches through
+    ``stack.service``; returns the wall seconds."""
     def dump_metrics(batch_idx, append):
-        write_jsonl(args.metrics_json, telemetry.registry.snapshot(),
-                    meta={"arch": cfg.name, "batch": batch_idx,
+        write_jsonl(args.metrics_json, stack.telemetry.registry.snapshot(),
+                    meta={"arch": stack.decoder.name, "batch": batch_idx,
                           "tiered": args.tiered}, append=append)
 
     stream = [q.text for q in make_query_stream("medical", args.requests,
@@ -276,17 +342,26 @@ def main():
     t0 = time.perf_counter()
     wrote = False
     for i in range(0, len(stream), args.batch):
-        svc.handle(stream[i:i + args.batch])
+        stack.service.handle(stream[i:i + args.batch])
         b = i // args.batch
         if args.metrics_json and args.metrics_interval \
                 and (b + 1) % args.metrics_interval == 0:
             dump_metrics(b, append=wrote)
             wrote = True
-    cache.maintenance(block=True)     # final idle tick: drain SLO gauges
-    print(f"{args.requests} requests in {time.perf_counter() - t0:.1f}s; "
+    stack.cache.maintenance(block=True)  # final idle tick: drain SLO gauges
+    if args.metrics_json:
+        dump_metrics(args.requests // args.batch, append=wrote)
+    return time.perf_counter() - t0
+
+
+def report(args, stack: Stack, wall: float) -> None:
+    """Print the serving summary: hit rate, stage latencies and the
+    enabled subsystems' counters."""
+    svc, cache = stack.service, stack.cache
+    print(f"{args.requests} requests in {wall:.1f}s; "
           f"hit rate {svc.hit_rate:.1%} "
           f"({int(svc.stats()['hits'])} LLM calls saved)")
-    stage_h = telemetry.stage_histogram()
+    stage_h = stack.telemetry.stage_histogram()
     for stage in ("embed", "plan", "cold_fetch", "generate", "commit",
                   "maintenance"):
         agg = stage_h.aggregate(stage=stage)
@@ -317,7 +392,7 @@ def main():
               f"policies {lrn['learned_policies']}")
     if args.learned_embedder:
         bk = svc.stats()["backend"]
-        rf, lrn = bk["refresh"], bk["learning"]
+        rf = bk["refresh"]
         print(f"learned embedder: version {rf['embed_version']} "
               f"({rf['refreshes_published']} published, "
               f"{rf['refreshes_rolled_back']} rolled back from "
@@ -337,8 +412,32 @@ def main():
               f"({cs['audited_false_hits']} false), "
               f"{len(cs['tenants'])} tenant window(s)")
     if args.metrics_json:
-        dump_metrics(args.requests // args.batch, append=wrote)
         print(f"metrics -> {args.metrics_json}")
+
+
+def generate_only(args) -> None:
+    """No cache: batched generation of random prompts."""
+    cfg, engine = build_engine(args)
+    rng = np.random.default_rng(0)
+    t0 = time.perf_counter()
+    for i in range(0, args.requests, args.batch):
+        prompts = rng.integers(0, cfg.vocab_size,
+                               (args.batch, 16)).astype(np.int32)
+        res = engine.generate(prompts, args.max_new_tokens)
+        print(f"batch {i//args.batch}: generated "
+              f"{res.tokens.shape[1]} tokens x {res.tokens.shape[0]}")
+    print(f"total {time.perf_counter() - t0:.1f}s")
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    print(f"compile cache: {enable_compile_cache()}")
+    if args.scenario:
+        return run_scenario(args)
+    if not args.cache:
+        return generate_only(args)
+    stack = build_stack(args)
+    report(args, stack, serve_stream(args, stack))
 
 
 if __name__ == "__main__":

@@ -36,10 +36,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-try:                                    # jax is a hard dep of the repo,
-    from jax.profiler import TraceAnnotation   # but keep obs importable
-except Exception:                       # against minimal environments
-    TraceAnnotation = None
+from jax.profiler import TraceAnnotation
 
 
 class Span:
@@ -102,7 +99,7 @@ class _SpanCtx:
         t = self._tracer
         self._span = span = Span(self._name, self._attrs)
         t._stack.append(span)
-        if t.annotate_xla and TraceAnnotation is not None:
+        if t.annotate_xla:
             self._ann = TraceAnnotation(self._name)
             self._ann.__enter__()
         return span

@@ -241,6 +241,32 @@ def test_service_fused_flag_serves_identically():
             assert va == vb
 
 
+def test_fused_flag_raises_where_the_chip_refuses_the_kernel(monkeypatch):
+    """On a TPU, fused=True (or warm_block) compiles the kernel when it
+    is set, and a compiler refusal raises NotImplementedError — never a
+    silent four-op fallback.  Steered here by making the service and the
+    kernel dispatch take their TPU branch: the kernel then lowers with
+    interpret=False, which the CPU backend refuses."""
+    from repro.cache_service import CacheConfig, TieringConfig
+    from repro.cache_service import service as service_mod
+    monkeypatch.setattr(service_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(cl_ops, "_on_tpu", lambda: True)
+
+    def cfg(**kw):
+        return CacheConfig(dim=16, tiering=TieringConfig(
+            hot_capacity=32, warm_capacity=64, n_clusters=4, bucket=16,
+            **kw))
+
+    with pytest.raises(NotImplementedError, match="does not compile"):
+        CacheService(cfg(fused=True))
+    with pytest.raises(NotImplementedError, match="does not compile"):
+        CacheService(cfg(warm_block=32))
+    svc = CacheService(cfg())
+    with pytest.raises(NotImplementedError, match="does not compile"):
+        svc.set_fused(True)
+    assert not svc.fused
+
+
 def test_tail_invariant_warning_on_unsafe_config():
     """flush_size * rebuild_every > warm_capacity clamps the tail window
     and must warn instead of silently degrading the rebuild cadence."""

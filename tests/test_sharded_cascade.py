@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from conftest import commit_insert, plan_lookup
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.cache_service import CacheService, tiers
@@ -113,11 +112,11 @@ def test_merge_helper_collective_matches_stacked_and_concat(S):
     np.testing.assert_array_equal(np.asarray(pm_o),
                                   np.asarray(flat_p[rows, im]))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda sl, pl: merge_local_topk(
             "model", k, sl.reshape(Q, k), pl.reshape(Q, k)),
         mesh=mesh, in_specs=(P("model"), P("model")),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
     sm_c, pm_c = jax.jit(fn)(s, pay)
     np.testing.assert_array_equal(np.asarray(sm_c), np.asarray(sm_o))
     np.testing.assert_array_equal(np.asarray(pm_c), np.asarray(pm_o))
@@ -576,12 +575,12 @@ def test_merge_local_topk_collective_matches_oracle_under_ties(S):
     sm_o, pv_o, ps_o = merge_stacked_topk(
         k, jnp.asarray(s), jnp.asarray(vids), jnp.asarray(shard))
     mesh = make_host_mesh(1, S)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda sl, vl, hl: merge_local_topk(
             "model", k, sl.reshape(Q, k), vl.reshape(Q, k),
             hl.reshape(Q, k)),
         mesh=mesh, in_specs=(P("model"), P("model"), P("model")),
-        out_specs=(P(), P(), P()), check_rep=False)
+        out_specs=(P(), P(), P()), check_vma=False)
     sm_c, pv_c, ps_c = jax.jit(fn)(jnp.asarray(s), jnp.asarray(vids),
                                    jnp.asarray(shard))
     np.testing.assert_array_equal(np.asarray(sm_c), np.asarray(sm_o))
