@@ -62,6 +62,25 @@ from repro.core.calibration import Calibration
 from repro.kernels.cascade_lookup.ops import _on_tpu
 from repro.obs import Telemetry
 from repro.obs.registry import SCHEMA, tenant_label
+from repro.obs.trace import child
+
+# every wait for the device on the write path: commit and the GC, flush,
+# rebuild and append helpers that maintenance() shares with it
+_WRITE_SYNC = "commit.sync"
+
+
+def _jit(fn, **static):
+    """``jax.jit`` of ``fn`` with ``static`` bound, under ``fn``'s own
+    name: a bare `functools.partial` compiles as ``jit__unknown``."""
+    bound = partial(fn, **static)
+    bound.__name__ = fn.__name__
+    return jax.jit(bound)
+
+
+def _fetched(x, span: str) -> np.ndarray:
+    """``np.asarray(x)``: a wait for the device, timed as ``span``."""
+    with child(span):
+        return np.asarray(x)
 
 
 @dataclass(frozen=True)
@@ -537,23 +556,22 @@ class CacheService:
         self.set_fused(fused)
         self._insert = jax.jit(tiers.hot_insert_batch)
         self._touch = jax.jit(tiers.hot_touch)
-        self._demote = jax.jit(partial(tiers.demote_coldest, m=flush_size))
+        self._demote = _jit(tiers.demote_coldest, m=flush_size)
         if sharded:
             self._append = jax.jit(tiers.warm_append_sharded)
-            self._rebuild = jax.jit(partial(tiers.warm_rebuild_sharded,
-                                            iters=kmeans_iters, seed=seed))
+            self._rebuild = _jit(tiers.warm_rebuild_sharded,
+                                 iters=kmeans_iters, seed=seed)
         else:
             self._append = jax.jit(tiers.warm_append)
-            self._rebuild = jax.jit(partial(tiers.warm_rebuild,
-                                            iters=kmeans_iters, seed=seed))
+            self._rebuild = _jit(tiers.warm_rebuild, iters=kmeans_iters,
+                                 seed=seed)
         self._evict_tenant = jax.jit(tiers.evict_tenant)
         self._publish_keys = jax.jit(tiers.publish_reembedded_keys)
         self._mask_expired = jax.jit(tiers.mask_expired)
         self._reap_expired = jax.jit(tiers.reap_expired)
         if self.ens is not None:
             self._ens_insert = jax.jit(tiers.ensemble_hot_insert_batch)
-            self._coldest = jax.jit(partial(tiers.coldest_slots,
-                                            m=flush_size))
+            self._coldest = _jit(tiers.coldest_slots, m=flush_size)
             self._ens_append = jax.jit(
                 tiers.ensemble_warm_append_sharded if sharded
                 else tiers.ensemble_warm_append)
@@ -569,19 +587,19 @@ class CacheService:
         if (fused or self.warm_block) and _on_tpu():
             self._require_kernel_compiles()
         self.fused = bool(fused)
-        self._lookup = jax.jit(partial(
+        self._lookup = _jit(
             tiers.cascade_query, k=self.topk, n_probe=self._n_probe,
             tail=self._tail, fused=self.fused,
             quantized=self.warm_dtype == "int8",
             mesh=self._mesh, axis=self._shard_axis,
-            warm_block_n=self.warm_block))
+            warm_block_n=self.warm_block)
         if getattr(self, "ens", None) is not None:
-            self._ens_lookup = jax.jit(partial(
+            self._ens_lookup = _jit(
                 tiers.ensemble_cascade_query, k=self.topk,
                 n_probe=self._n_probe, tail=self._tail, fused=self.fused,
                 quantized=self.warm_dtype == "int8",
                 mesh=self._mesh, axis=self._shard_axis,
-                warm_block_n=self.warm_block))
+                warm_block_n=self.warm_block)
 
     def _require_kernel_compiles(self) -> None:
         """Compile the fused kernel lookup for one query at this
@@ -711,7 +729,7 @@ class CacheService:
         if now is not None:
             hot_view, warm_view, nm = self._mask_expired(
                 self.hot, self.warm, now)
-            n_masked = int(nm)
+            n_masked = int(_fetched(nm, "plan.sync"))
             if n_masked:
                 self._c_expired_masked.inc(n_masked)
         panel_scores = None
@@ -730,16 +748,16 @@ class CacheService:
                                    jnp.asarray(emb_np),
                                    jnp.asarray(weights), jnp.asarray(qt),
                                    jnp.asarray(thr))
-            panel_scores = np.asarray(res.panel_scores)
+            panel_scores = _fetched(res.panel_scores, "plan.sync")
         else:
             pilot = np.asarray(request.embeddings)
             res = self._lookup(hot_view, warm_view, jnp.asarray(pilot),
                                jnp.asarray(qt), jnp.asarray(thr))
         self.hot = self._touch(self.hot, res.hot_slots, res.hot_hit)
-        hit = np.asarray(res.hit)
-        scores = np.asarray(res.scores[:, 0])
-        vids = np.asarray(res.value_ids[:, 0]).astype(np.int64)
-        hot_hit = np.asarray(res.hot_hit)
+        hit = _fetched(res.hit, "plan.sync")
+        scores = _fetched(res.scores[:, 0], "plan.sync")
+        vids = _fetched(res.value_ids[:, 0], "plan.sync").astype(np.int64)
+        hot_hit = _fetched(res.hot_hit, "plan.sync")
         self._n_plans += 1
         self._c_plans.inc()
         self._c_rows.inc(len(hit))
@@ -777,8 +795,9 @@ class CacheService:
             self.feedback.observe_plan(hit)
         if self.telemetry.health is not None:
             self.telemetry.health.observe_plan(qt, hit)
-        leader = coalesce_misses(pilot, hit, qt, thr) \
-            if coalesce else ungrouped_misses(hit)
+        with child("plan.coalesce"):
+            leader = coalesce_misses(pilot, hit, qt, thr) \
+                if coalesce else ungrouped_misses(hit)
         wall = time.perf_counter() - t0
         self._stage_h.observe(wall, stage="plan", tenant=tenant_label(qt))
         return CachePlan(
@@ -1213,7 +1232,7 @@ class CacheService:
 
     def _gc(self, evicted) -> int:
         """Free response strings whose ids a device op reported evicted."""
-        ids = np.asarray(evicted)
+        ids = _fetched(evicted, _WRITE_SYNC)
         n = 0
         for v in ids[ids >= 0]:
             self._texts.pop(int(v), None)
@@ -1227,8 +1246,8 @@ class CacheService:
         """Rows appended since the *published* index was built (the
         worst shard's backlog in the sharded tier — each shard has its
         own ring, so the window must cover the deepest one)."""
-        return int(np.max(np.asarray(self.warm.total
-                                     - self.warm.indexed_total)))
+        return int(np.max(_fetched(self.warm.total - self.warm.indexed_total,
+                                   _WRITE_SYNC)))
 
     def _tail_pressure(self) -> bool:
         """One more flush would push the unindexed backlog past the
@@ -1447,7 +1466,8 @@ class CacheService:
         """
         assert self._shadow_thread is not None
         t0 = time.perf_counter()
-        self._shadow_thread.join()
+        with child(_WRITE_SYNC):
+            self._shadow_thread.join()
         self._shadow_thread = None
         err = self._shadow_box.get("error")
         if err is not None:
@@ -1467,8 +1487,11 @@ class CacheService:
         return wall
 
     def _rebuild_inline(self) -> None:
-        t0 = time.perf_counter()
-        self.warm = jax.block_until_ready(self._rebuild(self.warm))
+        with child("rebuild"):
+            t0 = time.perf_counter()
+            warm = self._rebuild(self.warm)
+            with child(_WRITE_SYNC):
+                self.warm = jax.block_until_ready(warm)
         self._last_rebuild_s = time.perf_counter() - t0
         self._rebuild_total_s += self._last_rebuild_s
         self._c_rebuilds.inc()
@@ -1506,19 +1529,20 @@ class CacheService:
                                             panel_keys)
             self._c_ev_dropped.inc(self._gc(evicted))
             return
-        n = int(np.asarray(dem.mask).sum())
+        n = int(_fetched(dem.mask, _WRITE_SYNC).sum())
         if n:
             cap = self.warm.keys.shape[0]
-            pos = (int(np.asarray(self.warm.cursor))
+            pos = (int(_fetched(self.warm.cursor, _WRITE_SYNC))
                    + np.arange(n)) % cap
-            pos = pos[np.asarray(self.warm.valid)[pos]]
+            pos = pos[_fetched(self.warm.valid, _WRITE_SYNC)[pos]]
             if len(pos):
+                w = self.warm
                 dropped = self.cold.insert(
-                    np.asarray(self.warm.keys_q)[pos],
-                    np.asarray(self.warm.scales)[pos],
-                    np.asarray(self.warm.value_ids)[pos].astype(np.int64),
-                    np.asarray(self.warm.tenants)[pos],
-                    expires=np.asarray(self.warm.expires_at)[pos])
+                    _fetched(w.keys_q, _WRITE_SYNC)[pos],
+                    _fetched(w.scales, _WRITE_SYNC)[pos],
+                    _fetched(w.value_ids, _WRITE_SYNC)[pos].astype(np.int64),
+                    _fetched(w.tenants, _WRITE_SYNC)[pos],
+                    expires=_fetched(w.expires_at, _WRITE_SYNC)[pos])
                 self._c_ev_demoted.inc(len(pos))
                 self._n_demoted_cold += len(pos)
                 self._c_cold_evictions.inc(self._gc(dropped))
@@ -1556,16 +1580,17 @@ class CacheService:
             self._capture_and_append(dem)
 
     def _do_flush(self, rebuild: bool) -> None:
-        pk = None
-        if self.ens is not None:
-            # gather the demoting rows' stacked panel keys before the
-            # demote flips their valid bits — `coldest_slots` is the
-            # exact selection `demote_coldest` pops (§13)
-            slots = self._coldest(self.hot)
-            pk = self.ens.hot_keys[:, slots]
-        self.hot, dem = self._demote(self.hot)
-        self._capture_and_append(dem, pk)
-        self._c_demotions.inc(int(np.asarray(dem.mask).sum()))
+        with child("flush"):
+            pk = None
+            if self.ens is not None:
+                # gather the demoting rows' stacked panel keys before the
+                # demote flips their valid bits — `coldest_slots` is the
+                # exact selection `demote_coldest` pops (§13)
+                slots = self._coldest(self.hot)
+                pk = self.ens.hot_keys[:, slots]
+            self.hot, dem = self._demote(self.hot)
+            self._capture_and_append(dem, pk)
+            self._c_demotions.inc(int(_fetched(dem.mask, _WRITE_SYNC).sum()))
         # the tail window only covers the last `tail` ring writes; a
         # rebuild is forced before the unindexed backlog outgrows it,
         # else demoted rows would silently fall out of reach
@@ -1588,7 +1613,7 @@ class CacheService:
             self._start_shadow()
 
     def _maybe_flush(self) -> None:
-        n_valid = int(np.asarray(self.hot.valid).sum())
+        n_valid = int(_fetched(self.hot.valid, _WRITE_SYNC).sum())
         if n_valid >= self.flush_watermark * self.hot_capacity:
             self._do_flush(rebuild=False)
 

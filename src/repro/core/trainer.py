@@ -22,7 +22,9 @@ from repro.core.metrics import pair_classification_metrics
 from repro.data.corpora import PairDataset
 from repro.data.pairs import iter_batches, tokenize_pairs
 from repro.data.tokenizer import HashTokenizer
-from repro.models import encode, init_lm, split
+from repro.models import encode as encode_lm
+from repro.models import init_lm, split
+from repro.obs.trace import child
 from repro.training.optim import adam, apply_updates
 
 
@@ -61,7 +63,7 @@ class EmbedderTrainer:
                 toks = jnp.concatenate([batch["tok1"], batch["tok2"]], axis=0)
                 masks = jnp.concatenate([batch["mask1"], batch["mask2"]],
                                         axis=0)
-                embs = encode(p, self.cfg, toks, masks)
+                embs = encode_lm(p, self.cfg, toks, masks)
                 e1, e2 = jnp.split(embs, 2, axis=0)
                 return loss_fn(e1, e2, batch["label"], margin=self.ft.margin)
 
@@ -70,8 +72,11 @@ class EmbedderTrainer:
             params = apply_updates(params, updates)
             return params, opt_state, {"loss": loss, **om}
 
+        def encode(params, ids, mask):
+            return encode_lm(params, self.cfg, ids, mask)
+
         self._step = jax.jit(step)
-        self._encode = jax.jit(lambda p, t, m: encode(p, self.cfg, t, m))
+        self._encode = jax.jit(encode)
         self.history: List[dict] = []
 
     # ------------------------------------------------------------------
@@ -99,13 +104,16 @@ class EmbedderTrainer:
                     batch_size: int = 64) -> np.ndarray:
         out = []
         for i in range(0, len(texts), batch_size):
-            chunk = list(texts[i:i + batch_size])
-            pad_to = batch_size  # stable jit shape
-            while len(chunk) < pad_to:
-                chunk.append("")
-            ids, mask = tokenizer.encode_batch(chunk, self.ft.max_len)
+            with child("embed.tokenize"):
+                chunk = list(texts[i:i + batch_size])
+                pad_to = batch_size  # stable jit shape
+                while len(chunk) < pad_to:
+                    chunk.append("")
+                ids, mask = tokenizer.encode_batch(chunk, self.ft.max_len)
             e = self._encode(self.params, jnp.asarray(ids), jnp.asarray(mask))
-            out.append(np.asarray(e)[: len(texts[i:i + batch_size])])
+            with child("embed.sync"):
+                e = np.asarray(e)
+            out.append(e[: len(texts[i:i + batch_size])])
         return np.concatenate(out, axis=0)
 
     def pair_scores(self, ds: PairDataset, tokenizer: HashTokenizer

@@ -6,7 +6,8 @@ Three layers, all optional and all zero-cost when disabled:
   * :mod:`repro.obs.registry` — counters / gauges / fixed-bucket
     latency histograms with declared label schemas (§10.1);
   * :mod:`repro.obs.trace` — context-managed spans forming one tree
-    per request, with optional XLA profiler annotations (§10.2);
+    per request, with optional XLA profiler annotations (§10.2); the
+    layers below the pipeline add children through ``child()``;
   * :mod:`repro.obs.health` — per-tenant SLO-budget rates and rebuild
     overlap accounting, drained at the idle tick (§10.3);
   * :mod:`repro.obs.export` — JSON-lines and Prometheus renderers for
@@ -27,7 +28,7 @@ from .health import (HealthConfig, HealthTracker, TenantHealth,
 from .registry import (DEFAULT_LATENCY_BUCKETS_S, NULL_REGISTRY, SCHEMA,
                        Counter, Gauge, Histogram, MetricsRegistry,
                        NullRegistry, tenant_label)
-from .trace import NULL_TRACER, Span, Tracer
+from .trace import NULL_TRACER, Span, Tracer, child
 
 
 @dataclass
@@ -68,7 +69,7 @@ __all__ = [
     "MetricsRegistry", "NullRegistry", "NULL_REGISTRY",
     "Counter", "Gauge", "Histogram",
     "DEFAULT_LATENCY_BUCKETS_S", "SCHEMA", "tenant_label",
-    "Tracer", "Span", "NULL_TRACER",
+    "Tracer", "Span", "NULL_TRACER", "child",
     "HealthTracker", "HealthConfig", "TenantHealth",
     "check_overhead_budget",
     "to_jsonl", "write_jsonl", "read_jsonl", "validate_lines",

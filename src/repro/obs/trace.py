@@ -23,15 +23,21 @@ rooted at ``request``.  The tracer is deliberately tiny:
   * Spans are structural; they do **not** write metrics (the serving
     layers observe the ``stage_latency_seconds`` histogram directly,
     exactly once per stage — see DESIGN.md §10.2 for why the two are
-    kept separate).  Pass ``histogram=`` to opt a tracer into
-    recording span durations anyway (used by tools that only have a
-    tracer).
+    kept separate).
+  * Code below the serving pipeline (the cache service, the embedder)
+    holds no tracer: ``child(name, **attrs)`` opens a span under
+    whatever span is open on the calling thread.  It only ever adds
+    children, never a root: with no span open (a direct
+    ``CacheService.plan()`` call, or the shadow-rebuild thread) it
+    returns the shared no-op context and records nothing.
 
 ``NULL_TRACER`` (or ``Tracer(enabled=False)``) makes ``span()`` return
-a shared reusable no-op context manager.
+a shared reusable no-op context manager; such a tracer never becomes
+the thread's open tracer, so ``child()`` under it costs one lookup.
 """
 from __future__ import annotations
 
+import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
@@ -84,8 +90,17 @@ class Span:
                 f"{len(self.children)} children)")
 
 
+class _Open(threading.local):
+    """The tracer with a span open on this thread, for `child` (a class
+    default: a thread-local's missing attribute costs an exception)."""
+    tracer: Optional["Tracer"] = None
+
+
+_open = _Open()
+
+
 class _SpanCtx:
-    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_ann")
+    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_ann", "_outer")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Dict[str, object]):
@@ -94,11 +109,14 @@ class _SpanCtx:
         self._attrs = attrs
         self._span: Optional[Span] = None
         self._ann = None
+        self._outer = None
 
     def __enter__(self) -> Span:
         t = self._tracer
         self._span = span = Span(self._name, self._attrs)
         t._stack.append(span)
+        self._outer = _open.tracer
+        _open.tracer = t
         if t.annotate_xla:
             self._ann = TraceAnnotation(self._name)
             self._ann.__enter__()
@@ -119,10 +137,7 @@ class _SpanCtx:
             t._stack[-1].children.append(span)
         else:
             t._roots.append(span)
-        if t._histogram is not None:
-            t._histogram.observe(
-                span.duration_s, stage=span.name,
-                tenant=str(span.attrs.get("tenant", "-")))
+        _open.tracer = self._outer
 
 
 class _NullCtx:
@@ -152,15 +167,12 @@ _NULL_CTX = _NullCtx()
 
 class Tracer:
     def __init__(self, *, enabled: bool = True, annotate_xla: bool = False,
-                 keep: int = 64, histogram=None):
-        """``keep``: finished root spans retained (ring buffer).
-        ``histogram``: optional `repro.obs.registry.Histogram` with
-        labels ``(stage, tenant)`` to observe on every span end."""
+                 keep: int = 64):
+        """``keep``: finished root spans retained (ring buffer)."""
         self.enabled = bool(enabled)
         self.annotate_xla = bool(annotate_xla)
         self._stack: List[Span] = []
         self._roots: deque = deque(maxlen=keep)
-        self._histogram = histogram
 
     def span(self, name: str, **attrs):
         if not self.enabled:
@@ -183,3 +195,13 @@ class Tracer:
 
 
 NULL_TRACER = Tracer(enabled=False)
+
+
+def child(name: str, **attrs):
+    """A span named ``name`` under the span open on this thread, in
+    that span's tracer (annotated like any of its spans); the shared
+    no-op context where no span is open."""
+    t = _open.tracer
+    if t is None:
+        return _NULL_CTX
+    return _SpanCtx(t, name, attrs)

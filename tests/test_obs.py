@@ -8,14 +8,16 @@ import jax
 import numpy as np
 import pytest
 
-from repro.cache_service import CacheService
+from repro.cache_service import CacheConfig, CacheService, TieringConfig
+from repro.configs import get_config
 from repro.core import SemanticCache
 from repro.core.embedders import HashNgramEmbedder
+from repro.core.trainer import EmbedderTrainer, FinetuneConfig
 from repro.data import HashTokenizer
 from repro.obs import (
     DEFAULT_LATENCY_BUCKETS_S, SCHEMA, HealthTracker, MetricsRegistry,
-    Telemetry, Tracer, check_overhead_budget, read_jsonl, tenant_label,
-    to_jsonl, to_prometheus, validate_lines, write_jsonl,
+    Telemetry, Tracer, check_overhead_budget, child, read_jsonl,
+    tenant_label, to_jsonl, to_prometheus, validate_lines, write_jsonl,
 )
 from repro.serving import CachedLLMService
 
@@ -205,6 +207,142 @@ def test_disabled_tracer_is_inert():
     tel.registry.counter("x_total").inc(5)
     assert tel.registry.value("x_total") == 0
     assert tel.health is None
+
+
+@pytest.mark.parametrize("enabled,annotate", [(True, False), (True, True),
+                                              (False, False)])
+def test_child_span_needs_an_open_parent(enabled, annotate):
+    """``child`` adds a span under the span open on this thread, in
+    that span's tracer; with none open (or only a disabled tracer's) it
+    returns the shared no-op context and records nothing, and it never
+    creates a root."""
+    tr = Tracer(enabled=enabled, annotate_xla=annotate)
+    idle = child("plan.sync")
+    assert idle is child("embed.sync")           # one shared no-op
+    with idle as s:
+        assert s.duration_s == 0.0
+    assert tr.roots() == []
+    with tr.span("request"):
+        with child("plan.sync", rows=3):
+            with child("inner"):
+                pass
+        seen = []
+        t = threading.Thread(target=lambda: seen.append(child("off")))
+        t.start()
+        t.join()
+        assert seen == [idle]                    # another thread's span
+    assert child("plan.sync") is idle            # closed again
+    if not enabled:
+        assert tr.roots() == []
+        return
+    (root,) = tr.roots()
+    assert [s.name for s in root.walk()] == ["request", "plan.sync",
+                                             "inner"]
+    assert root.find("plan.sync").attrs == {"rows": 3}
+
+
+# ---------------------------------------------------------------------------
+# spans inside the cache service and the embedder
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tiny_embedder():
+    cfg = get_config("modernbert-149m").reduced(vocab_size=512)
+    tok = HashTokenizer(vocab_size=512)
+    return EmbedderTrainer(cfg, FinetuneConfig(max_len=12)), tok
+
+
+def _embedded_service(tiny_embedder, telemetry):
+    """The served path at tiny width: the embedder, then a hot tier of
+    16 that flushes 4 rows (and rebuilds the IVF) once it holds 8."""
+    trainer, tok = tiny_embedder
+    cache = CacheService(CacheConfig(
+        dim=trainer.cfg.d_model, threshold=0.999, telemetry=telemetry,
+        tiering=TieringConfig(hot_capacity=16, warm_capacity=256,
+                              n_clusters=4, bucket=64, n_probe=2,
+                              flush_watermark=0.5, flush_size=4,
+                              kmeans_iters=2)))
+    svc = CachedLLMService(trainer.make_embed_fn(tok), cache, None, tok,
+                           telemetry=telemetry)
+    return cache, svc
+
+
+def _batch(k, n):
+    return [f"question {k} number {i} about item {7 * i + k}"
+            for i in range(n)]
+
+
+def test_handle_spans_inside_the_embedder_and_the_cache(tiny_embedder):
+    """One handle call on an empty cache: 12 admissions cross the hot
+    tier's watermark, so commit flushes and rebuilds inline."""
+    tel = Telemetry()
+    _, svc = _embedded_service(tiny_embedder, tel)
+    svc.handle(_batch(0, 12))
+    root = tel.tracer.last_root()
+    assert root.stage_names()[:4] == ["embed", "plan", "generate",
+                                      "commit"]
+    assert root.find("embed").stage_names() == ["embed.tokenize",
+                                                "embed.sync"]
+    assert root.find("plan").stage_names() == ["plan.sync"] * 4 + [
+        "plan.coalesce"]
+    commit = root.find("commit")
+    names = commit.stage_names()
+    assert set(names) == {"commit.sync", "flush", "rebuild"}
+    assert names.count("flush") == names.count("rebuild") == 1
+    assert names.index("flush") < names.index("rebuild")  # siblings
+    flush, rebuild = commit.find("flush"), commit.find("rebuild")
+    assert set(flush.stage_names()) == {"commit.sync"}
+    assert rebuild.stage_names() == ["commit.sync"]
+    for span in root.walk():
+        assert sum(c.duration_s for c in span.children) <= span.duration_s
+
+
+@pytest.mark.parametrize("batch", [3, 12])
+def test_flush_and_rebuild_spans_count_the_service(tiny_embedder, batch):
+    tel = Telemetry()
+    cache, svc = _embedded_service(tiny_embedder, tel)
+    for k in range(6):
+        svc.handle(_batch(k, batch))
+    names = [s.name for r in tel.tracer.roots() for s in r.walk()]
+    snap = cache.stats_snapshot()
+    assert snap.rebuild["rebuilds"] >= 1
+    assert names.count("rebuild") == snap.rebuild["rebuilds"]
+    assert names.count("flush") * cache.flush_size \
+        == snap.tiers["demotions"]
+    assert names.count("request") == 6
+    assert names.count("plan.sync") == 4 * 6
+    assert names.count("embed.sync") == 6
+
+
+def test_disabled_telemetry_records_no_child_span(tiny_embedder):
+    tel = Telemetry.disabled()
+    cache, svc = _embedded_service(tiny_embedder, tel)
+    svc.handle(_batch(0, 12))
+    assert cache._rebuild_total_s > 0            # it flushed and rebuilt
+    assert tel.tracer.roots() == []
+    assert child("plan.sync") is child("commit.sync")
+
+
+@pytest.mark.parametrize("name", ["encode", "cascade_query",
+                                  "demote_coldest", "warm_rebuild"])
+def test_served_programs_carry_their_names(tiny_embedder, name):
+    """Each program of the served path compiles as ``jit_<name>``, so a
+    device trace tells the encoder, the cascade, the demotion and the
+    rebuild apart."""
+    trainer, _ = tiny_embedder
+    cache, _ = _embedded_service(tiny_embedder, Telemetry())
+    d = cache.dim
+    lowered = {
+        "encode": lambda: trainer._encode.lower(
+            trainer.params, np.zeros((64, 12), np.int32),
+            np.ones((64, 12), np.int32)),
+        "cascade_query": lambda: cache._lookup.lower(
+            cache.hot, cache.warm, np.zeros((2, d), np.float32),
+            np.zeros(2, np.int32), np.ones(2, np.float32)),
+        "demote_coldest": lambda: cache._demote.lower(cache.hot),
+        "warm_rebuild": lambda: cache._rebuild.lower(cache.warm),
+    }[name]()
+    assert f"module @jit_{name} " in lowered.as_text()
 
 
 # ---------------------------------------------------------------------------
