@@ -102,18 +102,24 @@ class EmbedderTrainer:
     # ------------------------------------------------------------------
     def embed_texts(self, texts, tokenizer: HashTokenizer,
                     batch_size: int = 64) -> np.ndarray:
+        """(len(texts), D) unit-norm embeddings, encoded in chunks of
+        ``batch_size`` rows.  Each chunk is padded with empty strings to
+        the next power of two of its rows, ``batch_size`` at most, so
+        the encoder compiles one shape per bucket (1, 2, 4, ...) and a
+        one-question call runs a one-row forward.  Rows never interact
+        in the encoder: the padding changes no real row."""
         out = []
         for i in range(0, len(texts), batch_size):
-            with child("embed.tokenize"):
-                chunk = list(texts[i:i + batch_size])
-                pad_to = batch_size  # stable jit shape
-                while len(chunk) < pad_to:
-                    chunk.append("")
-                ids, mask = tokenizer.encode_batch(chunk, self.ft.max_len)
+            chunk = list(texts[i:i + batch_size])
+            n = len(chunk)
+            pad_to = min(1 << (n - 1).bit_length(), batch_size)
+            with child("embed.tokenize", rows=n, padded_to=pad_to):
+                ids, mask = tokenizer.encode_batch(
+                    chunk + [""] * (pad_to - n), self.ft.max_len)
             e = self._encode(self.params, jnp.asarray(ids), jnp.asarray(mask))
             with child("embed.sync"):
                 e = np.asarray(e)
-            out.append(e[: len(texts[i:i + batch_size])])
+            out.append(e[:n])
         return np.concatenate(out, axis=0)
 
     def pair_scores(self, ds: PairDataset, tokenizer: HashTokenizer
@@ -127,5 +133,8 @@ class EmbedderTrainer:
         return pair_classification_metrics(scores, ds.labels)
 
     def make_embed_fn(self, tokenizer: HashTokenizer) -> Callable:
-        """list[str] -> (B, D) unit-norm np — plugs into CachedLLMService."""
+        """list[str] -> (B, D) unit-norm np — plugs into CachedLLMService.
+        ``embed_texts`` at its default ``batch_size`` of 64: the chunk
+        size and the largest bucket; a call's rows are padded to the next
+        power of two."""
         return lambda texts: self.embed_texts(texts, tokenizer)

@@ -297,6 +297,16 @@ def test_handle_spans_inside_the_embedder_and_the_cache(tiny_embedder):
         assert sum(c.duration_s for c in span.children) <= span.duration_s
 
 
+def test_embed_tokenize_span_says_the_rows_and_their_bucket(tiny_embedder):
+    tel = Telemetry()
+    _, svc = _embedded_service(tiny_embedder, tel)
+    svc.handle(_batch(0, 12))
+    embed = tel.tracer.last_root().find("embed")
+    assert embed.stage_names() == ["embed.tokenize", "embed.sync"]
+    assert embed.find("embed.tokenize").attrs == {"rows": 12,
+                                                  "padded_to": 16}
+
+
 @pytest.mark.parametrize("batch", [3, 12])
 def test_flush_and_rebuild_spans_count_the_service(tiny_embedder, batch):
     tel = Telemetry()
