@@ -149,7 +149,7 @@ def main():
                 refresh_policy=refresh)))
         print(f"cascade path: {'fused kernel' if cache.fused else 'four-op'}"
               f" (backend {jax.default_backend()})")
-    svc = CachedLLMService(trainer.make_embed_fn(tok), cache, engine, tok,
+    svc = CachedLLMService(trainer.make_embed_fn(tok), cache, engine,
                            max_new_tokens=args.max_new_tokens)
 
     # --- batched serving loop over a repeated-query trace -------------

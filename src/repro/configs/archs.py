@@ -11,4 +11,5 @@ from repro.configs import (  # noqa: F401
     qwen2_5_32b,
     granite_moe_3b,
     modernbert_149m,
+    jamba2_3b,
 )

@@ -53,6 +53,9 @@ class SSMConfig:
     d_conv: int = 4
     expand: int = 2
     dt_rank: int = 0                # 0 -> ceil(d_model / 16)
+    # Jamba's RMSNorms on dt, B and C after x_proj (dt_layernorm,
+    # b_layernorm, c_layernorm in HF's JambaMambaMixer)
+    inner_norms: bool = False
 
 
 @dataclass(frozen=True)
@@ -211,12 +214,13 @@ class ModelConfig:
         dt_rank = s.dt_rank or -(-self.d_model // 16)
         return (
             self.d_model * 2 * d_in            # in_proj
-            + s.d_conv * d_in                  # depthwise conv
+            + (s.d_conv + 1) * d_in            # depthwise conv + bias
             + d_in * (dt_rank + 2 * s.d_state) # x_proj
-            + dt_rank * d_in                   # dt_proj
+            + (dt_rank + 1) * d_in             # dt_proj + bias
             + d_in * s.d_state                 # A_log
             + d_in                             # D
             + d_in * self.d_model              # out_proj
+            + ((dt_rank + 2 * s.d_state) if s.inner_norms else 0)
         )
 
     def _xlstm_params(self, kind: str) -> int:

@@ -36,7 +36,7 @@ CONFIG = register(ModelConfig(
     norm_type="rmsnorm",
     use_rope=False,          # Jamba uses no positional encoding
     moe=MoEConfig(num_experts=16, top_k=2, expert_d_ff=24576),
-    ssm=SSMConfig(d_state=16, d_conv=4, expand=2),
+    ssm=SSMConfig(d_state=16, d_conv=4, expand=2, inner_norms=True),
     period=_PERIOD,
     # 398B params cannot hold fp32 master + fp32 Adam moments in one
     # v5e pod (4.8TB > 4TB HBM); bf16 params + bf16 moments fit

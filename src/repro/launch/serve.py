@@ -8,7 +8,10 @@ optional semantic cache in front (the paper's deployment).
 d_model 128); ``--no-smoke`` serves the published modernbert-149m
 widths (22 layers, d_model 768, vocab 50,368) with cache keys at
 D=768.  The decoder behind the cache is the ``--arch`` config reduced
-to its smoke size in both settings (`build_engine` prints which).
+to its smoke size, except that ``--no-smoke`` serves a decoder whose
+published weights fit half a chip at those widths (``--arch
+jamba2-3b``: 28 layers, d_model 2560, bf16); `build_engine` prints
+which.
 `chip_smoke.py` drives the same construction (`build_stack`).
 
 ``--tiered`` swaps the flat SemanticCache for the tiered CacheService;
@@ -54,6 +57,11 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_lm, split
 from repro.obs import Telemetry, write_jsonl
 from repro.serving import CachedLLMService, ServeEngine
+
+# a decoder whose published weights take more than half of one v5e
+# chip's 16 GB leaves too little beside the cache: it serves reduced
+DECODER_BYTES = 8e9
+PROMPT_TOKENS = 32              # the service's max_query_len
 
 
 def run_scenario(args):
@@ -212,14 +220,23 @@ class Stack:
     service: CachedLLMService
 
 
+def decoder_config(arch: str, smoke: bool) -> ModelConfig:
+    """The decoder behind the cache: at its published widths and weight
+    dtype unless ``smoke``, where those weights take at most
+    `DECODER_BYTES` (jamba2-3b's bf16 6.06 GB), else reduced."""
+    cfg = get_config(arch)
+    nbytes = cfg.param_count() * jax.numpy.dtype(cfg.param_dtype).itemsize
+    return cfg.reduced() if smoke or nbytes > DECODER_BYTES else cfg
+
+
 def build_engine(args) -> Tuple[ModelConfig, ServeEngine]:
-    """The decoder behind the cache, always at its reduced size."""
-    cfg = get_config(args.arch).reduced()
+    cfg = decoder_config(args.arch, args.smoke)
     pv, _ = split(init_lm(cfg, jax.random.PRNGKey(0)))
-    print(f"decoder {cfg.name}: reduced to {cfg.n_layers} layers, d_model "
-          f"{cfg.d_model}, vocab {cfg.vocab_size} "
-          f"({cfg.param_count():,} params)")
-    return cfg, ServeEngine(cfg, pv, max_len=64)
+    print(f"decoder {cfg.name}: {cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}, vocab {cfg.vocab_size} ({cfg.param_count():,} "
+          f"params, {cfg.param_dtype})")
+    return cfg, ServeEngine(cfg, pv,
+                            max_len=PROMPT_TOKENS + args.max_new_tokens)
 
 
 def build_embedder(args) -> Tuple[ModelConfig, HashTokenizer,
@@ -322,7 +339,8 @@ def build_stack(args) -> Stack:
             panels = [pilot_fn(texts)] + [np.asarray(e.embed(texts))
                                           for e in extras]
             return np.stack(panels, axis=1)        # (B, E, D)
-    svc = CachedLLMService(embed_fn, cache, engine, tok,
+    svc = CachedLLMService(embed_fn, cache, engine,
+                           max_query_len=PROMPT_TOKENS,
                            max_new_tokens=args.max_new_tokens)
     return Stack(decoder=cfg, embedder=enc_cfg,
                  tokenizer=tok, trainer=trainer, telemetry=telemetry,

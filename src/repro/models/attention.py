@@ -256,11 +256,14 @@ def cache_axes():
     }
 
 
-def apply_prefill(p, cfg: ModelConfig, x, positions, cache):
+def apply_prefill(p, cfg: ModelConfig, x, positions, cache, mask=None):
     """Run full attention over the prompt AND fill the cache.
 
     Returns (y, new_cache).  With a sliding window the cache keeps only
-    the last `window` tokens, written at slots (t mod window).
+    the last `window` tokens, written at slots (t mod window).  ``mask``
+    ((B,S) bool, None = all valid) marks each row's real tokens: pad
+    keys are masked here and get ``pos`` -1 in the cache, so decode
+    masks them too.
     """
     B, S, _ = x.shape
     L = cache["k"].shape[1]
@@ -271,26 +274,25 @@ def apply_prefill(p, cfg: ModelConfig, x, positions, cache):
         k = layers.apply_rope(k, sin, cos)
     o = gqa_attention(q, k, v, q_pos=positions, kv_pos=positions,
                       causal=True, window=cfg.sliding_window,
-                      unroll=cfg.unroll_inner,
+                      kv_valid=mask, unroll=cfg.unroll_inner,
                       acc_dtype=jnp.float32 if cfg.attn_f32 else jnp.bfloat16)
     y = _out_proj(p, cfg, o)
 
+    pos = jnp.broadcast_to(positions.astype(jnp.int32)[None], (B, S))
+    if mask is not None:
+        pos = jnp.where(mask, pos, -1)
     if L >= S:
         new_k = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
                                              (0, 0, 0, 0))
         new_v = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
                                              (0, 0, 0, 0))
-        pos_row = jnp.pad(positions.astype(jnp.int32), (0, L - S),
-                          constant_values=-1)
-        new_pos = jnp.broadcast_to(pos_row[None], (B, L))
+        new_pos = jnp.pad(pos, ((0, 0), (0, L - S)), constant_values=-1)
     else:
         # keep last L tokens, slot t % L
-        tail = positions[S - L:]                       # (L,)
-        slots = jnp.mod(tail, L)                       # (L,)
+        slots = jnp.mod(positions[S - L:], L)              # (L,)
         new_k = cache["k"].at[:, slots].set(k[:, S - L:].astype(cache["k"].dtype))
         new_v = cache["v"].at[:, slots].set(v[:, S - L:].astype(cache["v"].dtype))
-        new_pos = jnp.zeros((B, L), jnp.int32).at[:, slots].set(
-            jnp.broadcast_to(tail[None], (B, L)))
+        new_pos = jnp.zeros((B, L), jnp.int32).at[:, slots].set(pos[:, S - L:])
     return y, {"k": new_k, "v": new_v, "pos": new_pos}
 
 

@@ -100,12 +100,18 @@ def apply_full(p, cfg: ModelConfig, spec: LayerSpec, x, positions):
     return _ffn(p, cfg, spec, x)
 
 
-def apply_prefill(p, cfg: ModelConfig, spec: LayerSpec, x, positions, state):
+def apply_prefill(p, cfg: ModelConfig, spec: LayerSpec, x, positions, state,
+                  mask=None):
+    """``mask`` ((B,S) bool, None = all valid) marks the real tokens of
+    left-padded rows; only the attention and Mamba mixers take one."""
     h = layers.apply_norm(p["norm1"], cfg, x)
     if spec.mixer == ATTN:
-        y, ns = attention.apply_prefill(p["mixer"], cfg, h, positions, state)
+        y, ns = attention.apply_prefill(p["mixer"], cfg, h, positions, state,
+                                        mask)
     elif spec.mixer == MAMBA:
-        y, ns = mamba.apply_prefill(p["mixer"], cfg, h)
+        y, ns = mamba.apply_prefill(p["mixer"], cfg, h, mask)
+    elif mask is not None:
+        raise NotImplementedError(f"{spec.mixer} prefill takes no pad mask")
     elif spec.mixer == MLSTM:
         y, ns = xlstm.apply_mlstm_full(p["mixer"], cfg, h, return_state=True)
     else:

@@ -168,21 +168,28 @@ def lm_state_axes(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 def prefill(pv, cfg: ModelConfig, tokens, cache_len: int,
-            frontend_embeds=None):
+            frontend_embeds=None, mask=None):
     """Full forward over the prompt, building the decode state.
 
-    Returns (last_token_logits, state).
+    ``mask`` ((B, S_tok) bool, None = all valid) marks the real tokens
+    of left-padded prompts: pads reach neither the attention cache nor
+    the Mamba state, so each row's logits are those it gets alone and
+    unpadded (positions are not shifted: rotary positions are relative,
+    and Jamba has none).  Returns (last_token_logits, state).
     """
     x = _input_embeds(pv, cfg, tokens, frontend_embeds)
     B, S, _ = x.shape
     positions = jnp.arange(S, dtype=jnp.int32)
+    if mask is not None and frontend_embeds is not None:
+        mask = jnp.concatenate(
+            [jnp.ones((B, S - mask.shape[1]), bool), mask], axis=1)
 
     def body(x, layer_p):
         states = {}
         for i, spec in enumerate(cfg.period):
             x, ns, _ = blocks.apply_prefill(
                 layer_p[f"pos{i}"], cfg, spec, x, positions,
-                blocks.init_layer_state(cfg, spec, B, cache_len))
+                blocks.init_layer_state(cfg, spec, B, cache_len), mask)
             states[f"pos{i}"] = ns
         return x, states
 

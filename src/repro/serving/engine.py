@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import itertools
 import time
+from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -18,24 +19,48 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.cache_service.protocol import CacheBackend, CacheRequest
-from repro.configs.base import ModelConfig
+from repro.configs.base import ATTN, MAMBA, ModelConfig
 from repro.data.tokenizer import HashTokenizer
-from repro.models import decode_step, prefill
+from repro.models import decode_step as model_decode_step
+from repro.models import prefill as model_prefill
 from repro.obs import Telemetry
 from repro.obs.registry import tenant_label
+from repro.obs.trace import child
 from repro.serving.frontend import stub_frontend_embeds
 
 
 @dataclass
 class GenerationResult:
-    tokens: np.ndarray          # (B, max_new) int32
+    tokens: np.ndarray          # (B, max_new) int32, the real rows only
     n_prompt: int
     n_generated: int
     cache_hit: bool = False
+    padded_to: int = 0          # rows the device ran (real + padding)
+
+
+# decode steps queued on the device ahead of the token being copied:
+# ~200 ms of jamba2-3b's 32-row steps on a TPU v5e, longer than the
+# ~100-130 ms host stalls measured there
+STEPS_AHEAD = 16
+
+
+def _select(logits, temperature, key):
+    """Greedy (temperature 0) or sampled next tokens, (B, 1) int32."""
+    if temperature <= 0.0:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+    g = jax.random.gumbel(key, logits.shape)
+    return jnp.argmax(logits / temperature + g, axis=-1).astype(
+        jnp.int32)[:, None]
 
 
 class ServeEngine:
-    """Batched autoregressive serving for any decoder config."""
+    """Batched autoregressive serving for any decoder config.
+
+    ``tokenizer`` is a hash vocabulary of the model's own size.  The
+    jitted functions are named, so a device trace shows ``jit_prefill``
+    and ``jit_decode_step``; each picks its tokens on the device, and
+    the decode step updates its state in place (the state is donated).
+    """
 
     def __init__(self, cfg: ModelConfig, params, max_len: int = 512):
         if cfg.is_encoder:
@@ -43,35 +68,109 @@ class ServeEngine:
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
-        self._prefill = jax.jit(
-            lambda pv, toks, fe: prefill(pv, cfg, toks, max_len, fe),
-            static_argnames=())
-        self._decode = jax.jit(lambda pv, st, tok: decode_step(pv, cfg, st, tok))
+        self.tokenizer = HashTokenizer(vocab_size=cfg.vocab_size)
+        # every mixer takes a pad mask, so ragged prompts can share a batch
+        self.masked = all(s.mixer in (ATTN, MAMBA) for s in cfg.period)
+
+        def prefill(pv, toks, mask, fe, key, temperature):
+            logits, state = model_prefill(pv, cfg, toks, max_len, fe, mask)
+            return _select(logits, temperature, key), logits, state
+
+        def decode_step(pv, state, tok, key, temperature):
+            logits, state = model_decode_step(pv, cfg, state, tok)
+            key, sub = jax.random.split(key)
+            return _select(logits, temperature, sub), logits, state, key
+
+        self._prefill = jax.jit(prefill, static_argnums=5)
+        self._decode = jax.jit(decode_step, static_argnums=4,
+                               donate_argnums=1)
 
     def generate(self, prompts: np.ndarray, max_new_tokens: int = 32,
                  temperature: float = 0.0, seed: int = 0,
-                 use_frontend: bool = False) -> GenerationResult:
-        """prompts: (B, S) int32.  Greedy (temperature=0) or sampled."""
-        B, S = prompts.shape
-        fe = stub_frontend_embeds(self.cfg, B, seed) if use_frontend else None
-        logits, state = self._prefill(self.params, jnp.asarray(prompts), fe)
+                 use_frontend: bool = False, mask: Optional[np.ndarray] = None
+                 ) -> GenerationResult:
+        """prompts: (B, S) int32, each row's real tokens marked by ``mask``
+        ((B, S) bool, None = all real).  Rows are left-padded, so every
+        row's last prompt token is the last column, where the prefill
+        takes its logits.  The rows are padded with empty prompts to the
+        next power of two: one compiled shape per bucket (1, 2, 4, ...),
+        whatever the number of rows.  A config whose mixers take no pad mask
+        runs ragged prompts one length at a time.  Greedy
+        (temperature=0) or sampled, for exactly ``max_new_tokens``
+        tokens: the first from the prefill, then one per decode step,
+        each copied to the host while later steps run."""
+        n, S = prompts.shape
+        if max_new_tokens < 1 or S + max_new_tokens - 1 > self.max_len:
+            raise ValueError(f"{S} prompt tokens and {max_new_tokens} new "
+                             f"ones do not fit max_len {self.max_len}")
+        mask = np.ones((n, S), bool) if mask is None else np.asarray(mask)
+        if not self.masked and not mask.all():
+            return self._by_length(prompts, mask, max_new_tokens,
+                                   temperature, seed, use_frontend)
+        pad_to = 1 << (n - 1).bit_length()
+        if pad_to > n:
+            e_ids, e_mask = self.tokenizer.encode("", S)
+            prompts = np.concatenate([prompts, np.tile(e_ids, (pad_to - n, 1))])
+            mask = np.concatenate([mask, np.tile(e_mask, (pad_to - n, 1))])
+        # left-pad: each row's pads first, then its tokens in order
+        order = np.argsort(mask, axis=1, kind="stable")
+        ids = np.take_along_axis(prompts, order, axis=1)
+        mask = np.take_along_axis(mask, order, axis=1)
+        dev_mask = jnp.asarray(mask) if self.masked else None
+        fe = (stub_frontend_embeds(self.cfg, pad_to, seed) if use_frontend
+              else None)
         key = jax.random.PRNGKey(seed)
-        out = np.zeros((B, max_new_tokens), np.int32)
-        tok = self._select(logits, temperature, key)
-        for t in range(max_new_tokens):
-            out[:, t] = np.asarray(tok)[:, 0]
-            logits, state = self._decode(self.params, state, tok)
-            key, sub = jax.random.split(key)
-            tok = self._select(logits, temperature, sub)
-        return GenerationResult(out, n_prompt=S, n_generated=max_new_tokens)
+        out = np.zeros((pad_to, max_new_tokens), np.int32)
+        with child("generate.prefill", rows=n, padded_to=pad_to):
+            tok, logits, state = self._prefill(
+                self.params, jnp.asarray(ids), dev_mask, fe, key, temperature)
+            self._observe(0, logits, n)
+            with child("generate.sync"):
+                out[:, 0] = np.asarray(tok)[:, 0]
+        with child("generate.decode", rows=n, padded_to=pad_to,
+                   steps=max_new_tokens - 1):
+            # each token is copied once STEPS_AHEAD more steps are queued
+            # behind it, so the device runs on through a host stall
+            pending = deque()
+            for t in range(1, max_new_tokens):
+                tok, logits, state, key = self._decode(
+                    self.params, state, tok, key, temperature)
+                self._observe(t, logits, n)
+                pending.append((t, tok))
+                if len(pending) > STEPS_AHEAD:
+                    self._copy(out, *pending.popleft())
+            for t, ready in pending:
+                self._copy(out, t, ready)
+        return GenerationResult(out[:n], n_prompt=S,
+                                n_generated=max_new_tokens, padded_to=pad_to)
 
     @staticmethod
-    def _select(logits, temperature, key):
-        if temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        g = jax.random.gumbel(key, logits.shape)
-        return jnp.argmax(logits / temperature + g, axis=-1).astype(
-            jnp.int32)[:, None]
+    def _copy(out: np.ndarray, t: int, tok) -> None:
+        with child("generate.sync"):
+            out[:, t] = np.asarray(tok)[:, 0]
+
+    def _by_length(self, prompts, mask, max_new_tokens, temperature, seed,
+                   use_frontend) -> GenerationResult:
+        """Ragged prompts for mixers without a pad mask: one unpadded
+        call per prompt length."""
+        n, S = prompts.shape
+        lengths = mask.sum(axis=1)
+        out = np.zeros((n, max_new_tokens), np.int32)
+        padded = 0
+        for length in np.unique(lengths):
+            rows = np.flatnonzero(lengths == length)
+            ids = np.stack([prompts[r][mask[r]] for r in rows])
+            res = self.generate(ids, max_new_tokens, temperature, seed,
+                                use_frontend)
+            out[rows] = res.tokens
+            padded += res.padded_to
+        return GenerationResult(out, n_prompt=S, n_generated=max_new_tokens,
+                                padded_to=padded)
+
+    def _observe(self, step: int, logits, rows: int) -> None:
+        """Sees the prefill's logits (step 0) and each decode step's while
+        they are on the device; ``rows`` real rows come first.  A no-op
+        here, for a subclass to record them."""
 
 
 @dataclass
@@ -95,11 +194,17 @@ class CachedLLMService:
     """
 
     def __init__(self, embed_fn, cache: CacheBackend,
-                 engine: Optional[ServeEngine], tokenizer: HashTokenizer,
+                 engine: Optional[ServeEngine],
+                 tokenizer: Optional[HashTokenizer] = None,
                  max_query_len: int = 32, max_new_tokens: int = 16,
                  fused: Optional[bool] = None, coalesce: bool = True,
                  telemetry: Optional[Telemetry] = None):
-        """``fused`` (None = leave the backend's choice) selects the
+        """``tokenizer`` is the LLM's (None = the engine's own, a hash
+        vocabulary of the LLM's size; one of another size is refused).
+        A batch's miss leaders are generated in one call, padded to the
+        next power of two of their number.
+
+        ``fused`` (None = leave the backend's choice) selects the
         cache's cascade execution path — the fused Pallas lookup kernel
         vs the four-op composition — when the backend's capabilities
         advertise it; ``coalesce=False`` generates per miss row even
@@ -123,6 +228,12 @@ class CachedLLMService:
         self.caps = cache.capabilities()
         self.engine = engine
         self.tok = tokenizer
+        if engine is not None:
+            self.tok = tokenizer or engine.tokenizer
+            if self.tok.vocab_size != engine.cfg.vocab_size:
+                raise ValueError(
+                    f"tokenizer vocabulary {self.tok.vocab_size} is not "
+                    f"the LLM's {engine.cfg.vocab_size}")
         self.max_query_len = max_query_len
         self.max_new_tokens = max_new_tokens
         self.coalesce = coalesce
@@ -144,6 +255,12 @@ class CachedLLMService:
         self._c_coalesced = reg.counter(
             "serve_coalesced_misses_total",
             "misses served by another row's generation").labels()
+        self._c_gen_tokens = reg.counter(
+            "serve_generated_tokens_total",
+            "tokens the LLM generated for real rows").labels()
+        self._c_gen_padded = reg.counter(
+            "serve_generate_padded_rows_total",
+            "empty rows that padded LLM calls to their bucket").labels()
         self._c_maintenance = reg.counter(
             "serve_maintenance_calls_total",
             "between-batch maintenance() calls").labels()
@@ -159,8 +276,10 @@ class CachedLLMService:
     def _llm_answer(self, queries: List[str]) -> List[str]:
         if self.engine is None:  # degenerate echo backend for tests
             return [f"answer({q})" for q in queries]
-        ids, _ = self.tok.encode_batch(queries, self.max_query_len)
-        res = self.engine.generate(ids, self.max_new_tokens)
+        ids, mask = self.tok.encode_batch(queries, self.max_query_len)
+        res = self.engine.generate(ids, self.max_new_tokens, mask=mask)
+        self._c_gen_tokens.inc(res.tokens.size)
+        self._c_gen_padded.inc(res.padded_to - len(queries))
         return [" ".join(map(str, row)) for row in res.tokens]
 
     def handle(self, queries: List[str],

@@ -99,7 +99,7 @@ def test_full_serving_stack_with_real_llm():
     tok = HashTokenizer(vocab_size=enc_cfg.vocab_size)
     trainer = EmbedderTrainer(enc_cfg, FinetuneConfig(max_len=24))
     cache = SemanticCache(capacity=128, dim=enc_cfg.d_model, threshold=0.99)
-    svc = CachedLLMService(trainer.make_embed_fn(tok), cache, engine, tok,
+    svc = CachedLLMService(trainer.make_embed_fn(tok), cache, engine,
                            max_new_tokens=4)
     q = ["What are the symptoms of early-stage diabetes?"]
     r1 = svc.handle(q)[0]

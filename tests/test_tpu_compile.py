@@ -22,7 +22,8 @@ from repro.cache_service import tiers
 from repro.configs import get_config
 from repro.kernels.cascade_lookup import kernel as cl_kernel
 from repro.kernels.cosine_topk import kernel as ctk_kernel
-from repro.models import encode, init_lm, split
+from repro.models import encode, init_lm, init_lm_state, split
+from repro.serving import ServeEngine
 
 D, HOT, WARM, CLUSTERS, BUCKET = 768, 512, 4096, 32, 256
 
@@ -74,6 +75,25 @@ def test_full_width_encoder_forward_compiles(chip):
         _specs(params, chip), _spec((64, 24), jnp.int32, chip),
         _spec((64, 24), jnp.int32, chip)).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > 500e6
+
+
+def test_jamba2_decode_step_compiles_and_fits(chip):
+    """The LLM behind the cache at its published widths: the engine's
+    own 32-row decode step, bf16 weights, fits one chip's 16 GB, and
+    updates its state in place."""
+    cfg = get_config("jamba2-3b")
+    params = jax.eval_shape(
+        lambda: split(init_lm(cfg, jax.random.PRNGKey(0)))[0])
+    engine = ServeEngine(cfg, params, max_len=88)
+    state = jax.eval_shape(lambda: init_lm_state(cfg, 32, 88))
+    mem = engine._decode.lower(
+        _specs(params, chip), _specs(state, chip),
+        _spec((32, 1), jnp.int32, chip), _spec((2,), jnp.uint32, chip),
+        0.0).compile().memory_analysis()
+    assert mem.argument_size_in_bytes > 6e9
+    assert mem.alias_size_in_bytes > 0.25e9          # the donated state
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
 
 
 @pytest.mark.parametrize("quantized", [False, True])
