@@ -26,7 +26,7 @@ import jax
 
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES
 from repro.launch.mesh import make_production_mesh
-from repro.launch.programs import get_program
+from repro.launch.programs import get_program, prefill_scan_note
 from repro.launch.roofline import model_flops, roofline_terms
 from repro.launch.sharding import RULE_SETS, sharding_tree
 
@@ -99,6 +99,9 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         "lower_seconds": round(t_lower, 2),
         "compile_seconds": round(t_compile, 2),
     }
+    note = prefill_scan_note(prog)
+    if note:
+        result["note"] = note
     if verbose:
         print(f"== {arch} × {shape_name} "
               f"({'multi-pod 2x16x16' if multi_pod else 'single-pod 16x16'}, "
@@ -117,6 +120,8 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
               f"-> bottleneck={terms['bottleneck']}")
         print(f"  MODEL_FLOPS/HLO_FLOPS={result['useful_flops_ratio']:.3f}  "
               f"lower={t_lower:.1f}s compile={t_compile:.1f}s")
+        if note:
+            print(f"  note: {note}")
     return result
 
 
@@ -181,6 +186,7 @@ def run_extrapolated(arch: str, shape_name: str, *, rules: str = "train",
         HBM_BANDWIDTH, ICI_LINK_BANDWIDTH, PEAK_FLOPS_BF16)
     mesh = make_production_mesh(multi_pod=multi_pod)
     prog = get_program(arch, shape_name)  # full cfg, for metadata only
+    note = prefill_scan_note(prog)
     terms = {
         "per_device_flops": ext("flops"),
         "per_device_bytes": ext("bytes"),
@@ -218,6 +224,8 @@ def run_extrapolated(arch: str, shape_name: str, *, rules: str = "train",
         "useful_flops_ratio": mf / hlo_total if hlo_total else 0.0,
         "lower_seconds": 0.0, "compile_seconds": 0.0,
     }
+    if note:
+        result["note"] = note
     if verbose:
         print(f"== {arch} × {shape_name} (EXTRAPOLATED {n} periods) ==")
         print(f"  roofline: compute={terms['t_compute']*1e3:.2f}ms "
@@ -226,6 +234,8 @@ def run_extrapolated(arch: str, shape_name: str, *, rules: str = "train",
               f"-> bottleneck={dom}")
         print(f"  MODEL/HLO={result['useful_flops_ratio']:.3f} "
               f"args={ext('args')/2**30:.2f}GiB")
+        if note:
+            print(f"  note: {note}")
     return result
 
 

@@ -22,7 +22,9 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ATTN, INPUT_SHAPES, ModelConfig, ShapeConfig
+from repro.configs.base import (
+    ATTN, INPUT_SHAPES, MAMBA, ModelConfig, ShapeConfig,
+)
 from repro.models import (
     decode_step, init_lm_state, lm_param_specs, lm_state_axes, prefill,
 )
@@ -55,6 +57,16 @@ def resolve_config(cfg: ModelConfig, shape: ShapeConfig,
         # counts while-loop bodies once; see ModelConfig.scan_layers).
         cfg = cfg.replace(scan_layers=False, unroll_inner=True, remat=False)
     return cfg
+
+
+def prefill_scan_note(prog: Program) -> str | None:
+    """What a serve_prefill program's cost stands for where it is not the
+    served path: Mamba mixers under unroll_inner take the chunked scan."""
+    if (prog.name == "serve_prefill" and prog.cfg.unroll_inner
+            and any(s.mixer == MAMBA for s in prog.cfg.period)):
+        return ("Mamba mixers costed with the chunked training scan; "
+                "serving prefill runs a per-token recurrence")
+    return None
 
 
 def _batch_specs(cfg: ModelConfig, shape: ShapeConfig):
@@ -101,6 +113,9 @@ def build_program(cfg: ModelConfig, shape: ShapeConfig,
         return Program("train_step", cfg, shape, fn, args, arg_axes, out_axes)
 
     if shape.kind == "prefill":
+        # Under unroll_inner the Mamba mixers lower apply_full's chunked
+        # scan, not the per-token recurrence that serving prefill runs
+        # (mamba.apply_prefill): for them this costs the training scan.
         batch, batch_axes = _batch_specs(cfg, shape)
         cache_len = shape.seq_len
 

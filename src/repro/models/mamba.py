@@ -1,12 +1,29 @@
-"""Mamba (selective SSM) mixer — chunked associative-scan training path,
-O(1)-state decode path.
+"""Mamba (selective SSM) mixer: a chunked associative scan for training
+and encoders, a per-token recurrence for serving prefill, O(1)-state
+decode.
 
 TPU adaptation (DESIGN.md §3): the CUDA selective-scan kernel is replaced
-by a chunked formulation — an outer ``lax.scan`` over sequence chunks
-carrying the SSM state h, with a ``lax.associative_scan`` inside each
-chunk.  Working-set memory is O(chunk · d_inner · d_state) instead of
-O(S · d_inner · d_state); the chunk size is the knob the §Perf loop can
-turn.  The depthwise causal conv is a grouped `conv_general_dilated`.
+by two XLA forms of the recurrence h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t,
+y_t = C_t h_t, which take the same front (in_proj, causal conv, dt/B/C)
+and back (D skip, gate, out_proj):
+
+- ``apply_full`` (training, encoders, ``lm_loss``/``forward_lm``): an
+  outer ``lax.scan`` over sequence chunks carrying h, with a
+  ``lax.associative_scan`` inside each chunk.  It is differentiable at
+  log depth over long sequences; its working set is O(chunk · d_inner ·
+  d_state), the chunk size the knob the §Perf loop can turn.
+- ``apply_prefill`` (serving): a ``lax.scan`` over the tokens that
+  carries only h (B, d_inner, d_state) and stacks y_t (B, d_inner), so no
+  (B, L, d_inner, d_state) value exists.  At a short prompt the chunk is
+  the whole prompt, and the chunked form reads and writes several such
+  f32 tensors per layer, over ten times the recurrence's bytes (PERF.md
+  §6).  Under ``cfg.unroll_inner`` (the dry-run programs unroll every
+  loop so that ``cost_analysis`` counts it, at prompts of 32k tokens,
+  where a recurrence of one step a token cannot be unrolled) prefill
+  takes the chunked form.
+- ``apply_decode``: one step of the recurrence.
+
+The depthwise causal conv is a grouped `conv_general_dilated`.
 """
 from __future__ import annotations
 
@@ -17,6 +34,11 @@ from repro.configs.base import ModelConfig, SSMConfig
 from repro.models.param import Initializer
 
 MAMBA_CHUNK = 256
+# tokens per iteration of the prefill recurrence's loop: at 1 each step
+# slices its token's row out of arrays laid out with the sequence as the
+# sublane axis.  4 is an estimate from the TPU v5e compiler's cycle counts
+# for jamba2-3b's 32 x 24 prefill; no chip run has confirmed it (PERF.md §6)
+PREFILL_UNROLL = 4
 
 
 def _dims(cfg: ModelConfig):
@@ -78,18 +100,26 @@ def _rms(x, scale, eps):
     return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
 
 
-def _ssm_inputs(p, cfg: ModelConfig, x_c):
-    """x_c: (B,S,d_in) post-conv activations -> (A_bar, Bx, Cmat)."""
+def _dt_b_c(p, cfg: ModelConfig, x_c):
+    """x_c: (..., d_in) post-conv activations -> (dt (..., d_in), B, C
+    (..., N)) in float32, each through its norm."""
     s, d_in, R = _dims(cfg)
     N = s.d_state
     f32 = jnp.float32
-    xdb = x_c.astype(f32) @ p["x_proj"].astype(f32)        # (B,S,R+2N)
+    xdb = x_c.astype(f32) @ p["x_proj"].astype(f32)        # (...,R+2N)
     dt_raw, Bmat, Cmat = jnp.split(xdb, [R, R + N], axis=-1)
     if s.inner_norms:
         dt_raw = _rms(dt_raw, p["dt_norm"], cfg.norm_eps)
         Bmat = _rms(Bmat, p["B_norm"], cfg.norm_eps)
         Cmat = _rms(Cmat, p["C_norm"], cfg.norm_eps)
     dt = jax.nn.softplus(dt_raw @ p["dt_w"].astype(f32) + p["dt_b"].astype(f32))
+    return dt, Bmat, Cmat
+
+
+def _ssm_inputs(p, cfg: ModelConfig, x_c):
+    """x_c: (B,S,d_in) post-conv activations -> (A_bar, Bx, Cmat)."""
+    f32 = jnp.float32
+    dt, Bmat, Cmat = _dt_b_c(p, cfg, x_c)
     A = -jnp.exp(p["A_log"].astype(f32))                   # (d_in, N)
     A_bar = jnp.exp(dt[..., None] * A)                     # (B,S,d_in,N)
     Bx = (dt * x_c.astype(f32))[..., None] * Bmat[..., None, :]
@@ -110,17 +140,13 @@ def _chunk_scan(A_bar, Bx, h0):
     return h_all, h_all[:, -1]
 
 
-def apply_full(p, cfg: ModelConfig, x, *, return_state: bool = False,
-               mask=None):
-    """x: (B,S,d).  Chunked scan over the sequence.  ``mask`` ((B,S)
-    bool, None = all valid) marks the real tokens of left-padded rows:
-    the mixer's input is zeroed at pads before the conv and its conv
-    output after it, so pads add nothing to the conv window, and with
-    x_c zero there they add nothing to h (which stays zero through the
+def _front(p, x, mask):
+    """x: (B,S,d) -> (x_c, z, conv_state): in_proj, the causal conv and
+    its SiLU.  ``mask`` ((B,S) bool, None = all valid) marks the real
+    tokens of left-padded rows: the conv's input is zeroed at pads and its
+    output after it, so pads add nothing to the conv window, and with x_c
+    zero there they add nothing to h (which stays zero through the
     leading pads)."""
-    s, d_in, _ = _dims(cfg)
-    N = s.d_state
-    B, S, d = x.shape
     dt = x.dtype
     xz = x @ p["in_proj"].astype(dt)
     x_in, z = jnp.split(xz, 2, axis=-1)
@@ -131,6 +157,25 @@ def apply_full(p, cfg: ModelConfig, x, *, return_state: bool = False,
     x_c = jax.nn.silu(x_conv)
     if mask is not None:
         x_c = x_c * keep
+    return x_c, z, conv_state
+
+
+def _back(p, y, x_c, z):
+    """The scan's y (B,S,d_in) f32 -> the mixer's output: D skip, the
+    SiLU gate, out_proj."""
+    dt = z.dtype
+    y = y + p["D"].astype(jnp.float32) * x_c.astype(jnp.float32)
+    return (y.astype(dt) * jax.nn.silu(z)) @ p["out_proj"].astype(dt)
+
+
+def apply_full(p, cfg: ModelConfig, x, *, return_state: bool = False,
+               mask=None):
+    """x: (B,S,d).  Chunked scan over the sequence; ``mask`` as in
+    ``_front``."""
+    s, d_in, _ = _dims(cfg)
+    N = s.d_state
+    B, S, d = x.shape
+    x_c, z, conv_state = _front(p, x, mask)
 
     chunk = min(MAMBA_CHUNK, S)
     if cfg.unroll_inner:  # bound the unrolled loop at ~32 chunks
@@ -161,11 +206,9 @@ def apply_full(p, cfg: ModelConfig, x, *, return_state: bool = False,
     else:
         h_last, ys = jax.lax.scan(body, h0, (Ab, Bk, Ck))
     y = ys.transpose(1, 0, 2, 3).reshape(B, n_chunks * chunk, d_in)[:, :S]
-    y = y + p["D"].astype(jnp.float32) * x_c.astype(jnp.float32)
-    y = (y.astype(dt) * jax.nn.silu(z)) @ p["out_proj"].astype(dt)
+    y = _back(p, y, x_c, z)
     if return_state:
-        return y, {"h": h_last, "conv": conv_state[:, -(s.d_conv - 1):, :]
-                   if s.d_conv > 1 else conv_state}
+        return y, {"h": h_last, "conv": conv_state}
     return y
 
 
@@ -185,7 +228,37 @@ def state_axes():
 
 
 def apply_prefill(p, cfg: ModelConfig, x, mask=None):
-    return apply_full(p, cfg, x, return_state=True, mask=mask)
+    """x: (B,S,d) -> (y, {"h", "conv"}): the prompt through the mixer and
+    the decode state after it, ``mask`` as in ``_front``.
+
+    The selective scan is a ``lax.scan`` over the tokens that carries h
+    (B, d_in, N) in f32 and stacks y_t (B, d_in), h updated in the
+    reference's sequential order, so no (B, S, d_in, N) value exists.
+    The engine's prompts are short (chat.jamba2.gen64: 24 tokens), where
+    this reads and writes a tenth of the chunked scan's bytes; prompts of
+    thousands of tokens take as many serial steps (PERF.md §6 gives the
+    prompt lengths it was checked at).  Under ``cfg.unroll_inner`` it is
+    ``apply_full``'s chunked scan: the dry-run programs that set it unroll
+    every loop, at prompts far too long to unroll one step a token."""
+    if cfg.unroll_inner:
+        return apply_full(p, cfg, x, return_state=True, mask=mask)
+    s, d_in, _ = _dims(cfg)
+    f32 = jnp.float32
+    x_c, z, conv_state = _front(p, x, mask)
+    dt, Bmat, Cmat = _dt_b_c(p, cfg, x_c)
+    A = -jnp.exp(p["A_log"].astype(f32))                   # (d_in, N)
+
+    def step(h, xs):
+        dt_t, u_t, B_t, C_t = xs      # (B,d_in), (B,d_in), (B,N), (B,N)
+        h = jnp.exp(dt_t[..., None] * A) * h + u_t[..., None] * B_t[:, None]
+        return h, jnp.einsum("bdn,bn->bd", h, C_t)
+
+    xs = tuple(jnp.swapaxes(v, 0, 1)
+               for v in (dt, dt * x_c.astype(f32), Bmat, Cmat))
+    h0 = jnp.zeros((x.shape[0], d_in, s.d_state), f32)
+    h, ys = jax.lax.scan(step, h0, xs, unroll=PREFILL_UNROLL)
+    y = _back(p, jnp.swapaxes(ys, 0, 1), x_c, z)
+    return y, {"h": h, "conv": conv_state}
 
 
 def apply_decode(p, cfg: ModelConfig, x, state):

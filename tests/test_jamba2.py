@@ -6,11 +6,11 @@ at small widths with the 14-layer period kept.
 Tolerances.  The program runs here in float32 (``dtype="float32"``) on
 the same weights as the reference, which computes at
 ``Precision.HIGHEST``; the two differ only in the order of their sums
-(chunked associative scan against a sequential one, fused against
-separate products), about 1e-6 relative per layer, so logits agree to
-1e-4 relative after 28 layers and 8 decode steps.  Batching changes
-only the shapes of the same products, so a row batched or alone agrees
-to 1e-5.
+(the training path's chunked associative scan against a sequential
+one, fused against separate products), about 1e-6 relative per layer,
+so logits agree to 1e-4 relative after 28 layers and 8 decode steps.
+Batching changes only the shapes of the same products, so a row
+batched or alone agrees to 1e-5.
 """
 import sys
 from pathlib import Path
@@ -21,10 +21,11 @@ import numpy as np
 import pytest
 
 from repro.configs import SSMConfig, get_config
-from repro.configs.base import ATTN, MAMBA
+from repro.configs.base import ATTN, INPUT_SHAPES, MAMBA
 from repro.core import SemanticCache
 from repro.core.embedders import HashNgramEmbedder
 from repro.data import HashTokenizer
+from repro.launch.programs import build_program, prefill_scan_note
 from repro.launch.serve import decoder_config
 from repro.models import attention, blocks, init_lm, mamba, split
 from repro.models.param import Initializer
@@ -137,6 +138,87 @@ def test_one_layer_matches_the_reference(small, pos):
     with jax.default_matmul_precision("highest"):
         want = layer(x, lp, eps=m["eps"], matmul="f32")
     assert _rel(np.asarray(y), np.asarray(want)) < REL
+
+
+def _leaves(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (a scan's body)
+    included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _leaves(sub)
+
+
+def test_prefill_matches_full_and_decode(small):
+    """The serving prefill's per-token recurrence against the training
+    path's chunked scan and against decode a token at a time, on a
+    left-padded batch with an all-pad row (an empty bucket row): the same
+    y at real tokens and the same {"h", "conv"} state, and h of the
+    all-pad row exactly zero."""
+    cfg, m, pv = small
+    p = jax.tree_util.tree_map(lambda a: a[1], pv["layers"]["pos0"])["mixer"]
+    B, S = 4, 11
+    lens = [11, 6, 2, 0]
+    mask = np.arange(S)[None] >= S - np.array(lens)[:, None]
+    x = jax.random.normal(jax.random.PRNGKey(5), (B, S, m["d"]))
+    y, state = mamba.apply_prefill(p, cfg, x, jnp.asarray(mask))
+    y_full, state_full = mamba.apply_full(p, cfg, x, return_state=True,
+                                          mask=jnp.asarray(mask))
+    np.testing.assert_allclose(np.asarray(y)[mask],
+                               np.asarray(y_full)[mask], atol=1e-4)
+    for k in ("h", "conv"):
+        np.testing.assert_allclose(np.asarray(state[k]),
+                                   np.asarray(state_full[k]), atol=1e-4)
+    for b, n in enumerate(lens):
+        st = mamba.init_state(cfg, 1)
+        for t in range(S - n, S):
+            y_t, st = mamba.apply_decode(p, cfg, x[b:b + 1, t:t + 1], st)
+            np.testing.assert_allclose(np.asarray(y[b, t]),
+                                       np.asarray(y_t[0, 0]), atol=1e-4)
+        for k in ("h", "conv"):
+            np.testing.assert_allclose(np.asarray(state[k][b]),
+                                       np.asarray(st[k][0]), atol=1e-4)
+    assert not np.asarray(state["h"][3]).any()
+
+
+def test_prefill_builds_no_sequence_by_state_tensor():
+    """No value of the prefill program, inside the recurrence's body
+    included, has the sequence, d_inner and SSM state axes together, so
+    the chunked scan's (B, L, d_inner, d_state) tensors cannot come back;
+    under ``unroll_inner`` prefill still takes the chunked scan."""
+    B, S, N = 3, 10, 6          # d_inner 32; no other width is 10 or 6
+    cfg = get_config("jamba2-3b").replace(
+        d_model=16, dtype="float32",
+        ssm=SSMConfig(d_state=N, d_conv=4, expand=2, dt_rank=2,
+                      inner_norms=True))
+    d_in = 2 * cfg.d_model
+    p, _ = split(mamba.init_mamba(Initializer(jax.random.PRNGKey(0)), cfg))
+    x = jnp.zeros((B, S, cfg.d_model))
+    mask = jnp.ones((B, S), bool)
+
+    def shapes(c):
+        jaxpr = jax.make_jaxpr(lambda p, x, mask: mamba.apply_prefill(
+            p, c, x, mask))(p, x, mask).jaxpr
+        return [v.aval.shape for e in _leaves(jaxpr) for v in e.outvars]
+
+    assert not [sh for sh in shapes(cfg)
+                if S in sh and d_in in sh and N in sh]
+    assert (B, S, d_in, N) in shapes(cfg.replace(unroll_inner=True))
+
+
+def test_dry_run_prefill_names_the_scan_it_costs():
+    """The dry-run's unrolled serve_prefill lowers the Mamba mixers'
+    chunked scan, not the served recurrence, and says so; the scanned
+    program and a decode program do not carry the note."""
+    cfg = get_config("jamba2-3b")
+    prefill = INPUT_SHAPES["prefill_32k"]
+    assert "chunked" in prefill_scan_note(build_program(cfg, prefill))
+    assert prefill_scan_note(build_program(cfg, prefill, unroll=False)) is None
+    assert prefill_scan_note(
+        build_program(cfg, INPUT_SHAPES["decode_32k"])) is None
 
 
 def test_mamba_inner_norms_are_applied(small):

@@ -77,15 +77,21 @@ def test_full_width_encoder_forward_compiles(chip):
     assert compiled.memory_analysis().argument_size_in_bytes > 500e6
 
 
-def test_jamba2_decode_step_compiles_and_fits(chip):
-    """The LLM behind the cache at its published widths: the engine's
-    own 32-row decode step, bf16 weights, fits one chip's 16 GB, and
-    updates its state in place."""
+@pytest.fixture(scope="module")
+def jamba2():
+    """(params as shapes, engine) of the LLM behind the cache at its
+    published widths, bf16 weights."""
     cfg = get_config("jamba2-3b")
     params = jax.eval_shape(
         lambda: split(init_lm(cfg, jax.random.PRNGKey(0)))[0])
-    engine = ServeEngine(cfg, params, max_len=88)
-    state = jax.eval_shape(lambda: init_lm_state(cfg, 32, 88))
+    return params, ServeEngine(cfg, params, max_len=88)
+
+
+def test_jamba2_decode_step_compiles_and_fits(chip, jamba2):
+    """The engine's own 32-row decode step fits one chip's 16 GB, and
+    updates its state in place."""
+    params, engine = jamba2
+    state = jax.eval_shape(lambda: init_lm_state(engine.cfg, 32, 88))
     mem = engine._decode.lower(
         _specs(params, chip), _specs(state, chip),
         _spec((32, 1), jnp.int32, chip), _spec((2,), jnp.uint32, chip),
@@ -94,6 +100,19 @@ def test_jamba2_decode_step_compiles_and_fits(chip):
     assert mem.alias_size_in_bytes > 0.25e9          # the donated state
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
+
+
+def test_jamba2_prefill_keeps_no_scan_tensors(chip, jamba2):
+    """The engine's prefill of a 32-row bucket of 24-token prompts: the
+    Mamba layers' per-token recurrence holds no f32 (32, 24, 5120, 16)
+    scan tensors, so the program's scratch is ~0.25 GB, where the chunked
+    scan took ~1.37 GB."""
+    params, engine = jamba2
+    mem = engine._prefill.lower(
+        _specs(params, chip), _spec((32, 24), jnp.int32, chip),
+        _spec((32, 24), jnp.bool_, chip), None,
+        _spec((2,), jnp.uint32, chip), 0.0).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5e9
 
 
 @pytest.mark.parametrize("quantized", [False, True])
