@@ -1,6 +1,6 @@
-"""Tiered cache vs flat brute force at production corpus sizes, fused
-vs unfused cascade, replicated vs sharded warm tier, fp32 vs int8 warm
-panel.
+"""Tiered cache vs flat brute force at production corpus sizes, the
+served jnp cascade vs the Pallas kernel, replicated vs sharded warm
+tier, fp32 vs int8 warm panel.
 
 Flat exact lookup is O(N·D) per query; the tiered cascade is
 O(N_hot·D + (K + n_probe·bucket)·D) — at 64k+ entries the warm IVF tier
@@ -12,19 +12,11 @@ at the operating threshold.
 
 Cascade paths compared per size:
 
-  * ``cascade_unfused``       — the four-op XLA composition
-    (`tiers.cascade_lookup`).
-  * ``cascade_fused``         — `tiers.cascade_query(fused=True)` as
-    dispatched for this backend: the fused Pallas kernel on TPU, the
-    single-op jnp oracle on CPU.
+  * ``cascade_unfused``       — the served jnp cascade
+    (`tiers.cascade_query`, every configuration's lookup).
   * ``cascade_fused_kernel``  — the Pallas kernel forced on
     (interpret mode off-TPU; correctness-path timing, not the CPU
     production path).
-  * ``cascade_fused_blockwise`` — the kernel again, but streaming the
-    warm panel in ``warm_block_n``-row blocks with a running argmax
-    (DESIGN.md §12) — the residency mode that lets the warm slice
-    exceed VMEM.  Asserted bit-exact against the unfused cascade like
-    the other fp32 fused rows.
   * ``cascade_int8``          — the warm panel scanned from its int8
     symmetric quantization, selected rows re-scored exactly
     (DESIGN.md §8); recall must stay within 0.5% of fp32.
@@ -32,17 +24,18 @@ Cascade paths compared per size:
     device (`model` mesh axis, one local IVF per shard, per-shard
     probes ``n_probe/shards``); the cross-shard collective is the
     (Q, k·shards) candidate merge, reported as ``gather_cols`` —
-    compare with ``n``.  Fused-vs-oracle parity is asserted bit-exact.
+    compare with ``n``.  shard_map-vs-oracle parity is asserted
+    bit-exact.
   * ``cascade_sharded_int8``  — both together.
 
-The fp32 fused and unfused paths are asserted to produce the identical
-hit set (bit-exact parity); the int8 rows assert recall within 0.5% of
-fp32 instead (quantization may legitimately flip candidates inside the
-error bound).  At the 256k tier the sharded p50 is expected to beat
-the replicated p50 — asserted on real multi-device backends, a stderr
-warning on CPU where "devices" are threads contending for the same
-cores.  Set ``BENCH_TIERED_SIZES=16384,65536`` to override the size
-sweep.
+The fp32 kernel and the served cascade are asserted to produce the
+identical hit set (bit-exact parity); the int8 rows assert recall
+within 0.5% of fp32 instead (quantization may legitimately flip
+candidates inside the error bound).  At the 256k tier the sharded p50
+is expected to beat the replicated p50 — asserted on real
+multi-device backends, a stderr warning on CPU where "devices" are
+threads contending for the same cores.  Set
+``BENCH_TIERED_SIZES=16384,65536`` to override the size sweep.
 
 The ``tiered/ensemble/*`` rows time the fused multi-embedder cascade
 (DESIGN.md §13): one pilot-routed kernel pass over E stacked key
@@ -50,7 +43,7 @@ panels with the weighted fused score computed in-VMEM, vs the
 single-embedder cascade it must cost at most 1.6x of (the sequential
 alternative costs ~E x).  Fused recall is hard-asserted at or above
 the best single embedder's exact recall, the forced kernel is asserted
-bit-exact against the E-panel four-op oracle, and the
+bit-exact against the served E-panel jnp cascade, and the
 ``weights_uniform`` / ``weights_learned`` rows run the per-tenant
 mixture-weight refit (ridge on per-embedder score/duplicate events)
 against frozen uniform weights on a drifting stream.  Override with
@@ -340,22 +333,14 @@ def _bench_one_size(n_total):
     thresholds = jnp.full((Q,), THRESHOLD, jnp.float32)
 
     flat_fn = jax.jit(lambda st, qq: store_lib.query(st, qq, THRESHOLD, 1))
-    # stream the warm panel in 4 blocks — the §12 residency mode where
-    # the warm slice need not fit VMEM at once
-    warm_block = max((n_total - HOT + 3) // 4, 256)
     paths = {
         "cascade_unfused": jax.jit(partial(
-            tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0, fused=False)),
-        "cascade_fused": jax.jit(partial(
-            tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0, fused=True)),
+            tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0)),
         "cascade_fused_kernel": jax.jit(partial(
-            tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0, fused=True,
+            tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0,
             use_kernel=True)),
-        "cascade_fused_blockwise": jax.jit(partial(
-            tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0, fused=True,
-            use_kernel=True, warm_block_n=warm_block)),
         "cascade_int8": jax.jit(partial(
-            tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0, fused=True,
+            tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0,
             quantized=True)),
     }
 
@@ -383,9 +368,7 @@ def _bench_one_size(n_total):
         yield f"{tag}/{name}", us / Q, {
             "n": n_total, "us_per_query": us / Q, "p50_us": p50,
             "recall_at_thr": recall, "spurious_hits": spurious,
-            "speedup_vs_flat": speedup,
-            **({"warm_block_n": warm_block}
-               if name == "cascade_fused_blockwise" else {})}
+            "speedup_vs_flat": speedup}
         if name == "cascade_int8":
             # quantized selection may flip candidates inside the error
             # bound; the budget is 0.5% of the fp32 recall
@@ -400,22 +383,20 @@ def _bench_one_size(n_total):
     # kernel is a correctness path and must not mask a regression here
     if n_total >= 1 << 16:
         prod = {n: s for n, s in speedups.items()
-                if n not in ("cascade_fused_kernel",
-                             "cascade_fused_blockwise")}
+                if n != "cascade_fused_kernel"}
         assert max(prod.values()) > 1.0, \
             f"{tag}: no production cascade path beats flat ({prod})"
 
-    # no recall regression: fp32 fused paths reproduce the unfused
+    # no recall regression: the fp32 kernel reproduces the served
     # cascade bit-exactly (scores, ids, hit set); the int8 row is
     # excluded — its parity budget is the 0.5% recall assert above
     base = results["cascade_unfused"]
-    for name in ("cascade_fused", "cascade_fused_kernel",
-                 "cascade_fused_blockwise"):
-        for field in tiers.CascadeResult._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(base, field)),
-                np.asarray(getattr(results[name], field)),
-                err_msg=f"{tag}/{name} diverges from unfused on {field}")
+    for field in tiers.CascadeResult._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(base, field)),
+            np.asarray(getattr(results["cascade_fused_kernel"], field)),
+            err_msg=f"{tag}/cascade_fused_kernel diverges from the "
+                    f"served cascade on {field}")
 
     yield from _bench_sharded(tag, n_total, keys, hot, q, tenants,
                               thresholds, n_clusters, bucket, iters,
@@ -445,7 +426,7 @@ def _bench_one_size(n_total):
 def _bench_sharded(tag, n_total, keys, hot, q, tenants, thresholds,
                    n_clusters, bucket, iters, exact_hit, recalls, p50s):
     """Replicated-vs-sharded rows: the warm tier split over every
-    visible device, per-shard fused kernel, (Q, k·shards) merge."""
+    visible device, per-shard jnp cascade, (Q, k·shards) merge."""
     shards = len(jax.devices())
     mesh = make_host_mesh(1, shards)
     swarm = _sharded_warm(keys, n_clusters, bucket, iters, shards, mesh)
@@ -462,16 +443,14 @@ def _bench_sharded(tag, n_total, keys, hot, q, tenants, thresholds,
     }
     for name, kw in sharded_paths.items():
         fn = jax.jit(partial(tiers.cascade_query, k=topk,
-                             n_probe=probe_local, tail=0, fused=True,
-                             mesh=mesh, **kw))
+                             n_probe=probe_local, tail=0, mesh=mesh, **kw))
         res = fn(hot, swarm, q, tenants, thresholds)
         jax.block_until_ready(res)
         # bit-exact parity of the distributed schedule against its
-        # single-device oracle (per-shard four-op emulation + stacked
-        # merge) — the sharded analogue of the fused/unfused assert
+        # single-device oracle (per-shard jnp cascade + stacked
+        # merge) — the sharded analogue of the kernel parity assert
         oracle = jax.jit(partial(tiers.cascade_query, k=topk,
-                                 n_probe=probe_local, tail=0,
-                                 fused=False, **kw))(
+                                 n_probe=probe_local, tail=0, **kw))(
             hot, swarm, q, tenants, thresholds)
         for field in tiers.CascadeResult._fields:
             np.testing.assert_array_equal(
@@ -503,7 +482,7 @@ def _bench_sharded(tag, n_total, keys, hot, q, tenants, thresholds,
             # a structured skip the trajectory gate can verify.
             if n_total >= 1 << 18 and shards > 1:
                 aname = f"{tag}/sharded_p50_beats_replicated"
-                rep_p50 = p50s["cascade_fused"]
+                rep_p50 = p50s["cascade_unfused"]
                 if jax.default_backend() == "cpu":
                     _assert_skipped(
                         aname, "cpu backend: shards are host threads "
@@ -577,7 +556,7 @@ def _bench_ensemble(n_total):
         ensemble claim from arxiv 2507.07061 — exact per-panel recall
         is an upper bound on any single-embedder cascade, so this is
         the strong form);
-      * the forced kernel is bit-exact with the E-panel four-op oracle
+      * the forced kernel is bit-exact with the E-panel jnp cascade
         (scores, ids, hit set — every EnsembleResult field);
       * int8 fused recall within 0.5% of fp32 fused;
       * fused p50 <= 1.6x the single-embedder fused p50 (vs ~E x for
@@ -609,7 +588,7 @@ def _bench_ensemble(n_total):
     # the single-embedder production path on the pilot panel — the
     # latency denominator of the tentpole claim
     single_fn = jax.jit(partial(
-        tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0, fused=True))
+        tiers.cascade_query, k=1, n_probe=N_PROBE, tail=0))
     qpilot = jnp.asarray(qp[:, 0])
     res_s = single_fn(hot, warm, qpilot, tenants, thresholds)
     jax.block_until_ready(res_s)
@@ -625,8 +604,7 @@ def _bench_ensemble(n_total):
     ens_kw = dict(k=1, n_probe=N_PROBE, tail=0)
     recalls = {}
     for name, kw in (("fused", {}), ("fused_int8", {"quantized": True})):
-        fn = jax.jit(partial(tiers.ensemble_cascade_query, fused=True,
-                             **ens_kw, **kw))
+        fn = jax.jit(partial(tiers.ensemble_cascade_query, **ens_kw, **kw))
         res = fn(hot, warm, ens, qe, wj, tenants, thresholds)
         jax.block_until_ready(res)
         hit = np.asarray(res.hit)
@@ -670,11 +648,10 @@ def _bench_ensemble(n_total):
                 f"below fp32 {recalls['fused']}"
 
     # bit-exact parity: the fused kernel (forced; interpret mode
-    # off-TPU) against the E-panel four-op oracle in ref.py
-    oracle = jax.jit(partial(tiers.ensemble_cascade_query, fused=False,
-                             **ens_kw))(
+    # off-TPU) against the served E-panel jnp cascade in ref.py
+    oracle = jax.jit(partial(tiers.ensemble_cascade_query, **ens_kw))(
         hot, warm, ens, qe, wj, tenants, thresholds)
-    kernel = jax.jit(partial(tiers.ensemble_cascade_query, fused=True,
+    kernel = jax.jit(partial(tiers.ensemble_cascade_query,
                              use_kernel=True, **ens_kw))(
         hot, warm, ens, qe, wj, tenants, thresholds)
     for field in tiers.EnsembleResult._fields:

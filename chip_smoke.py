@@ -12,11 +12,13 @@ seeded synthetic pairs, in front of the reduced decoder.  It serves a
 seeded query stream through `CachedLLMService.handle` until the hot tier
 has flushed to the warm ring, the warm IVF has been rebuilt and requests
 hit in the warm tier.  Then, for a seeded probe batch, it copies the tier
-state to the host and recomputes the four-op cascade on the CPU at f32
-("highest" matmul precision): top-1 value ids and hit verdicts must match
-the chip's `plan()` except where the CPU score lies within 1e-3 of the
-threshold or of the runner-up.  Last, it checks that `fused=True` is
-refused on the chip with the compiler's reason.
+state to the host and recomputes the served cascade (`tiers.cascade_query`)
+on the CPU at f32 ("highest" matmul precision): top-1 value ids and hit
+verdicts must match the chip's `plan()` except where the CPU score lies
+within 1e-3 of the threshold or of the runner-up.  Last, it lowers the
+Pallas cascade kernel (`use_kernel=True`) at the served tier shapes: the
+chip's compiler must refuse it, and its reason is printed; if it
+compiles, the check fails, since the kernel should then be served.
 
 Four chips: a sharded `CacheService` over `make_cache_mesh(4)`, filled past
 a flush and a rebuild, whose `plan()` must equal the single-device oracle
@@ -106,7 +108,7 @@ def probe_texts(requests: int) -> list:
 
 
 def probe_phase(stack, probe_texts) -> None:
-    """Chip plan() vs the four-op cascade recomputed on the CPU at f32."""
+    """Chip plan() vs the served cascade recomputed on the CPU at f32."""
     import jax
     import jax.numpy as jnp
 
@@ -136,7 +138,7 @@ def probe_phase(stack, probe_texts) -> None:
     hot, warm = _on_cpu(cache.hot), _on_cpu(cache.warm)
     plan = cache.plan(CacheRequest.build(embs, qt), coalesce=False)
     with jax.default_matmul_precision("highest"):
-        ref = jax.jit(partial(tiers.cascade_lookup, k=2,
+        ref = jax.jit(partial(tiers.cascade_query, k=2,
                               n_probe=cache._n_probe, tail=cache._tail))(
             hot, warm, _on_cpu(embs), _on_cpu(qt), _on_cpu(thr))
     s = np.asarray(ref.scores)
@@ -182,16 +184,25 @@ def probe_phase(stack, probe_texts) -> None:
           f"{int(b_hit.sum())} brute-force hits served")
 
 
-def fused_phase(dim: int) -> None:
-    """fused=True must raise with the chip compiler's reason."""
-    from repro.cache_service import CacheConfig, CacheService, TieringConfig
+def fused_phase(cache) -> None:
+    """The Pallas cascade kernel must be refused at the served tier shapes,
+    with the chip compiler's reason."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.cache_service import tiers
+
+    lookup = jax.jit(partial(tiers.cascade_query, k=cache.topk,
+                             n_probe=cache._n_probe, tail=cache._tail,
+                             use_kernel=True))
     try:
-        CacheService(CacheConfig(dim=dim, tiering=TieringConfig(
-            hot_capacity=512, warm_capacity=4096, n_clusters=32,
-            bucket=256, fused=True)))
-    except NotImplementedError as e:
+        lookup.lower(cache.hot, cache.warm,
+                     jax.ShapeDtypeStruct((1, cache.dim), jnp.float32),
+                     jax.ShapeDtypeStruct((1,), jnp.int32),
+                     jax.ShapeDtypeStruct((1,), jnp.float32)).compile()
+    except Exception as e:   # Mosaic and XLA raise many types here
         print(f"fused kernel refused on the chip, as expected: "
-              f"{str(e).splitlines()[0][:400]}")
+              f"{type(e).__name__}: {str(e).splitlines()[0][:400]}")
         return
     raise RuntimeError("chip_smoke: the fused cascade kernel compiled; "
                        "serve through it here and record it")
@@ -296,7 +307,7 @@ def main(argv=None) -> None:
                stack.cache.dim) == (22, 768, 50368, 768),
               f"embedder is not at its published widths: {enc}")
         probe_phase(stack, probe_texts(args.requests))
-        fused_phase(stack.cache.dim)
+        fused_phase(stack.cache)
     print(json.dumps({"ok": True, "device": device}))
 
 

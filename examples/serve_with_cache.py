@@ -62,9 +62,6 @@ def main():
     ap.add_argument("--tenants", type=int, default=1,
                     help="round-robin request batches over N logical "
                          "tenants (tiered cache only)")
-    ap.add_argument("--fused", action="store_true",
-                    help="run the cascade through the fused Pallas "
-                         "lookup kernel (TPU; four-op fallback on CPU)")
     ap.add_argument("--background-rebuild", action="store_true",
                     help="double-buffer the warm IVF rebuild: k-means "
                          "runs on a shadow index off the hot path and "
@@ -86,20 +83,16 @@ def main():
                          "below-threshold queries fall through to a "
                          "budgeted cold fetch, maintenance() promotes "
                          "re-hot rows back (0 = off; DESIGN.md §12)")
-    ap.add_argument("--warm-block", type=int, default=0,
-                    help="stream the fused kernel's warm panel in "
-                         "N-row blocks (0 = whole-panel; DESIGN.md §12)")
     ap.add_argument("--metrics-json", default=None, metavar="PATH",
                     help="write the telemetry registry snapshot as "
                          "JSON-lines after the run (DESIGN.md §10.1); "
                          "validate with python -m repro.obs.export "
                          "--validate PATH")
     args = ap.parse_args()
-    if args.flat and (args.fused or args.background_rebuild
-                      or args.learned_admission or args.learned_embedder
-                      or args.cold_capacity or args.warm_block):
-        ap.error("--fused/--background-rebuild/--learned-admission/"
-                 "--learned-embedder/--cold-capacity/--warm-block "
+    if args.flat and (args.background_rebuild or args.learned_admission
+                      or args.learned_embedder or args.cold_capacity):
+        ap.error("--background-rebuild/--learned-admission/"
+                 "--learned-embedder/--cold-capacity "
                  "require the tiered CacheService (drop --flat)")
 
     # --- LLM backend (reduced variant of the assigned arch) -----------
@@ -135,10 +128,9 @@ def main():
             admission_margin=0.02, telemetry=telemetry,
             tiering=TieringConfig(
                 hot_capacity=512, warm_capacity=4096, n_clusters=32,
-                bucket=256, n_probe=4, flush_size=128, fused=args.fused,
+                bucket=256, n_probe=4, flush_size=128,
                 background_rebuild=args.background_rebuild,
-                cold_capacity=args.cold_capacity,
-                warm_block=args.warm_block or None),
+                cold_capacity=args.cold_capacity),
             learning=LearningConfig(
                 learned_admission=args.learned_admission,
                 learned_embedder=args.learned_embedder,
@@ -147,8 +139,6 @@ def main():
                 embedder_tokenizer=tok
                 if args.learned_embedder else None,
                 refresh_policy=refresh)))
-        print(f"cascade path: {'fused kernel' if cache.fused else 'four-op'}"
-              f" (backend {jax.default_backend()})")
     svc = CachedLLMService(trainer.make_embed_fn(tok), cache, engine,
                            max_new_tokens=args.max_new_tokens)
 

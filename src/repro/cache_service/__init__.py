@@ -28,13 +28,13 @@ from repro.cache_service.service import (
     CacheService, ServiceStats,
 )
 from repro.cache_service.tiers import (
-    CascadeResult, Demoted, HotState, WarmState, cascade_lookup,
-    cascade_query, demote_coldest, evict_tenant, hot_insert,
-    hot_insert_batch, hot_query, hot_touch, init_hot, init_warm,
-    init_warm_sharded, mask_expired, place_warm_sharded,
+    CascadeResult, Demoted, HotState, WarmState, cascade_query,
+    demote_coldest, evict_tenant, hot_insert, hot_insert_batch,
+    hot_touch, init_hot, init_warm, init_warm_sharded, mask_expired,
+    place_warm_sharded,
     publish_reembedded_keys, quantize_rows, reap_expired, requantize,
     stack_warm, warm_append, warm_append_sharded, warm_occupancy,
-    warm_publish_index, warm_query, warm_rebuild, warm_rebuild_sharded,
+    warm_publish_index, warm_rebuild, warm_rebuild_sharded,
 )
 
 __all__ = [
@@ -48,12 +48,12 @@ __all__ = [
     "CacheBackend", "CacheCapabilities", "CachePlan", "CacheRequest",
     "CommitReceipt", "MaintenanceReport", "coalesce_misses",
     "ungrouped_misses",
-    "CascadeResult", "Demoted", "HotState", "WarmState", "cascade_lookup",
-    "cascade_query", "demote_coldest", "evict_tenant", "hot_insert",
-    "hot_insert_batch", "hot_query", "hot_touch", "init_hot", "init_warm",
-    "init_warm_sharded", "mask_expired", "place_warm_sharded",
+    "CascadeResult", "Demoted", "HotState", "WarmState", "cascade_query",
+    "demote_coldest", "evict_tenant", "hot_insert", "hot_insert_batch",
+    "hot_touch", "init_hot", "init_warm", "init_warm_sharded",
+    "mask_expired", "place_warm_sharded",
     "publish_reembedded_keys", "quantize_rows", "reap_expired",
     "requantize", "stack_warm", "warm_append", "warm_append_sharded",
-    "warm_occupancy", "warm_publish_index", "warm_query", "warm_rebuild",
+    "warm_occupancy", "warm_publish_index", "warm_rebuild",
     "warm_rebuild_sharded",
 ]

@@ -7,7 +7,7 @@ a frozen dataclass of frozen **grouped sub-configs**, one per
 subsystem, each validating its own fields at construction:
 
   * ``TieringConfig``   — hot/warm/cold capacities, IVF shape, flush
-    cadence, fused/quantized/blockwise execution (§2–§4, §12)
+    cadence, fp32/int8 warm panel (§2–§4, §12)
   * ``ShardingConfig``  — mesh + axis of the sharded warm tier (§8)
   * ``LearningConfig``  — §9 admission learning, §11 embedder refresh,
     §14.3 conformal hit calibration
@@ -49,10 +49,8 @@ class TieringConfig:
     flush_size: Optional[int] = None     # None -> hot_capacity // 4
     rebuild_every: int = 1
     kmeans_iters: int = 4
-    fused: bool = False                  # Pallas cascade kernel (§3.1)
     background_rebuild: bool = False     # double-buffered IVF (§7.1)
     warm_dtype: str = "float32"          # "float32" | "int8" (§8.1)
-    warm_block: Optional[int] = None     # blockwise fused scan (§12.5)
     cold_capacity: int = 0               # 0 = no cold tier (§12)
     cold_policy: Optional[ColdRoutingPolicy] = None
 
@@ -75,8 +73,6 @@ class TieringConfig:
         _require(self.warm_dtype in ("float32", "int8"),
                  f"warm_dtype must be float32|int8, got "
                  f"{self.warm_dtype!r}")
-        _require(self.warm_block is None or self.warm_block > 0,
-                 f"warm_block must be positive: {self.warm_block}")
         _require(self.cold_capacity >= 0,
                  f"cold_capacity must be >= 0: {self.cold_capacity}")
 
@@ -177,8 +173,8 @@ class CacheConfig:
     # ------------------------------------------------------------------
     _TIERING_KEYS = ("hot_capacity", "warm_capacity", "n_clusters",
                      "bucket", "n_probe", "flush_watermark", "flush_size",
-                     "rebuild_every", "kmeans_iters", "fused",
-                     "background_rebuild", "warm_dtype", "warm_block",
+                     "rebuild_every", "kmeans_iters",
+                     "background_rebuild", "warm_dtype",
                      "cold_capacity", "cold_policy")
     _LEARNING_KEYS = ("learned_admission", "conformal",
                       "learned_embedder", "embedder_trainer",

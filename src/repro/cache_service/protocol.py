@@ -1,7 +1,7 @@
 """Typed cache serving protocol: ``CacheBackend`` + plan/commit lifecycle.
 
 The serving pipeline used to capability-sniff its cache with
-``hasattr(cache, "set_fused")`` / ``supports_tenants`` and drive it
+``hasattr`` / ``supports_tenants`` and drive it
 through two untyped calls (``lookup`` then ``insert``).  This module is
 the typed replacement (DESIGN.md §7):
 
@@ -53,13 +53,8 @@ TenantArg = Union[int, Sequence[int], np.ndarray]
 
 @dataclass(frozen=True)
 class CacheCapabilities:
-    """Static feature descriptor; replaces hasattr capability sniffing.
-
-    ``fused_lookup=True`` additionally guarantees the backend exposes
-    ``set_fused(bool)`` (the cascade execution-path switch).
-    """
+    """Static feature descriptor; replaces hasattr capability sniffing."""
     tenants: bool = False            # isolates per-tenant id spaces
-    fused_lookup: bool = False       # has set_fused() / Pallas cascade
     admission: bool = False          # plan carries a real admit decision
     background_rebuild: bool = False  # maintenance() can double-buffer
     tiered: bool = False             # hot/warm cascade vs flat store

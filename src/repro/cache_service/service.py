@@ -59,7 +59,6 @@ from repro.cache_service.protocol import (
     MaintenanceReport, coalesce_misses, ungrouped_misses,
 )
 from repro.core.calibration import Calibration
-from repro.kernels.cascade_lookup.ops import _on_tpu
 from repro.obs import Telemetry
 from repro.obs.registry import SCHEMA, tenant_label
 from repro.obs.trace import child
@@ -130,7 +129,7 @@ class CacheService:
         Feature semantics (the prose below names the legacy flat
         keywords; each lives on the sub-config given in parentheses).
 
-        Tail invariant (``TieringConfig``; see ``tiers.warm_query``):
+        Tail invariant (``TieringConfig``; see ``ref.cascade_lookup``):
         rows demoted into the
         warm ring stay unindexed until the next IVF rebuild and are only
         reachable through the brute-force tail window over the last
@@ -147,15 +146,12 @@ class CacheService:
         shard ring, so the window, the clamp and the warning are all
         per shard.
 
-        ``fused=True`` routes the cascade through the fused Pallas
-        lookup kernel (`kernels/cascade_lookup`) on TPU — subject to
-        the kernel's VMEM budget: the warm slice must fit on-chip
-        (DESIGN.md §3.1).  On CPU the flag falls back to the same
-        four-op math, so it never changes results or CPU latency.  On
-        a TPU the kernel is compiled when the flag is set, and a kernel
-        the chip's compiler refuses raises ``NotImplementedError`` with
-        the compiler's reason (today it refuses the in-kernel IVF
-        gathers) — it never falls back to the four-op path silently.
+        Every configuration looks up through one jitted jnp cascade,
+        `tiers.cascade_query` (device program ``jit_cascade_query``;
+        `tiers.ensemble_cascade_query` under an ensemble) — the
+        reference the Pallas kernel of DESIGN.md §3.1 is tested
+        against.  No served path reaches that kernel until Mosaic
+        lowers it.
 
         ``background_rebuild=True`` double-buffers the IVF rebuild
         (DESIGN.md §7): flushes that would have re-clustered inline
@@ -215,13 +211,6 @@ class CacheService:
         *unsharded* warm ring's quantized panel; combine it with
         ``mesh`` and construction raises.
 
-        ``warm_block`` streams the warm panel through the fused kernel
-        in blocks of that many rows (DESIGN.md §12), lifting the
-        single-block VMEM ceiling on warm capacity; None keeps the
-        whole-panel residency.  Results are bit-identical either way.
-        It only means something to the fused kernel, so on a TPU it is
-        checked like ``fused=True``.
-
         ``embedders`` turns on the fused multi-embedder ensemble
         (DESIGN.md §13): an int E (or a sequence of E embedder handles,
         retained for the caller's convenience — the service itself only
@@ -244,7 +233,7 @@ class CacheService:
         ``StalenessConfig`` (§14.2) turns on TTL eviction: admitted
         rows are stamped ``now + ttl`` (the request's per-row TTL, or
         ``default_ttl``), expired rows are masked out of every tier's
-        plan-time view — hot, warm and cold, fused and unfused — and
+        plan-time view — hot, warm and cold — and
         reaped (slots + host strings freed) on the maintenance tick.
         ``clock`` injects the time source for deterministic benches.
 
@@ -286,8 +275,7 @@ class CacheService:
         n_clusters, bucket, n_probe = tc.n_clusters, tc.bucket, tc.n_probe
         flush_watermark, flush_size = tc.flush_watermark, tc.flush_size
         rebuild_every, kmeans_iters = tc.rebuild_every, tc.kmeans_iters
-        fused, background_rebuild = tc.fused, tc.background_rebuild
-        warm_dtype, warm_block = tc.warm_dtype, tc.warm_block
+        background_rebuild, warm_dtype = tc.background_rebuild, tc.warm_dtype
         cold_capacity, cold_policy = tc.cold_capacity, tc.cold_policy
         mesh, shard_axis = shc.mesh, shc.shard_axis
         learned_admission = lc.learned_admission
@@ -374,7 +362,6 @@ class CacheService:
         self._mesh = mesh
         self._shard_axis = shard_axis
         self._flush_local = flush_local
-        self.warm_block = warm_block
         self.cold: Optional[ColdTier] = \
             ColdTier(cold_capacity, dim, policy=cold_policy) \
             if cold_capacity > 0 else None
@@ -553,7 +540,16 @@ class CacheService:
         self._refresh_thread: Optional[threading.Thread] = None
         self._refresh_box: Dict[str, object] = {}
 
-        self.set_fused(fused)
+        quantized = self.warm_dtype == "int8"
+        self._lookup = _jit(
+            tiers.cascade_query, k=self.topk, n_probe=self._n_probe,
+            tail=self._tail, quantized=quantized, mesh=self._mesh,
+            axis=self._shard_axis)
+        if self.ens is not None:
+            self._ens_lookup = _jit(
+                tiers.ensemble_cascade_query, k=self.topk,
+                n_probe=self._n_probe, tail=self._tail, quantized=quantized,
+                mesh=self._mesh, axis=self._shard_axis)
         self._insert = jax.jit(tiers.hot_insert_batch)
         self._touch = jax.jit(tiers.hot_touch)
         self._demote = _jit(tiers.demote_coldest, m=flush_size)
@@ -577,59 +573,6 @@ class CacheService:
                 else tiers.ensemble_warm_append)
             self._ens_publish_panel = jax.jit(tiers.publish_panel,
                                               static_argnames=("e",))
-
-    def set_fused(self, fused: bool) -> None:
-        """Select the cascade execution path (four-op vs fused kernel);
-        re-jits the lookup, so flipping it mid-serve costs one trace.
-        On a TPU the kernel path is compiled here, and a refusal of the
-        chip's compiler raises ``NotImplementedError`` (with the
-        compiler's error chained)."""
-        if (fused or self.warm_block) and _on_tpu():
-            self._require_kernel_compiles()
-        self.fused = bool(fused)
-        self._lookup = _jit(
-            tiers.cascade_query, k=self.topk, n_probe=self._n_probe,
-            tail=self._tail, fused=self.fused,
-            quantized=self.warm_dtype == "int8",
-            mesh=self._mesh, axis=self._shard_axis,
-            warm_block_n=self.warm_block)
-        if getattr(self, "ens", None) is not None:
-            self._ens_lookup = _jit(
-                tiers.ensemble_cascade_query, k=self.topk,
-                n_probe=self._n_probe, tail=self._tail, fused=self.fused,
-                quantized=self.warm_dtype == "int8",
-                mesh=self._mesh, axis=self._shard_axis,
-                warm_block_n=self.warm_block)
-
-    def _require_kernel_compiles(self) -> None:
-        """Compile the fused kernel lookup for one query at this
-        service's tier shapes; raise with the compiler's reason when the
-        chip refuses it, instead of serving through another path."""
-        lookup = partial(
-            tiers.ensemble_cascade_query if self.ens is not None
-            else tiers.cascade_query, k=self.topk, n_probe=self._n_probe,
-            tail=self._tail, fused=True,
-            quantized=self.warm_dtype == "int8", mesh=self._mesh,
-            axis=self._shard_axis, warm_block_n=self.warm_block)
-        q_shape = (1, self.n_embedders, self.dim) if self.ens is not None \
-            else (1, self.dim)
-        args = [self.hot, self.warm]
-        if self.ens is not None:
-            args += [self.ens, jax.ShapeDtypeStruct(q_shape, jnp.float32),
-                     jax.ShapeDtypeStruct((1, self.n_embedders),
-                                          jnp.float32)]
-        else:
-            args.append(jax.ShapeDtypeStruct(q_shape, jnp.float32))
-        args += [jax.ShapeDtypeStruct((1,), jnp.int32),
-                 jax.ShapeDtypeStruct((1,), jnp.float32)]
-        try:
-            jax.jit(lookup).lower(*args).compile()
-        except Exception as e:   # Mosaic and XLA raise many types here
-            raise NotImplementedError(
-                "the fused cascade kernel (fused=True / warm_block) does "
-                "not compile for this TPU; serve with the four-op cascade "
-                f"(fused=False, warm_block=None). Compiler: "
-                f"{type(e).__name__}: {e}") from e
 
     # ------------------------------------------------------------------
     # tenant policy surface
@@ -694,8 +637,7 @@ class CacheService:
     # CacheBackend protocol: plan / commit / maintenance / stats
     # ------------------------------------------------------------------
     def capabilities(self) -> CacheCapabilities:
-        return CacheCapabilities(tenants=True, fused_lookup=True,
-                                 admission=True,
+        return CacheCapabilities(tenants=True, admission=True,
                                  background_rebuild=self.background_rebuild,
                                  tiered=True,
                                  warm_sharded=self._mesh is not None,
@@ -721,7 +663,7 @@ class CacheService:
             qt, self.feedback if self.conformal else None)
         # §14.2: expired rows are masked out of this plan's *view* of
         # the tiers (valid &= not-expired, before the jitted cascade —
-        # elementwise, so fused/unfused/sharded/ensemble all inherit
+        # elementwise, so sharded and ensemble lookups inherit
         # it); the slots themselves are reclaimed by maintenance()
         now = float(self._clock()) if self._ttl_active else None
         hot_view, warm_view = self.hot, self.warm
@@ -1460,7 +1402,7 @@ class CacheService:
 
         ``indexed_total`` becomes the snapshot's total, so every row
         appended *after* the snapshot stays covered by the tail window
-        — recall never dips across the swap (`tiers.warm_query`'s
+        — recall never dips across the swap (the cascade's
         epoch partition keeps slots overwritten post-snapshot out of
         the stale inverted lists).
         """

@@ -22,17 +22,17 @@ Cascade semantics: one jitted call scores both tiers and returns the
 best of the two top-k sets, plus provenance (``hot_hit``) so the host
 only bumps hot-tier LRU clocks.  Scores are cosine in both tiers, so
 "hot first, warm fallback" and "max over tiers" pick the same answers.
-`cascade_query` selects between the four-op XLA composition and the
-fused Pallas kernel (`kernels/cascade_lookup`, DESIGN.md §3) — same
-results, one kernel launch.
+Every tier configuration runs one jnp cascade
+(`kernels/cascade_lookup/ref.py`, through `cascade_query`); it is also
+the reference for the Pallas kernel of DESIGN.md §3, which no served
+code reaches until Mosaic lowers it.
 
 Scale-out (DESIGN.md §8): the warm tier also exists in a *sharded*
 form — a stacked ``WarmState`` whose every leaf carries a leading
 ``shards`` axis, one independent ring + local IVF per shard, laid over
 the mesh ``model`` axis by ``cascade_query(..., mesh=...)`` via
 shard_map.  Each shard probes its own centroids and computes a local
-top-k (the fused kernel runs per shard on exactly the warm slice its
-VMEM budget assumes); the only collective is the tiny
+top-k; the only collective is the tiny
 (Q, k·shards) candidate merge shared with `store.query_sharded`
 (`core.distrib`).  The hot tier stays replicated and is attributed to
 shard 0 so the merge never sees duplicate hot candidates.  The warm
@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ivf as ivf_lib
+from repro.kernels.cascade_lookup import ops as _casc_ops
 from repro.kernels.cascade_lookup.ref import cosine, fuse
 
 NEG = -1e30
@@ -198,19 +199,6 @@ def hot_touch(state: HotState, slots: jax.Array, hit: jax.Array) -> HotState:
     return state._replace(last_used=new_last, clock=clock)
 
 
-def hot_query(state: HotState, q: jax.Array, q_tenants: jax.Array,
-              k: int = 1) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Exact tenant-masked top-k.  q: (Q, D), q_tenants: (Q,) int32."""
-    qn = _unit(q.astype(jnp.float32))
-    scores = cosine(qn, state.keys)                               # (Q, N)
-    ok = state.valid[None, :] & (state.tenants[None, :]
-                                 == q_tenants[:, None])
-    scores = jnp.where(ok, scores, NEG)
-    s, slots = jax.lax.top_k(scores, k)
-    vids = jnp.where(s > NEG / 2, state.value_ids[slots], -1)
-    return s, slots, vids
-
-
 def coldest_slots(state: HotState, m: int) -> jax.Array:
     """The m coldest hot slots in demotion order — the exact selection
     `demote_coldest` pops, exposed so the ensemble flush can gather the
@@ -334,7 +322,7 @@ def warm_append(state: WarmState, dem: Demoted) -> Tuple[WarmState, jax.Array]:
 
     Returns (state, evicted (m,) int32) — response ids of overwritten
     ring slots, -1 padding.  Appended rows are unindexed until the next
-    rebuild; `warm_query`'s tail window keeps them reachable.  The int8
+    rebuild; the cascade's tail window keeps them reachable.  The int8
     panel (``keys_q``/``scales``) and the TTL column (``expires_at``)
     are maintained in the same update.
     """
@@ -421,7 +409,7 @@ def warm_publish_index(current: WarmState, shadow: WarmState) -> WarmState:
     ring, which may have advanced past the shadow's snapshot.  Because
     ``indexed_total`` becomes the snapshot's total, every row appended
     after the snapshot still satisfies ``write_seq > indexed_total``
-    and is served by `warm_query`'s tail window, while ring slots
+    and is served by the cascade's tail window, while ring slots
     overwritten post-snapshot are excluded from the (stale) inverted
     lists by the same epoch partition — so the swap can never create a
     recall dip or a duplicate candidate.
@@ -464,54 +452,6 @@ def publish_reembedded_keys(hot: HotState, warm: WarmState,
             warm._replace(keys=wk, keys_q=q8, scales=sc))
 
 
-def warm_query(state: WarmState, q: jax.Array, q_tenants: jax.Array,
-               k: int = 1, n_probe: int = 8, tail: int = 0
-               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """IVF probe + unindexed-tail scan, tenant-masked.
-
-    Candidates are the members of the ``n_probe`` nearest clusters plus
-    the last ``tail`` ring positions filtered to rows written after the
-    last rebuild.  With tail >= flush_size * rebuild_every, every live
-    row is reachable, so recall matches a full brute-force scan of the
-    probed clusters.
-    """
-    qn = _unit(q.astype(jnp.float32))
-    Q = qn.shape[0]
-    cap = state.keys.shape[0]
-    n_clusters, bucket = state.members.shape
-    n_probe = min(n_probe, n_clusters)
-
-    csims = cosine(qn, state.centroids)                            # (Q, K)
-    _, probes = jax.lax.top_k(csims, n_probe)
-    cand = state.members[probes].reshape(Q, n_probe * bucket)
-    # partition candidates by write epoch so a slot overwritten after
-    # the rebuild (stale member entry + tail member) never appears
-    # twice: IVF side serves rows indexed at the last rebuild, the
-    # tail serves rows written after it.
-    is_tail = jnp.zeros(cand.shape, bool)
-    if tail:
-        tail_idx = (state.cursor - 1 - jnp.arange(tail, dtype=jnp.int32)) \
-            % cap
-        unindexed = state.write_seq[tail_idx] > state.indexed_total
-        tail_cand = jnp.where(unindexed, tail_idx, -1)
-        cand = jnp.concatenate(
-            [cand, jnp.broadcast_to(tail_cand[None, :], (Q, tail))], axis=1)
-        is_tail = jnp.concatenate(
-            [is_tail, jnp.ones((Q, tail), bool)], axis=1)
-
-    safe = jnp.clip(cand, 0, cap - 1)
-    ok = (cand >= 0) & state.valid[safe] \
-        & (state.tenants[safe] == q_tenants[:, None]) \
-        & (is_tail | (state.write_seq[safe] <= state.indexed_total))
-    scores = cosine(qn, state.keys[safe])
-    scores = jnp.where(ok, scores, NEG)
-    top_s, top_i = jax.lax.top_k(scores, k)
-    rows = jnp.arange(Q)[:, None]
-    slots = safe[rows, top_i]
-    vids = jnp.where(top_s > NEG / 2, state.value_ids[slots], -1)
-    return top_s, slots, vids
-
-
 def warm_occupancy(state: WarmState) -> jax.Array:
     return jnp.mean(state.valid.astype(jnp.float32))
 
@@ -520,41 +460,17 @@ def warm_occupancy(state: WarmState) -> jax.Array:
 # cascade + tenant eviction
 # ---------------------------------------------------------------------------
 
-def cascade_lookup(hot: HotState, warm: WarmState, q: jax.Array,
-                   q_tenants: jax.Array, thresholds: jax.Array,
-                   k: int = 1, n_probe: int = 8,
-                   tail: int = 0) -> CascadeResult:
-    """One jitted lookup over both tiers.
-
-    thresholds: (Q,) per-query operating points (host-resolved from the
-    per-tenant policy table — a traced array, so mixed-tenant batches
-    never retrace).
-    """
-    hs, hslots, hvids = hot_query(hot, q, q_tenants, k)
-    ws, _, wvids = warm_query(warm, q, q_tenants, k, n_probe, tail)
-    all_s = jnp.concatenate([hs, ws], axis=1)                      # (Q, 2k)
-    all_v = jnp.concatenate([hvids, wvids], axis=1)
-    s, i = jax.lax.top_k(all_s, k)
-    rows = jnp.arange(s.shape[0])[:, None]
-    vids = all_v[rows, i]
-    hit = s[:, 0] >= thresholds
-    hot_hit = hit & (i[:, 0] < k)
-    return CascadeResult(scores=s, value_ids=vids, hot_slots=hslots[:, 0],
-                         hot_hit=hot_hit, hit=hit)
-
-
 def _cascade_ops(hot: HotState, warm: WarmState, qn, qt, thr, k, n_probe,
-                 tail, use_kernel, quantized, warm_block_n=None):
+                 tail, use_kernel, quantized):
     """Flat-array cascade through the kernel-package dispatch; returns
     the 6-tuple (scores, vids, warm_slots, hot_slots, hot_hit, hit)."""
-    from repro.kernels.cascade_lookup import ops as _casc_ops
     return _casc_ops.cascade_lookup(
         qn, qt, thr, hot.keys, hot.valid, hot.tenants, hot.value_ids,
         warm.keys, warm.valid, warm.tenants, warm.value_ids,
         warm.write_seq, warm.centroids, warm.members,
         warm.cursor, warm.indexed_total, warm.keys_q, warm.scales,
         k=k, n_probe=n_probe, tail=tail, quantized=quantized,
-        use_kernel=use_kernel, warm_block_n=warm_block_n)
+        use_kernel=use_kernel)
 
 
 def _rescore_exact(qn, keys, s, wslots):
@@ -569,8 +485,7 @@ def _rescore_exact(qn, keys, s, wslots):
 
 
 def _shard_cascade(hot: HotState, warm: WarmState, qn, qt, thr, k, n_probe,
-                   tail, use_kernel, quantized, shard_index,
-                   warm_block_n=None):
+                   tail, use_kernel, quantized, shard_index):
     """One shard's candidates for the sharded cascade (DESIGN.md §8).
 
     The hot tier is replicated but *attributed to shard 0* (its valid
@@ -581,8 +496,7 @@ def _shard_cascade(hot: HotState, warm: WarmState, qn, qt, thr, k, n_probe,
     """
     hot = hot._replace(valid=hot.valid & (shard_index == 0))
     s, vids, wslots, hslots, _, _ = _cascade_ops(
-        hot, warm, qn, qt, thr, k, n_probe, tail, use_kernel, quantized,
-        warm_block_n)
+        hot, warm, qn, qt, thr, k, n_probe, tail, use_kernel, quantized)
     if quantized:
         s = _rescore_exact(qn, warm.keys, s, wslots)
     is_hot = ((wslots < 0) & (s > NEG / 2)).astype(jnp.int32)
@@ -590,8 +504,8 @@ def _shard_cascade(hot: HotState, warm: WarmState, qn, qt, thr, k, n_probe,
 
 
 def _cascade_sharded_oracle(hot: HotState, swarm: WarmState, qn, qt, thr,
-                            k, n_probe, tail, use_kernel, quantized,
-                            warm_block_n=None) -> CascadeResult:
+                            k, n_probe, tail, use_kernel, quantized
+                            ) -> CascadeResult:
     """Single-device emulation of the sharded schedule — the bit-exact
     oracle the shard_map path is tested against.  Shard s's candidates
     occupy columns [s·k, (s+1)·k) of the merge panel, exactly like the
@@ -601,7 +515,7 @@ def _cascade_sharded_oracle(hot: HotState, swarm: WarmState, qn, qt, thr,
     per = [_shard_cascade(hot,
                           jax.tree_util.tree_map(lambda x, i=i: x[i], swarm),
                           qn, qt, thr, k, n_probe, tail, use_kernel,
-                          quantized, i, warm_block_n)
+                          quantized, i)
            for i in range(shards)]
     s, vids, is_hot = merge_stacked_topk(
         k, jnp.stack([p[0] for p in per]), jnp.stack([p[1] for p in per]),
@@ -614,7 +528,7 @@ def _cascade_sharded_oracle(hot: HotState, swarm: WarmState, qn, qt, thr,
 
 def _cascade_sharded(hot: HotState, swarm: WarmState, qn, qt, thr, k,
                      n_probe, tail, use_kernel, quantized, mesh,
-                     axis, warm_block_n=None) -> CascadeResult:
+                     axis) -> CascadeResult:
     """shard_map execution of the sharded cascade: warm leaves split on
     their leading shard axis over ``axis``, hot/queries replicated, one
     (Q, k·shards) all-gather merge (`core.distrib.merge_local_topk`)."""
@@ -627,7 +541,7 @@ def _cascade_sharded(hot: HotState, swarm: WarmState, qn, qt, thr, k,
         warm_local = jax.tree_util.tree_map(lambda x: x[0], swarm_)
         s, vids, is_hot, hslots = _shard_cascade(
             hot_, warm_local, qn_, qt_, thr_, k, n_probe, tail,
-            use_kernel, quantized, i, warm_block_n)
+            use_kernel, quantized, i)
         sm, vm, hm = merge_local_topk(axis, k, s, vids, is_hot)
         hit = sm[:, 0] >= thr_
         hot_hit = hit & (hm[:, 0] != 0)
@@ -651,59 +565,47 @@ def _cascade_sharded(hot: HotState, swarm: WarmState, qn, qt, thr, k,
 def cascade_query(hot: HotState, warm: WarmState, q: jax.Array,
                   q_tenants: jax.Array, thresholds: jax.Array,
                   k: int = 1, n_probe: int = 8, tail: int = 0,
-                  fused: bool = False,
-                  use_kernel: bool | None = None,
+                  use_kernel: bool = False,
                   quantized: bool = False,
-                  mesh=None, axis: str = "model",
-                  warm_block_n: int | None = None) -> CascadeResult:
-    """Cascade lookup with a selectable execution path.
+                  mesh=None, axis: str = "model") -> CascadeResult:
+    """The cascade lookup over both tiers, for every tier configuration.
 
-    ``fused=False`` runs the original four-op XLA composition
-    (`cascade_lookup`), the parity reference.  ``fused=True`` routes
-    through `kernels/cascade_lookup` — one fused Pallas kernel on TPU
-    (candidate panels stay in VMEM; the bucket-gather round-trip
-    through HBM disappears) and the same four-op math as a single jnp
-    oracle on CPU / interpret mode.  Both paths return bit-identical
-    ``CascadeResult``s, including tenant masking, invalid slots and the
-    tail window; ``use_kernel`` forces the Pallas path (interpret mode
-    off-TPU) for parity tests.
+    The query is normalised once, then the jnp cascade
+    (`kernels/cascade_lookup/ref.py`: hot exact top-k, centroid probe,
+    IVF bucket gather + unindexed-tail scan, best-of-tiers merge) runs
+    through the `ops` dispatch — fp32 or int8, sharded or not.  It is
+    the program the chip serves and the reference the Pallas kernel is
+    tested against: ``use_kernel=True`` swaps in the kernel (interpret
+    mode off the TPU) for those parity tests.  No served code sets it:
+    Mosaic does not lower the kernel's IVF gathers yet
+    (`tests/test_tpu_compile.py`).
 
     A stacked ``warm`` (leading shard axis, ``keys.ndim == 3``) selects
     the sharded schedule (DESIGN.md §8): per-shard local probe + local
-    top-k (fused or four-op per shard), tiny (Q, k·shards) merge.  With
-    ``mesh`` the shards execute under shard_map over ``axis``; without
-    it the single-device oracle emulates the identical schedule (same
-    results bit-for-bit).  ``tail`` is then the *per-shard* tail
-    window.  ``quantized=True`` scans the warm panel from its int8
-    form and re-scores the selected rows exactly (scores in the result
-    are true fp32 cosines either way).  ``warm_block_n`` streams the
-    warm panel through the fused kernel in blocks of that many rows
-    (DESIGN.md §12) so a shard's warm slice may exceed its VMEM budget;
-    results are bit-identical for every block count (and the flag is a
-    no-op on the four-op / oracle paths).
+    top-k, tiny (Q, k·shards) merge.  With ``mesh`` the shards execute
+    under shard_map over ``axis``; without it the single-device oracle
+    emulates the identical schedule (same results bit-for-bit).
+    ``tail`` is then the *per-shard* tail window.  ``quantized=True``
+    scans the warm panel from its int8 form and re-scores the selected
+    rows exactly (scores in the result are true fp32 cosines either
+    way).
     """
     sharded = warm.keys.ndim == 3
     if mesh is not None and not sharded:
         raise ValueError("cascade_query(mesh=...) needs the stacked "
                          "(sharded) WarmState; see init_warm_sharded")
-    uk = use_kernel if fused else False
+    qn = _unit(q.astype(jnp.float32))
+    qt = q_tenants.astype(jnp.int32)
+    thr = jnp.asarray(thresholds, jnp.float32)
     if sharded:
-        qn = _unit(q.astype(jnp.float32))
-        qt = q_tenants.astype(jnp.int32)
-        thr = jnp.asarray(thresholds, jnp.float32)
         if mesh is None:
             return _cascade_sharded_oracle(hot, warm, qn, qt, thr, k,
-                                           n_probe, tail, uk, quantized,
-                                           warm_block_n)
+                                           n_probe, tail, use_kernel,
+                                           quantized)
         return _cascade_sharded(hot, warm, qn, qt, thr, k, n_probe, tail,
-                                uk, quantized, mesh, axis, warm_block_n)
-    if not fused and not quantized:
-        return cascade_lookup(hot, warm, q, q_tenants, thresholds, k=k,
-                              n_probe=n_probe, tail=tail)
-    qn = _unit(q.astype(jnp.float32))
+                                use_kernel, quantized, mesh, axis)
     s, vids, wslots, hslots, hot_hit, hit = _cascade_ops(
-        hot, warm, qn, q_tenants.astype(jnp.int32), thresholds, k,
-        n_probe, tail, uk, quantized, warm_block_n)
+        hot, warm, qn, qt, thr, k, n_probe, tail, use_kernel, quantized)
     if quantized:
         # exact re-score may reorder the k selected candidates
         s = _rescore_exact(qn, warm.keys, s, wslots)
@@ -711,7 +613,7 @@ def cascade_query(hot: HotState, warm: WarmState, q: jax.Array,
         rows = jnp.arange(s.shape[0])[:, None]
         vids = vids[rows, idx]
         wslots = wslots[rows, idx]
-        hit = s[:, 0] >= thresholds
+        hit = s[:, 0] >= thr
         hot_hit = hit & (wslots[:, 0] < 0)
     return CascadeResult(scores=s, value_ids=vids, hot_slots=hslots,
                          hot_hit=hot_hit, hit=hit)
@@ -739,10 +641,11 @@ def evict_tenant(hot: HotState, warm: WarmState, tenant: jax.Array
 def mask_expired(hot: HotState, warm: WarmState, now: jax.Array
                  ) -> Tuple[HotState, WarmState, jax.Array]:
     """Plan-time staleness mask: views of both tiers with every expired
-    row's ``valid`` bit cleared, so the cascade (fused or four-op,
-    sharded or not — the mask is elementwise and precedes the lookup)
-    can never serve a stale entry.  The underlying state is untouched;
-    `reap_expired` frees the rows on the maintenance tick.  Returns
+    row's ``valid`` bit cleared, so the cascade (sharded or not,
+    single-embedder or ensemble — the mask is elementwise and precedes
+    the lookup) can never serve a stale entry.  The underlying state is
+    untouched; `reap_expired` frees the rows on the maintenance tick.
+    Returns
     (hot_view, warm_view, n_masked) where ``n_masked`` counts rows that
     were valid but past their deadline.
     """
@@ -966,18 +869,16 @@ def publish_panel(ens: EnsembleState, e: int, hot_keys: jax.Array,
 
 
 def _ensemble_ops(hot: HotState, warm: WarmState, ens: EnsembleState,
-                  qe, w, qt, thr, k, n_probe, tail, use_kernel, quantized,
-                  warm_block_n=None):
+                  qe, w, qt, thr, k, n_probe, tail, use_kernel, quantized):
     """E-panel cascade through the kernel-package dispatch; returns the
     6-tuple (scores, vids, warm_slots, hot_slots, hot_hit, hit)."""
-    from repro.kernels.cascade_lookup import ops as _casc_ops
     return _casc_ops.ensemble_lookup(
         qe, w, qt, thr, ens.hot_keys, hot.valid, hot.tenants, hot.value_ids,
         ens.warm_keys, warm.valid, warm.tenants, warm.value_ids,
         warm.write_seq, warm.centroids, warm.members, warm.cursor,
         warm.indexed_total, ens.warm_keys_q, ens.warm_scales,
         k=k, n_probe=n_probe, tail=tail, quantized=quantized,
-        use_kernel=use_kernel, warm_block_n=warm_block_n)
+        use_kernel=use_kernel)
 
 
 def _rescore_exact_fused(qe, w, warm_panels, s, wslots):
@@ -1010,14 +911,14 @@ def _top1_panel_scores(qe, hot_panels, warm_winner_keys, wslot0, hslots,
 
 def _shard_ensemble(hot: HotState, warm: WarmState, ens: EnsembleState,
                     qe, w, qt, thr, k, n_probe, tail, use_kernel, quantized,
-                    shard_index, warm_block_n=None):
+                    shard_index):
     """One shard's fused-ensemble candidates (mirrors `_shard_cascade`:
     hot attributed to shard 0, exact fused re-score before the merge).
     Returns (scores, vids, is_hot, hot_slots, warm_slots)."""
     hot = hot._replace(valid=hot.valid & (shard_index == 0))
     s, vids, wslots, hslots, _, _ = _ensemble_ops(
         hot, warm, ens, qe, w, qt, thr, k, n_probe, tail, use_kernel,
-        quantized, warm_block_n)
+        quantized)
     if quantized:
         s = _rescore_exact_fused(qe, w, ens.warm_keys, s, wslots)
     is_hot = ((wslots < 0) & (s > NEG / 2)).astype(jnp.int32)
@@ -1033,8 +934,7 @@ def _ens_shard(ens: EnsembleState, i) -> EnsembleState:
 
 
 def _ensemble_sharded_oracle(hot, swarm, ens, qe, w, qt, thr, k, n_probe,
-                             tail, use_kernel, quantized,
-                             warm_block_n=None) -> EnsembleResult:
+                             tail, use_kernel, quantized) -> EnsembleResult:
     """Single-device emulation of the sharded fused-ensemble schedule —
     the bit-exact oracle the shard_map path is tested against."""
     from repro.core.distrib import merge_stacked_topk
@@ -1042,7 +942,7 @@ def _ensemble_sharded_oracle(hot, swarm, ens, qe, w, qt, thr, k, n_probe,
     per = [_shard_ensemble(hot,
                            jax.tree_util.tree_map(lambda x, i=i: x[i], swarm),
                            _ens_shard(ens, i), qe, w, qt, thr, k, n_probe,
-                           tail, use_kernel, quantized, i, warm_block_n)
+                           tail, use_kernel, quantized, i)
            for i in range(shards)]
     Q = qe.shape[1]
     shard_cols = [jnp.full((Q, k), i, jnp.int32) for i in range(shards)]
@@ -1064,8 +964,7 @@ def _ensemble_sharded_oracle(hot, swarm, ens, qe, w, qt, thr, k, n_probe,
 
 
 def _ensemble_sharded(hot, swarm, ens, qe, w, qt, thr, k, n_probe, tail,
-                      use_kernel, quantized, mesh, axis,
-                      warm_block_n=None) -> EnsembleResult:
+                      use_kernel, quantized, mesh, axis) -> EnsembleResult:
     """shard_map execution of the sharded fused ensemble: warm tiers
     and panel leaves split on their leading shard axis, hot panels and
     queries replicated, one (Q, k·shards) merge carrying (vid, is_hot,
@@ -1082,7 +981,7 @@ def _ensemble_sharded(hot, swarm, ens, qe, w, qt, thr, k, n_probe, tail,
                                   warm_keys_q=ewq_[0], warm_scales=ewsc_[0])
         s, vids, is_hot, hslots, wslots = _shard_ensemble(
             hot_, warm_local, ens_local, qe_, w_, qt_, thr_, k, n_probe,
-            tail, use_kernel, quantized, i, warm_block_n)
+            tail, use_kernel, quantized, i)
         shard_col = jnp.full(s.shape, i, jnp.int32)
         sm, vm, hm, wm, cm = merge_local_topk(axis, k, s, vids, is_hot,
                                               wslots, shard_col)
@@ -1121,19 +1020,17 @@ def ensemble_cascade_query(hot: HotState, warm: WarmState,
                            weights: jax.Array, q_tenants: jax.Array,
                            thresholds: jax.Array, k: int = 1,
                            n_probe: int = 8, tail: int = 0,
-                           fused: bool = False,
-                           use_kernel: bool | None = None,
+                           use_kernel: bool = False,
                            quantized: bool = False, mesh=None,
-                           axis: str = "model",
-                           warm_block_n: int | None = None
-                           ) -> EnsembleResult:
+                           axis: str = "model") -> EnsembleResult:
     """Fused multi-embedder cascade lookup (DESIGN.md §13).
 
     q: (Q, E, D) — one embedding per embedder per query, panel 0 the
     pilot; weights: (Q, E) per-query mixture weights (host-resolved
-    from the per-tenant policy table, like thresholds).  Execution
-    paths, sharding detection, quantization semantics and
-    ``warm_block_n`` all mirror `cascade_query`; scores everywhere are
+    from the per-tenant policy table, like thresholds).  The jnp
+    cascade (`ref.ensemble_lookup`) serves; ``use_kernel``, sharding
+    detection and quantization semantics all mirror `cascade_query`;
+    scores everywhere are
     the weighted fused cosine, and routing runs once on the pilot
     panel against the base tier's (pilot-built) IVF.  The result adds
     ``panel_scores`` — the top-1 candidate's unweighted per-embedder
@@ -1152,18 +1049,16 @@ def ensemble_cascade_query(hot: HotState, warm: WarmState,
     qt = q_tenants.astype(jnp.int32)
     thr = jnp.asarray(thresholds, jnp.float32)
     w = jnp.asarray(weights, jnp.float32)
-    uk = use_kernel if fused else False
     if sharded:
         if mesh is None:
             return _ensemble_sharded_oracle(hot, warm, ens, qe, w, qt, thr,
-                                            k, n_probe, tail, uk, quantized,
-                                            warm_block_n)
+                                            k, n_probe, tail, use_kernel,
+                                            quantized)
         return _ensemble_sharded(hot, warm, ens, qe, w, qt, thr, k, n_probe,
-                                 tail, uk, quantized, mesh, axis,
-                                 warm_block_n)
+                                 tail, use_kernel, quantized, mesh, axis)
     s, vids, wslots, hslots, hot_hit, hit = _ensemble_ops(
-        hot, warm, ens, qe, w, qt, thr, k, n_probe, tail, uk, quantized,
-        warm_block_n)
+        hot, warm, ens, qe, w, qt, thr, k, n_probe, tail, use_kernel,
+        quantized)
     if quantized:
         # exact fused re-score may reorder the k selected candidates
         s = _rescore_exact_fused(qe, w, ens.warm_keys, s, wslots)

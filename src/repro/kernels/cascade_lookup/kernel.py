@@ -29,8 +29,8 @@ the accumulator concatenated first, and the warm accumulator carries
 each candidate's *flat panel position* (probe-major, tail last — the
 position it occupies in the oracle's single gathered panel) as an
 explicit tie key, so streaming the blocks in any order is
-bit-compatible with the four-op path — `ref.py` — including tenant
-masking, invalid slots and the tail window.
+bit-compatible with the served jnp cascade — `ref.py` — including
+tenant masking, invalid slots and the tail window.
 
 ``quantized=True`` swaps the streamed warm blocks for their int8
 symmetric per-row quantization (``warm_keys`` arrives as int8 plus a

@@ -1,8 +1,11 @@
-"""jit'd dispatch wrapper for the fused cascade lookup.
+"""Dispatch between the jnp cascade and the Pallas cascade kernel.
 
-Chooses the Pallas kernel on TPU (or interpret mode when asked) and the
-four-op jnp oracle otherwise — the oracle IS the original unfused
-cascade math, so the CPU fallback costs nothing over the four-op path.
+The jnp cascade (`ref.py`) serves every tier configuration on every
+platform.  ``use_kernel=True`` runs the Pallas kernel instead —
+interpret mode off the TPU — and only the kernel's parity tests set
+it: Mosaic refuses the kernel's IVF gathers
+(`tests/test_tpu_compile.py`), so no served code reaches it.  The
+change that makes the kernel lower flips this one default by platform.
 Both share the exact signature, so `tiers.cascade_query` is agnostic.
 The ``quantized`` flag selects the int8 warm-panel variant in both
 implementations (DESIGN.md §8); callers re-score the returned
@@ -30,20 +33,16 @@ def cascade_lookup(q, q_tenants, thresholds,
                    warm_keys_q=None, warm_scales=None,
                    k: int = 1, n_probe: int = 8, tail: int = 0, *,
                    quantized: bool = False,
-                   use_kernel: bool | None = None,
+                   use_kernel: bool = False,
                    block_n: int = _kernel.DEFAULT_BLOCK_N,
                    warm_block_n: int | None = None):
     """q: (Q, D) unit-norm -> (scores, value_ids, warm_slots, hot_slots,
     hot_hit, hit); see `ref.cascade_lookup`.
 
-    use_kernel: None -> kernel on TPU, oracle elsewhere (interpret-mode
-    kernels are for correctness tests, not the CPU hot path).
-    warm_block_n streams the warm panel through the kernel in blocks of
-    that many rows (None = whole panel, the pre-§12 residency); the
-    oracle ignores it — blocking never changes results.
+    ``block_n`` and ``warm_block_n`` (stream the warm panel in blocks of
+    that many rows; None = whole panel) are the kernel's own; the jnp
+    cascade ignores them — blocking never changes results.
     """
-    if use_kernel is None:
-        use_kernel = _on_tpu()
     if use_kernel:
         return _kernel.cascade_lookup(
             q, q_tenants, thresholds, hot_keys, hot_valid, hot_tenants,
@@ -66,7 +65,7 @@ def ensemble_lookup(q, weights, q_tenants, thresholds,
                     warm_keys_q=None, warm_scales=None,
                     k: int = 1, n_probe: int = 8, tail: int = 0, *,
                     quantized: bool = False,
-                    use_kernel: bool | None = None,
+                    use_kernel: bool = False,
                     block_n: int = _kernel.DEFAULT_BLOCK_N,
                     warm_block_n: int | None = None):
     """E-panel fused ensemble dispatch (DESIGN.md §13): q (E, Q, D)
@@ -75,11 +74,9 @@ def ensemble_lookup(q, weights, q_tenants, thresholds,
     pilot-built IVF -> the same 6-tuple as `cascade_lookup` with the
     weighted fused score; see `ref.ensemble_lookup`.
 
-    Dispatch rules match `cascade_lookup`: kernel on TPU (interpret
-    mode when forced elsewhere), four-op oracle otherwise.
+    Dispatch rules match `cascade_lookup`: the jnp cascade unless
+    ``use_kernel`` (interpret mode off the TPU).
     """
-    if use_kernel is None:
-        use_kernel = _on_tpu()
     if use_kernel:
         return _kernel.cascade_lookup_ensemble(
             q, weights, q_tenants, thresholds, hot_keys, hot_valid,

@@ -1,16 +1,18 @@
-"""Pure-jnp oracle for the fused cascade lookup.
+"""The cascade lookup in jnp: what every tier configuration serves.
 
-This is the tiered cache's original four-op path (hot exact top-k, warm
-centroid probe, IVF bucket gather + unindexed-tail scan, best-of-tiers
-merge — `cache_service/tiers.py`) expressed over plain arrays, so the
-Pallas kernel and the NamedTuple-based cascade can both be checked
-against one reference.  Candidate ordering matches `jax.lax.top_k`
-tie-breaking (lowest index wins) everywhere, which is what the kernel's
-masked-argmax rounds reproduce.
+Hot exact top-k, warm centroid probe, IVF bucket gather +
+unindexed-tail scan, best-of-tiers merge, over plain arrays.
+`cache_service/tiers.cascade_query` runs it for every tier
+configuration (fp32 or int8, sharded or not; `ensemble_lookup` for
+the multi-embedder ensemble), so this is the program the chip serves.
+It is also the reference the Pallas kernel (`kernel.py`) is tested
+against, so a kernel that one day lowers is checked against exactly
+what is served.  Its own semantic reference is exact brute force over
+the live rows (`core/store.py`).  Candidate ordering matches
+`jax.lax.top_k` tie-breaking (lowest index wins) everywhere, which is
+what the kernel's masked-argmax rounds reproduce.
 
-Queries are expected unit-norm float32 (the caller normalizes once; the
-unfused tiers path normalizes per tier, but `_unit` is idempotent up to
-bit-identity on already-unit rows, so parity holds).
+Queries are expected unit-norm float32 (the caller normalizes once).
 
 ``quantized=True`` scores the warm panel from its int8 symmetric
 per-row quantization (``warm_keys_q`` + ``warm_scales``) with fp32
@@ -37,8 +39,8 @@ def cosine(q, keys):
 
     Written as an elementwise product summed over D rather than a dot:
     each score is then the same f32 sum whatever N is and however the
-    panel is cut into blocks, so the kernel's streamed blocks, this
-    oracle's whole panel and the tiers' four-op path agree bit for bit.
+    panel is cut into blocks, so the kernel's streamed blocks and this
+    cascade's whole panel agree bit for bit.
     XLA's CPU dot rounds differently with the panel's shape, and on a
     TPU an f32 dot defaults to bf16 passes; this form is f32 on both.
     Jitted, so that called eagerly the product and the sum still fuse
@@ -71,6 +73,11 @@ def cascade_lookup(q, q_tenants, thresholds,
     Returns (scores (Q, k), value_ids (Q, k), warm_slots (Q, k),
     hot_slots (Q,), hot_hit (Q,), hit (Q,)) — ``warm_slots`` is -1 for
     candidates answered by the hot tier (or padding).
+
+    Warm candidates are the members of the ``n_probe`` nearest clusters
+    plus the last ``tail`` ring positions written after the last
+    rebuild.  With tail >= flush_size * rebuild_every every live row is
+    reachable, so recall matches a full scan of the probed clusters.
     """
     q = q.astype(jnp.float32)
     q_tenants = q_tenants.astype(jnp.int32)
@@ -138,8 +145,9 @@ def ensemble_lookup(q, weights, q_tenants, thresholds,
                     quantized: bool = False
                     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                                jax.Array, jax.Array]:
-    """E-panel four-op oracle for the fused ensemble cascade
-    (DESIGN.md §13): the weighted fused similarity over E embedder key
+    """The ensemble cascade in jnp (DESIGN.md §13), served for every
+    multi-embedder configuration and the reference of the kernel's
+    ensemble form: the weighted fused similarity over E embedder key
     panels, routed once on the *pilot* embedder (panel 0).
 
     q: (E, Q, D) unit-norm, one query embedding per embedder;

@@ -137,10 +137,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="host-RAM cold-tier rows behind the warm ring "
                          "(0 = no cold tier; DESIGN.md §12; implies "
                          "--tiered, incompatible with --cache-shards)")
-    ap.add_argument("--warm-block", type=int, default=0,
-                    help="stream the fused kernel's warm panel in blocks "
-                         "of N rows (0 = whole-panel residency; "
-                         "DESIGN.md §12)")
     ap.add_argument("--ensemble", type=int, default=0, metavar="E",
                     help="serve E embedders through the fused multi-"
                          "embedder cascade: the fine-tuned embedder is "
@@ -192,7 +188,7 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
                  "add --cache")
     if args.cache_shards or args.warm_dtype != "float32" \
             or args.learned_admission or args.learned_embedder \
-            or args.cold_capacity or args.warm_block or args.ensemble \
+            or args.cold_capacity or args.ensemble \
             or args.ttl or args.conformal:
         args.tiered = True
     if args.cold_capacity and args.cache_shards:
@@ -290,7 +286,6 @@ def build_cache(args, enc_cfg: ModelConfig, trainer: EmbedderTrainer,
         tiering=TieringConfig(hot_capacity=512, warm_capacity=4096,
                               n_clusters=32, bucket=256,
                               warm_dtype=args.warm_dtype,
-                              warm_block=args.warm_block or None,
                               cold_capacity=args.cold_capacity),
         sharding=ShardingConfig(mesh=mesh),
         learning=LearningConfig(

@@ -197,18 +197,15 @@ class CachedLLMService:
                  engine: Optional[ServeEngine],
                  tokenizer: Optional[HashTokenizer] = None,
                  max_query_len: int = 32, max_new_tokens: int = 16,
-                 fused: Optional[bool] = None, coalesce: bool = True,
+                 coalesce: bool = True,
                  telemetry: Optional[Telemetry] = None):
         """``tokenizer`` is the LLM's (None = the engine's own, a hash
         vocabulary of the LLM's size; one of another size is refused).
         A batch's miss leaders are generated in one call, padded to the
         next power of two of their number.
 
-        ``fused`` (None = leave the backend's choice) selects the
-        cache's cascade execution path — the fused Pallas lookup kernel
-        vs the four-op composition — when the backend's capabilities
-        advertise it; ``coalesce=False`` generates per miss row even
-        for near-identical queries (the legacy behaviour).
+        ``coalesce=False`` generates per miss row even for
+        near-identical queries (the legacy behaviour).
 
         ``telemetry`` (None = adopt the backend's, so the whole stack
         shares one registry/tracer) wires the §10 spans and serving
@@ -265,13 +262,6 @@ class CachedLLMService:
             "serve_maintenance_calls_total",
             "between-batch maintenance() calls").labels()
         self._trace = itertools.count()
-        if fused is not None:
-            if self.caps.fused_lookup:
-                self.cache.set_fused(fused)
-            elif fused:
-                raise ValueError(
-                    f"cache backend {type(cache).__name__} has no fused "
-                    "cascade path; use CacheService or drop fused=True")
 
     def _llm_answer(self, queries: List[str]) -> List[str]:
         if self.engine is None:  # degenerate echo backend for tests

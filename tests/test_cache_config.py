@@ -40,8 +40,7 @@ def test_cache_config_rejects_bad_top_level(bad):
     dict(n_clusters=0), dict(bucket=0), dict(n_probe=0),
     dict(flush_watermark=0.0), dict(flush_watermark=1.5),
     dict(flush_size=0), dict(rebuild_every=0),
-    dict(warm_dtype="bfloat16"), dict(warm_block=0),
-    dict(cold_capacity=-1),
+    dict(warm_dtype="bfloat16"), dict(cold_capacity=-1),
 ])
 def test_tiering_config_rejects_bad_fields(bad):
     with pytest.raises(ValueError):
@@ -68,14 +67,14 @@ def test_from_kwargs_groups_every_renamed_key():
     fb = FeedbackConfig()
     cfg = CacheConfig.from_kwargs(
         32, threshold=0.9, hot_capacity=64, warm_capacity=256,
-        fused=True, cold_capacity=512, learned_admission=True,
+        cold_capacity=512, learned_admission=True,
         feedback_config=fb,                  # renamed -> learning.feedback
         embedders=3, ensemble_weights=None,  # renamed -> ensemble.weights
         default_ttl=30.0,
     )
     assert cfg.dim == 32 and cfg.threshold == 0.9
     assert cfg.tiering.hot_capacity == 64
-    assert cfg.tiering.fused and cfg.tiering.cold_capacity == 512
+    assert cfg.tiering.cold_capacity == 512
     assert cfg.learning.learned_admission
     assert cfg.learning.feedback is fb
     assert cfg.ensemble.embedders == 3

@@ -250,8 +250,11 @@ def test_warm_topk_no_duplicates_after_ring_wrap():
     # wrap the ring: slots 2,3 then 0,1 overwritten post-rebuild
     warm, _ = tiers.warm_append(warm, dem([2, 3, 0, 1], [2, 3, 4, 5]))
     q = e[:1]                                      # near slot 0's new row
-    s, slots, vids = tiers.warm_query(warm, q, jnp.zeros(1, jnp.int32),
-                                      k=2, n_probe=2, tail=4)
+    res = tiers.cascade_query(tiers.init_hot(4, d), warm, q,
+                              jnp.zeros(1, jnp.int32),
+                              jnp.zeros(1, jnp.float32),
+                              k=2, n_probe=2, tail=4)
+    s, vids = res.scores, res.value_ids
     live = np.asarray(vids[0])[np.asarray(s[0]) > -1e29]
     assert len(set(live.tolist())) == len(live), vids
 

@@ -1,7 +1,8 @@
-"""Fused cascade-lookup kernel: interpret-mode parity with the four-op
-cascade (exact score/index agreement across tenants, tail rows and
-invalid slots), plus fused/unfused agreement through a real demotion
-flush + rebuild cycle."""
+"""Fused cascade-lookup kernel: interpret-mode parity with the served
+jnp cascade (exact score/index agreement across tenants, tail rows and
+invalid slots), plus kernel/cascade agreement through a real demotion
+flush + rebuild cycle, and a check that served code never reaches the
+kernel."""
 import warnings
 
 import jax.numpy as jnp
@@ -92,7 +93,7 @@ def test_blockwise_warm_stream_matches_oracle(n_wblocks, quantized):
     """DESIGN.md §12: the warm panel streams through the Pallas grid in
     blocks, so a warm slice larger than the single-block VMEM design
     size still runs — and every block count is bit-exact with the
-    four-op oracle (whose panel is gathered whole), fp32 and int8,
+    jnp cascade (whose panel is gathered whole), fp32 and int8,
     including ring wraparound of the tail window."""
     hot, warm = _random_states(Nw=256, unindexed=30)
     if quantized:
@@ -128,16 +129,17 @@ def test_blockwise_warm_stream_ragged_last_block():
 
 
 def test_cascade_query_warm_block_n_matches_default():
-    """tiers-level: cascade_query(warm_block_n=...) on the kernel path
-    equals the unfused four-op result."""
+    """ops-level: the kernel streaming the warm panel in 32-row blocks
+    (`ops.cascade_lookup(use_kernel=True, warm_block_n=32)`) equals the
+    served jnp cascade on the same tier state."""
     hot, warm = _random_states(Nw=128)
     q, qt, thr = _queries(8, 16)
-    base = tiers.cascade_query(hot, warm, q, qt, thr, k=2, n_probe=4,
-                               tail=8, fused=False)
-    blk = tiers.cascade_query(hot, warm, q, qt, thr, k=2, n_probe=4,
-                              tail=8, fused=True, use_kernel=True,
-                              warm_block_n=32)
-    _assert_same_result(base, blk)
+    args = (q, qt, thr) + _flatten(hot, warm)
+    base = cl_ops.cascade_lookup(*args, k=2, n_probe=4, tail=8)
+    blk = cl_ops.cascade_lookup(*args, k=2, n_probe=4, tail=8,
+                                use_kernel=True, warm_block_n=32)
+    for a, b in zip(base, blk):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_fused_kernel_empty_warm_tier():
@@ -181,7 +183,7 @@ def test_ops_dispatch_paths_agree():
 
 
 # ---------------------------------------------------------------------------
-# tiers-level: fused flag on the cascade
+# tiers-level: the kernel behind cascade_query
 # ---------------------------------------------------------------------------
 
 def _assert_same_result(a, b):
@@ -191,10 +193,10 @@ def _assert_same_result(a, b):
                                       err_msg=name)
 
 
-def test_cascade_query_fused_matches_unfused_after_flush_rebuild():
+def test_cascade_query_kernel_matches_cascade_after_flush_rebuild():
     """Drive a real service through demotion flushes + an IVF rebuild
-    cycle, then compare cascade_query(fused=True) — kernel forced —
-    against fused=False on the resulting tier states."""
+    cycle, then compare cascade_query(use_kernel=True) against the
+    served jnp cascade on the resulting tier states."""
     d = 16
     svc = CacheService(dim=d, hot_capacity=32, warm_capacity=128,
                        n_clusters=4, bucket=32, n_probe=4, threshold=0.8,
@@ -210,61 +212,47 @@ def test_cascade_query_fused_matches_unfused_after_flush_rebuild():
 
     q, qt, thr = _queries(16, d)
     for k, tail in [(1, svc._tail), (2, svc._tail), (1, 0)]:
-        unfused = tiers.cascade_query(svc.hot, svc.warm, q, qt, thr, k=k,
-                                      n_probe=4, tail=tail, fused=False)
-        fused = tiers.cascade_query(svc.hot, svc.warm, q, qt, thr, k=k,
-                                    n_probe=4, tail=tail, fused=True,
-                                    use_kernel=True)
-        _assert_same_result(unfused, fused)
+        served = tiers.cascade_query(svc.hot, svc.warm, q, qt, thr, k=k,
+                                     n_probe=4, tail=tail)
+        kernel = tiers.cascade_query(svc.hot, svc.warm, q, qt, thr, k=k,
+                                     n_probe=4, tail=tail, use_kernel=True)
+        _assert_same_result(served, kernel)
 
 
-def test_service_fused_flag_serves_identically():
-    """Two services fed the same trace, one fused: every lookup must
-    agree (hits, scores, served strings)."""
-    d = 24
-    mk = lambda fused: CacheService(
-        dim=d, hot_capacity=16, warm_capacity=64, n_clusters=4, bucket=32,
-        n_probe=4, threshold=0.85, flush_size=8, rebuild_every=1,
-        fused=fused)
-    a, b = mk(False), mk(True)
-    assert not a.fused and b.fused
-    for step in range(8):
-        e = _unit(rng.standard_normal((8, d)).astype(np.float32))
-        texts = [f"s{step}-{i}" for i in range(8)]
-        commit_insert(a, e, texts, tenant=step % 2)
-        commit_insert(b, e, texts, tenant=step % 2)
-        for t in range(2):
-            ha, sa, va = plan_lookup(a, e, tenant=t)
-            hb, sb, vb = plan_lookup(b, e, tenant=t)
-            np.testing.assert_array_equal(ha, hb)
-            np.testing.assert_allclose(sa, sb)
-            assert va == vb
+@pytest.mark.parametrize("variant", ["float32", "int8", "sharded"])
+def test_service_on_tpu_serves_through_the_jnp_cascade(monkeypatch,
+                                                       variant):
+    """With the dispatch taking its TPU branch, a service still builds,
+    plans, flushes, rebuilds and commits through the jnp cascade — fp32,
+    int8 and sharded warm tiers alike: no served code lowers the Pallas
+    kernel, which Mosaic refuses."""
+    import jax
 
+    from repro.cache_service import (
+        CacheConfig, ShardingConfig, TieringConfig,
+    )
 
-def test_fused_flag_raises_where_the_chip_refuses_the_kernel(monkeypatch):
-    """On a TPU, fused=True (or warm_block) compiles the kernel when it
-    is set, and a compiler refusal raises NotImplementedError — never a
-    silent four-op fallback.  Steered here by making the service and the
-    kernel dispatch take their TPU branch: the kernel then lowers with
-    interpret=False, which the CPU backend refuses."""
-    from repro.cache_service import CacheConfig, TieringConfig
-    from repro.cache_service import service as service_mod
-    monkeypatch.setattr(service_mod, "_on_tpu", lambda: True)
+    def refuse(*a, **kw):
+        raise AssertionError("served code reached the cascade kernel")
     monkeypatch.setattr(cl_ops, "_on_tpu", lambda: True)
-
-    def cfg(**kw):
-        return CacheConfig(dim=16, tiering=TieringConfig(
+    monkeypatch.setattr(cl_kernel, "cascade_lookup", refuse)
+    monkeypatch.setattr(cl_kernel, "cascade_lookup_ensemble", refuse)
+    d = 16
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("model",)) \
+        if variant == "sharded" else None
+    svc = CacheService(CacheConfig(
+        dim=d, threshold=0.8, tiering=TieringConfig(
             hot_capacity=32, warm_capacity=64, n_clusters=4, bucket=16,
-            **kw))
-
-    with pytest.raises(NotImplementedError, match="does not compile"):
-        CacheService(cfg(fused=True))
-    with pytest.raises(NotImplementedError, match="does not compile"):
-        CacheService(cfg(warm_block=32))
-    svc = CacheService(cfg())
-    with pytest.raises(NotImplementedError, match="does not compile"):
-        svc.set_fused(True)
-    assert not svc.fused
+            flush_size=8,
+            warm_dtype="int8" if variant == "int8" else "float32"),
+        sharding=ShardingConfig(mesh=mesh)))
+    for step in range(6):
+        e = _unit(rng.standard_normal((8, d)).astype(np.float32))
+        commit_insert(svc, e, [f"s{step}-{i}" for i in range(8)])
+        hit, _, served = plan_lookup(svc, e)
+        assert hit.all() and served == [f"s{step}-{i}" for i in range(8)]
+    st = svc.stats_snapshot()
+    assert st.tiers["demotions"] > 0 and st.rebuild["rebuilds"] > 0
 
 
 def test_tail_invariant_warning_on_unsafe_config():

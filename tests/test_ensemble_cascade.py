@@ -1,5 +1,5 @@
 """Fused multi-embedder ensemble cascade (DESIGN.md §13): kernel vs
-the four-op oracle (fp32/int8, blockwise), the E=1 degenerate identity
+the jnp cascade (fp32/int8, blockwise), the E=1 degenerate identity
 with the single cascade, panel/base mutation alignment, sharded
 shard_map-vs-oracle parity across 1/2/8 virtual devices, panel
 versioning via `publish_panel`, and the service-level round trip
@@ -57,7 +57,7 @@ def _assert_same(a, b, fields=tiers.EnsembleResult._fields, msg=""):
 
 
 # ---------------------------------------------------------------------------
-# kernel layer: pallas kernel vs the four-op reference
+# kernel layer: pallas kernel vs the jnp cascade
 # ---------------------------------------------------------------------------
 
 def _kernel_fixture(e=E, n_q=9, nh=40, cap=96, n_k=6, bucket=24):
@@ -95,7 +95,7 @@ def _kernel_fixture(e=E, n_q=9, nh=40, cap=96, n_k=6, bucket=24):
 @pytest.mark.parametrize("warm_block_n", [None, 32, 17])
 def test_kernel_matches_four_op_oracle(quantized, warm_block_n):
     """One pallas pass over the E stacked panels is bit-exact with the
-    unfused four-op reference — partial probes, tail window, invalid
+    served jnp cascade — partial probes, tail window, invalid
     slots, mixed tenants, uneven warm blocking included."""
     q, w, args, wkq, scales = _kernel_fixture()
     ref = clk_ref.ensemble_lookup(q, w, *args, warm_keys_q=wkq,
@@ -185,11 +185,10 @@ def test_tiers_fused_kernel_matches_oracle(quantized):
     thr = jnp.full((Q,), 0.8, jnp.float32)
     ref = tiers.ensemble_cascade_query(
         hot, warm, ens, jnp.asarray(qp), jnp.asarray(w), qt, thr, k=2,
-        n_probe=2, tail=8, fused=False, quantized=quantized)
+        n_probe=2, tail=8, quantized=quantized)
     ker = tiers.ensemble_cascade_query(
         hot, warm, ens, jnp.asarray(qp), jnp.asarray(w), qt, thr, k=2,
-        n_probe=2, tail=8, fused=True, use_kernel=True,
-        quantized=quantized, warm_block_n=32)
+        n_probe=2, tail=8, use_kernel=True, quantized=quantized)
     _assert_same(ref, ker, msg=f"quant={quantized} ")
     # panel_scores consistency: where a candidate exists, the fused
     # top-1 equals the weighted sum of the reported per-panel cosines

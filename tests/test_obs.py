@@ -399,12 +399,12 @@ def test_overhead_budget_check():
 # the span tree + registry deltas through the full pipeline
 # ---------------------------------------------------------------------------
 
-def _service(fused: bool):
+def _service(warm_dtype: str = "float32"):
     tel = Telemetry()
     cache = CacheService(dim=32, hot_capacity=16, warm_capacity=256,
                          n_clusters=4, bucket=32, n_probe=2,
                          threshold=0.93, flush_watermark=0.5, flush_size=4,
-                         kmeans_iters=2, seed=0, fused=fused,
+                         kmeans_iters=2, seed=0, warm_dtype=warm_dtype,
                          background_rebuild=True, telemetry=tel)
     embedder = HashNgramEmbedder(dim=32)
     svc = CachedLLMService(lambda qs: embedder.embed(qs), cache, None,
@@ -412,13 +412,13 @@ def _service(fused: bool):
     return tel, cache, svc
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_handle_produces_complete_span_tree(fused):
+@pytest.mark.parametrize("warm_dtype", ["float32", "int8"])
+def test_handle_produces_complete_span_tree(warm_dtype):
     """One request through handle() yields the full §10.2 span tree —
     embed/plan/generate/commit and, once the flush watermark trips,
     maintenance — plus tenant-labeled registry deltas, for both the
-    fused and unfused cascade paths."""
-    tel, cache, svc = _service(fused)
+    fp32 and the int8 warm panel."""
+    tel, cache, svc = _service(warm_dtype)
     queries = [f"distinct query number {i} about topic {i}"
                for i in range(12)]
     svc.handle(queries, tenant=3)
@@ -484,7 +484,7 @@ def test_flat_cache_shares_telemetry_with_engine():
 # ---------------------------------------------------------------------------
 
 def test_stats_snapshot_schema():
-    _, cache, svc = _service(fused=False)
+    _, cache, svc = _service()
     svc.handle(["one query", "two query"], tenant=1)
     snap = cache.stats_snapshot()
     assert snap.schema == SCHEMA

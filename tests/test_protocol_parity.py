@@ -3,7 +3,7 @@ the naive two-call serving loop (uncoalesced per-batch plan, then a
 fresh for_insert commit of the misses — the v2.0-removed lookup/insert
 shims, inlined) — hits, scores, value ids, admissions, evictions and
 the full device tier state — for both backends (SemanticCache and
-CacheService) and both cascade paths (fused and unfused).  The query
+CacheService) and both warm panels (fp32 and int8).  The query
 mix includes exact in-batch duplicates, so miss coalescing is exercised
 while keeping even the host strings identical."""
 import numpy as np
@@ -93,13 +93,13 @@ def _parity_counts(svc):
             "evictions": s.tiers["evictions"]}
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_cache_service_plan_commit_matches_two_call_loop(fused):
+@pytest.mark.parametrize("warm_dtype", ["float32", "int8"])
+def test_cache_service_plan_commit_matches_two_call_loop(warm_dtype):
     d = 24
     mk = lambda: CacheService(
         dim=d, hot_capacity=16, warm_capacity=64, n_clusters=4, bucket=32,
         n_probe=4, threshold=0.85, admission_margin=0.05, flush_size=8,
-        rebuild_every=2, fused=fused)
+        rebuild_every=2, warm_dtype=warm_dtype)
     naive, typed = mk(), mk()
     for b, embs in enumerate(_batches(d)):
         tenant = b % 3

@@ -1,6 +1,6 @@
 """Sharded warm tier (DESIGN.md §8): shard_map-vs-oracle and
-sharded-vs-single-device parity for `cascade_query` (fused and
-unfused, fp32 and int8) across 1/2/8 virtual devices, the shared
+sharded-vs-single-device parity for `cascade_query` (jnp cascade and
+Pallas kernel, fp32 and int8) across 1/2/8 virtual devices, the shared
 local-topk/tiny-merge helper, the quantization error bound, a
 `warm_publish_index` swap mid-stream and `evict_tenant` on a sharded
 warm tier.  Multi-device cases need
@@ -138,25 +138,24 @@ def test_merge_helper_ties_resolve_to_earliest_shard():
 
 def test_sharded_oracle_s1_equals_plain_single_device():
     """One shard IS the single-device cascade: the stacked schedule at
-    S=1 must be bit-exact with the plain path, fused and unfused."""
+    S=1 must be bit-exact with the plain path, jnp and kernel."""
     hot = _hot()
     warm = _warm_shard(64, 16, 8, 16)
     swarm = jax.tree_util.tree_map(lambda x: x[None], warm)
     q, qt, thr = _queries(9, 16)
-    for fused, uk, quant in [(False, None, False), (True, True, False)]:
+    for uk in (False, True):
         plain = tiers.cascade_query(hot, warm, q, qt, thr, k=2, n_probe=4,
-                                    tail=10, fused=fused, use_kernel=uk,
-                                    quantized=quant)
+                                    tail=10, use_kernel=uk)
         stacked = tiers.cascade_query(hot, swarm, q, qt, thr, k=2, n_probe=4,
-                                      tail=10, fused=fused, use_kernel=uk,
-                                      quantized=quant)
+                                      tail=10, use_kernel=uk)
         _assert_same(plain, stacked)
 
 
 @pytest.mark.parametrize("S", [1, 2, 8])
-@pytest.mark.parametrize("fused,quantized", [(False, False), (True, False),
-                                             (True, True)])
-def test_shard_map_matches_single_device_oracle(S, fused, quantized):
+@pytest.mark.parametrize("use_kernel,quantized", [(False, False),
+                                                  (True, False),
+                                                  (True, True)])
+def test_shard_map_matches_single_device_oracle(S, use_kernel, quantized):
     """The distributed schedule (shard_map + all-gather merge) is
     bit-exact with its single-device emulation — partial probes, tail
     windows, invalid slots and mixed tenants included."""
@@ -165,21 +164,20 @@ def test_shard_map_matches_single_device_oracle(S, fused, quantized):
     swarm = _swarm(S)
     q, qt, thr = _queries(9, 16)
     mesh = make_host_mesh(1, S)
-    uk = True if fused else None
     oracle = tiers.cascade_query(hot, swarm, q, qt, thr, k=2, n_probe=2,
-                                 tail=5, fused=fused, use_kernel=uk,
+                                 tail=5, use_kernel=use_kernel,
                                  quantized=quantized)
     dist = jax.jit(lambda h, w, qq, t, th: tiers.cascade_query(
-        h, w, qq, t, th, k=2, n_probe=2, tail=5, fused=fused,
-        use_kernel=uk, quantized=quantized, mesh=mesh))(
+        h, w, qq, t, th, k=2, n_probe=2, tail=5, use_kernel=use_kernel,
+        quantized=quantized, mesh=mesh))(
             hot, _shard_put(swarm, mesh), q, qt, thr)
     _assert_same(oracle, dist)
 
 
 @pytest.mark.parametrize("S", [2, 8])
 def test_sharded_fused_bitexact_vs_single_device_unfused_full_probe(S):
-    """The acceptance parity: the fused sharded cascade on S virtual
-    devices reproduces the single-device unfused path bit-for-bit at
+    """The acceptance parity: the kernel's sharded cascade on S virtual
+    devices reproduces the single-device served path bit-for-bit at
     fp32 (scores, value ids, hit masks) when both sides probe their
     full cluster sets over the same row universe."""
     _need_devices(S)
@@ -223,11 +221,11 @@ def test_sharded_fused_bitexact_vs_single_device_unfused_full_probe(S):
     q, qt, thr = _queries(16, D)
     mesh = make_host_mesh(1, S)
     single = tiers.cascade_query(hot, plain_warm(), q, qt, thr, k=k,
-                                 n_probe=8, tail=0, fused=False)
+                                 n_probe=8, tail=0)
     swarm = _shard_put(tiers.stack_warm([shard(s) for s in range(S)]), mesh)
     dist = jax.jit(lambda h, w, qq, t, th: tiers.cascade_query(
-        h, w, qq, t, th, k=k, n_probe=2, tail=0, fused=True,
-        use_kernel=True, mesh=mesh))(hot, swarm, q, qt, thr)
+        h, w, qq, t, th, k=k, n_probe=2, tail=0, use_kernel=True,
+        mesh=mesh))(hot, swarm, q, qt, thr)
     _assert_same(single, dist)
 
 
@@ -243,8 +241,8 @@ def test_cross_shard_collective_is_k_shards_not_corpus(S):
     q, qt, thr = _queries(Q, 16)
     mesh = make_host_mesh(1, S)
     fn = jax.jit(lambda h, w, qq, t, th: tiers.cascade_query(
-        h, w, qq, t, th, k=k, n_probe=2, tail=4, fused=True,
-        use_kernel=True, mesh=mesh))
+        h, w, qq, t, th, k=k, n_probe=2, tail=4, use_kernel=True,
+        mesh=mesh))
     txt = fn.lower(hot, _shard_put(swarm, mesh), q, qt, thr) \
             .compile().as_text()
     # HLO shape syntax: `%x = f32[8,4]{0,1} all-gather(...)`
@@ -290,8 +288,7 @@ def test_int8_scores_are_exact_rescored_cosines():
     q, qt, _ = _queries(12, 16)
     thr = jnp.full((12,), -1.0, jnp.float32)
     res = tiers.cascade_query(hot, warm, q, qt, thr, k=2, n_probe=4,
-                              tail=0, fused=True, use_kernel=True,
-                              quantized=True)
+                              tail=0, use_kernel=True, quantized=True)
     vids = np.asarray(res.value_ids)
     scores = np.asarray(res.scores)
     wkeys = np.asarray(warm.keys)
@@ -331,10 +328,9 @@ def test_int8_recall_parity_on_clustered_corpus():
     qt = jnp.zeros((64,), jnp.int32)
     thr = jnp.full((64,), 0.9, jnp.float32)
     fp32 = tiers.cascade_query(hot, warm, q, qt, thr, k=1, n_probe=4,
-                               tail=0, fused=False)
+                               tail=0)
     int8 = tiers.cascade_query(hot, warm, q, qt, thr, k=1, n_probe=4,
-                               tail=0, fused=True, use_kernel=True,
-                               quantized=True)
+                               tail=0, use_kernel=True, quantized=True)
     f_hit, i_hit = np.asarray(fp32.hit), np.asarray(int8.hit)
     assert f_hit.sum() > 0
     recall = (f_hit & i_hit).sum() / max(f_hit.sum(), 1)

@@ -118,12 +118,12 @@ def test_jamba2_prefill_keeps_no_scan_tensors(chip, jamba2):
 @pytest.mark.parametrize("quantized", [False, True])
 @pytest.mark.parametrize("Q", [8, 128])
 def test_four_op_cascade_compiles(chip, Q, quantized):
-    """The served lookup (`cascade_query`, fused=False) at D=768."""
+    """The served lookup (`cascade_query`, the jnp cascade) at D=768."""
     hot = _specs(jax.eval_shape(lambda: tiers.init_hot(HOT, D)), chip)
     warm = _specs(jax.eval_shape(
         lambda: tiers.init_warm(WARM, D, CLUSTERS, BUCKET)), chip)
     jax.jit(partial(tiers.cascade_query, k=1, n_probe=8, tail=128,
-                    fused=False, quantized=quantized)).lower(
+                    quantized=quantized)).lower(
         hot, warm, _spec((Q, D), jnp.float32, chip),
         _spec((Q,), jnp.int32, chip),
         _spec((Q,), jnp.float32, chip)).compile()
@@ -150,8 +150,8 @@ def test_cosine_topk_kernel_compiles(chip, Q, N, k):
 @pytest.mark.parametrize("quantized", [False, True])
 def test_cascade_kernel_ivf_gather_is_refused(chip, quantized):
     """Mosaic cannot lower the fused cascade kernel's data-dependent IVF
-    gathers; `CacheService` turns this refusal into its fused=True
-    error.  When a kernel that lowers replaces it, this test goes."""
+    gathers, so no served code reaches the kernel (`ops.py`).  When a
+    kernel that lowers replaces it, this test goes."""
     f32, i32 = jnp.float32, jnp.int32
     s = lambda shape, dt=f32: _spec(shape, dt, chip)
     args = (s((8, D)), s((8,), i32), s((8,)), s((HOT, D)),

@@ -6,7 +6,7 @@ injectable logical clock (``StalenessConfig.clock``), with a hot tier
 squeezed small enough that entries demote through warm mid-life — the
 invariants must hold wherever an entry happens to live:
 
-  * an expired entry is NEVER served, from any tier, fused or unfused,
+  * an expired entry is NEVER served, from any tier, fp32 or int8 warm,
     whether or not maintenance has reaped it yet (plan-time masking);
   * reaping never frees a live value_id — every unexpired entry is
     still served with its own response after any maintenance;
@@ -47,7 +47,7 @@ def _unit(x):
     return x / np.maximum(np.linalg.norm(x, axis=-1, keepdims=True), 1e-9)
 
 
-def _svc(clock, *, fused=False, dim=16):
+def _svc(clock, *, warm_dtype="float32", dim=16):
     # low flush watermark + small insert batches: every hot overflow
     # goes through the maintenance *demotion* path into warm (an
     # insert-time overflow hard-drops instead, which is legitimate
@@ -57,7 +57,7 @@ def _svc(clock, *, fused=False, dim=16):
         tiering=TieringConfig(hot_capacity=8, warm_capacity=64,
                               n_clusters=2, bucket=32, n_probe=2,
                               flush_watermark=0.5, flush_size=4,
-                              fused=fused),
+                              warm_dtype=warm_dtype),
         staleness=StalenessConfig(clock=lambda: clock["t"])))
 
 
@@ -69,11 +69,11 @@ def _commit_rows(svc, embs, responses, ttl, tenant=0):
     return svc.commit(plan, responses)
 
 
-def _ttl_world(seed, clock, fused):
+def _ttl_world(seed, clock, warm_dtype="float32"):
     """Random TTL pattern over enough rows to push through hot into
     warm; returns (svc, embs, deadlines)."""
     rng = np.random.default_rng(seed)
-    svc = _svc(clock, fused=fused)
+    svc = _svc(clock, warm_dtype=warm_dtype)
     n = int(rng.integers(12, 28))      # > hot_capacity=8: forces demotion
     embs = _unit(rng.standard_normal((n, 16)).astype(np.float32))
     ttl = np.where(rng.random(n) < 0.6,
@@ -89,9 +89,9 @@ def _ttl_world(seed, clock, fused):
 
 @_property
 def test_expired_rows_never_served_any_tier(seed):
-    fused = bool(seed % 2)
+    warm_dtype = ("float32", "int8")[seed % 2]
     clock = {"t": 100.0}
-    svc, embs, deadlines = _ttl_world(seed, clock, fused)
+    svc, embs, deadlines = _ttl_world(seed, clock, warm_dtype)
     rng = np.random.default_rng(seed + 1)
     for now in sorted(rng.uniform(100.0, 125.0, 4)):
         clock["t"] = float(now)
@@ -101,7 +101,7 @@ def test_expired_rows_never_served_any_tier(seed):
         # an expired row must never hit — masked at plan time even
         # though maintenance hasn't reaped anything yet
         assert not np.any(hit & ~live), (
-            f"expired row served at t={now} (fused={fused}): "
+            f"expired row served at t={now} ({warm_dtype}): "
             f"{np.flatnonzero(hit & ~live)}")
         # and every still-live row must still be served with its own
         # response (expiry must not over-mask live neighbours)
@@ -114,7 +114,7 @@ def test_expired_rows_never_served_any_tier(seed):
 @_property
 def test_reaping_never_frees_live_value_id(seed):
     clock = {"t": 0.0}
-    svc, embs, deadlines = _ttl_world(seed, clock, fused=False)
+    svc, embs, deadlines = _ttl_world(seed, clock)
     rng = np.random.default_rng(seed + 2)
     for now in sorted(rng.uniform(0.0, 30.0, 5)):
         clock["t"] = float(now)
